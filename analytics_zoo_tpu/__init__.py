@@ -34,6 +34,8 @@ __version__ = "0.2.0"
 from analytics_zoo_tpu.core.config import ZooConfig  # noqa: F401
 from analytics_zoo_tpu.core.context import (  # noqa: F401
     ZooContext,
+    describe_devices,
+    enable_compile_cache,
     get_zoo_context,
     init_zoo_context,
 )

@@ -37,8 +37,8 @@ class ZooConfig:
 
     # --- training --------------------------------------------------------
     # Steps fused into one XLA dispatch (lax.scan over a device-resident
-    # superbatch).  >1 amortizes per-step host->device latency — essential
-    # on remote-tunnel links, and still removes dispatch overhead on-host.
+    # superbatch).  >1 removes the per-step dispatch and host->device
+    # overhead from the loop.
     steps_per_execution: int = 1
     # Failure-retry semantics of InternalDistriOptimizer.train
     # (reference Topology.scala:1179-1261).

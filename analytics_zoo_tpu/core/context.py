@@ -174,6 +174,40 @@ def init_zoo_context(
     return _GLOBAL_CONTEXT
 
 
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache() -> str:
+    """Place JAX's persistent compilation cache and return its directory.
+
+    The directory is part of the cache key, so it must not move between
+    runs: where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself
+    and no directory is set in code; otherwise the cache lives at the
+    fixed, git-ignored ``<checkout>/.jax_cache``.  Entry points (the chip
+    smoke, the benchmark, the loadgen server) call this once, BEFORE the
+    first compilation — JAX binds the cache on first use and ignores a
+    later change.  Never run on import.
+    """
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(_CHECKOUT, ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir
+
+
+def describe_devices() -> dict:
+    """What jax runs on, as jax reports it — the ``device`` object every
+    result, status file and benchmark row carries so that a CPU run can
+    never pass for a chip run."""
+    import jax
+
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
+
+
 def _initialize_distributed(config: ZooConfig, coordinator_address,
                             num_processes, process_id) -> bool:
     """Join (or start) the jax.distributed coordination service, with
@@ -469,18 +503,15 @@ def make_mesh(devices, mesh_shape, axis_names) -> "jax.sharding.Mesh":
     # ICI-topology-aware device placement: on real TPU slices
     # mesh_utils orders devices so the minor mesh axes ride physical
     # ICI rings (collectives on the model/expert axis stay on-chip
-    # links instead of hopping the torus).  Falls back to a plain
-    # reshape on CPU meshes / single hosts where it doesn't apply.
-    dev_array = None
-    if devices and getattr(devices[0], "platform", "") == "tpu":
-        try:
-            from jax.experimental import mesh_utils
+    # links instead of hopping the torus).  A mesh shape the physical
+    # topology cannot carry raises here; CPU meshes have no topology and
+    # take a plain reshape.
+    if devices[0].platform == "tpu":
+        from jax.experimental import mesh_utils
 
-            dev_array = mesh_utils.create_device_mesh(
-                tuple(mesh_shape), devices=devices)
-        except Exception:
-            dev_array = None
-    if dev_array is None:
+        dev_array = mesh_utils.create_device_mesh(
+            tuple(mesh_shape), devices=devices)
+    else:
         dev_array = np.asarray(devices).reshape(mesh_shape)
     return Mesh(dev_array, tuple(axis_names))
 

@@ -30,6 +30,15 @@ quarantined to ``<file>.corrupt`` and the caller falls back to a clean
 recompile.  Writes are atomic (tmp + ``os.replace``) so a crash
 mid-store never leaves a half-written entry under the real name.
 
+Every entry records the devices its program was lowered for and is
+loaded onto exactly those (jax would otherwise assume every backend
+device).  Known limit, seen on a four-chip v5e host: the TPU client of
+jax 0.9.0 / libtpu 0.0.34 binds every restored one-device program to
+device 0 whatever it is asked (and reports the asked device), so a
+per-device replica on chips 1..n cannot warm-start there — its first
+dispatch raises.  One replica on chip 0, the serving default, is
+unaffected (PERF.md, open questions).
+
 Every outcome is counted in
 ``serving_compile_cache_events_total{event=hit|miss|corrupt|version_skew}``
 with the owning model as a ``model`` label (docs/OBSERVABILITY.md).
@@ -79,6 +88,15 @@ def cache_env() -> Dict[str, str]:
         "jaxlib": getattr(jaxlib, "__version__", "unknown"),
         "mesh": f"{devs[0].platform}x{len(devs)}",
     }
+
+
+def _assignment(sharding) -> list:
+    """The devices of one input sharding in device-assignment order: a
+    mesh's flat device order, else (one device, or no mesh) by id."""
+    mesh = getattr(sharding, "mesh", None)
+    if mesh is not None:
+        return list(mesh.devices.flat)
+    return sorted(sharding.device_set, key=lambda d: d.id)
 
 
 class CompileCache:
@@ -140,13 +158,23 @@ class CompileCache:
         """Serialize one compiled executable; atomic overwrite-in-place
         (version-skewed or stale entries at the same digest are simply
         replaced).  Returns the entry path."""
+        import jax
         from jax.experimental import serialize_executable
 
         blob, in_tree, out_tree = serialize_executable.serialize(compiled)
         payload = pickle.dumps((blob, in_tree, out_tree),
                                protocol=pickle.HIGHEST_PROTOCOL)
+        # the devices the program was lowered for, in assignment order:
+        # jax's deserialize_and_load otherwise assumes EVERY backend
+        # device, and a one-device replica program loaded on a four-chip
+        # host then demands four argument shards.  Read from the input
+        # shardings, not the runtime executable: on TPU a one-device
+        # program is loaded portable and reports the default device.
+        device_ids = [d.id for d in _assignment(
+            jax.tree_util.tree_leaves(compiled.input_shardings)[0])]
         header = dict(fingerprint=fingerprint, sig=sig, model=model,
-                      created=time.time(), **cache_env())
+                      created=time.time(), device_ids=device_ids,
+                      **cache_env())
         hdr = json.dumps(header, sort_keys=True).encode("utf-8")
         crc = zlib.crc32(payload) & 0xFFFFFFFF
         path = self.path_for(fingerprint, sig)
@@ -217,12 +245,16 @@ class CompileCache:
         return (header.get("jax") == env["jax"]
                 and header.get("jaxlib") == env["jaxlib"])
 
-    def _deserialize(self, payload: bytes):
+    @staticmethod
+    def _deserialize(header: Dict[str, Any], payload: bytes):
+        import jax
         from jax.experimental import serialize_executable
 
+        by_id = {d.id: d for d in jax.devices()}
         blob, in_tree, out_tree = pickle.loads(payload)
         return serialize_executable.deserialize_and_load(
-            blob, in_tree, out_tree)
+            blob, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in header["device_ids"]])
 
     def load(self, fingerprint: str, sig: Dict[str, Any],
              model: str = "default"):
@@ -249,7 +281,7 @@ class CompileCache:
                 cache_env()["jax"])
             return None
         try:
-            compiled = self._deserialize(payload)
+            compiled = self._deserialize(header, payload)
         except Exception as e:
             # structurally intact but undeserializable (e.g. an XLA
             # build mismatch the version header didn't capture)
@@ -278,7 +310,7 @@ class CompileCache:
                 self._event("version_skew", model)
                 continue
             try:
-                compiled = self._deserialize(payload)
+                compiled = self._deserialize(header, payload)
             except Exception as e:
                 self._quarantine(path, model, f"deserialize failed: {e}")
                 continue

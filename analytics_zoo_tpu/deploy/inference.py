@@ -611,14 +611,12 @@ class InferenceModel:
         not multi-device.
         """
         if devices is None:
+            from analytics_zoo_tpu.core import context as _context
             from analytics_zoo_tpu.parallel.sharding import replica_devices
 
-            try:
-                from analytics_zoo_tpu.core.context import _GLOBAL_CONTEXT
-                devices = (replica_devices(_GLOBAL_CONTEXT.mesh)
-                           if _GLOBAL_CONTEXT is not None else jax.devices())
-            except Exception:
-                devices = jax.devices()
+            ctx = _context._GLOBAL_CONTEXT
+            devices = (replica_devices(ctx.mesh) if ctx is not None
+                       else jax.devices())
         devices = list(devices)[:max(1, int(n))]
         if self._net is None:
             # shared-forward fallback: predict() handles buckets/top-N

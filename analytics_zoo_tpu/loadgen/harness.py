@@ -367,9 +367,26 @@ def run_open_loop_check(qps: float = 50.0, duration_s: float = 2.0,
 # -- the kill leg: a real server process dies mid-storm ---------------------
 
 def _loadgen_env() -> Dict[str, str]:
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    return env
+    """The server child's environment: the parent's, platform included
+    (``JAX_PLATFORMS`` is inherited, never defaulted), minus the
+    parent's ``XLA_FLAGS`` (a child sizes its own virtual devices)."""
+    return {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+
+
+def _require_chip_free() -> None:
+    """One process per chip: a parent that has initialised an
+    accelerator backend holds the chip, and a server child that needs
+    it then fails or hangs — refuse to start one."""
+    import jax
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized() \
+            and jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"this process already holds the {jax.default_backend()} "
+            "backend; a server child cannot share the chip.  Run the "
+            "process legs before any in-process JAX work (default_report "
+            "does), or run them from a process that stays off JAX")
 
 
 def start_server_process(spool: str, cache_dir: str, status_file: str,
@@ -384,6 +401,7 @@ def start_server_process(spool: str, cache_dir: str, status_file: str,
             "--status-file", status_file,
             "--slo-p99-ms", str(slo_ms)]
     argv += list(extra_args or [])
+    _require_chip_free()
     logf = open(log_path, "w")
     try:
         return subprocess.Popen(argv, env=_loadgen_env(), stdout=logf,
@@ -688,6 +706,15 @@ def default_report(workdir: str, quick: bool = False) -> Dict[str, Any]:
             "quick": bool(quick),
         },
     }
+    # the process legs run FIRST, while this process has not touched a
+    # backend: their server children own the device for as long as they
+    # live (one process per chip); the in-process legs take it afterwards
+    report["kill"] = run_kill_leg(os.path.join(workdir, "kill"),
+                                  duration_s=22.0 * scale,
+                                  kill_at_s=7.0 * scale)
+    report["pod_kill"] = run_pod_kill_leg(
+        os.path.join(workdir, "pod_kill"), duration_s=16.0 * scale,
+        kill_at_s=6.0 * scale, tail_duration_s=8.0 * scale)
     report["steady"] = run_steady_leg(duration_s=8.0 * scale)
     report["burst"] = run_burst_leg(duration_s=14.0 * scale,
                                     at_s=3.0 * scale, dur_s=2.0 * scale)
@@ -695,10 +722,8 @@ def default_report(workdir: str, quick: bool = False) -> Dict[str, Any]:
                                             shift_at_s=6.0 * scale)
     report["adversarial"] = run_adversarial_leg()
     report["open_loop"] = run_open_loop_check()
-    report["kill"] = run_kill_leg(os.path.join(workdir, "kill"),
-                                  duration_s=22.0 * scale,
-                                  kill_at_s=7.0 * scale)
-    report["pod_kill"] = run_pod_kill_leg(
-        os.path.join(workdir, "pod_kill"), duration_s=16.0 * scale,
-        kill_at_s=6.0 * scale, tail_duration_s=8.0 * scale)
+    from analytics_zoo_tpu.core.context import describe_devices
+
+    # what the in-process legs actually ran on
+    report["run_metadata"]["device"] = describe_devices()
     return report

@@ -202,10 +202,17 @@ def _dump_status(path: str, payload: Dict[str, Any]) -> None:
 def main(argv=None) -> int:
     args = parse_args(argv)
     if args.local_devices:
+        # virtual devices exist on the CPU platform only: asking for
+        # them IS asking for the CPU (two pod members on one host cannot
+        # share a chip); the status file says so under "device"
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count="
               f"{args.local_devices}").strip()
+    from analytics_zoo_tpu.core.context import (describe_devices,
+                                                enable_compile_cache)
+    enable_compile_cache()
     if args.pod_processes > 1:
         from analytics_zoo_tpu import init_zoo_context
         init_zoo_context(
@@ -279,12 +286,15 @@ def main(argv=None) -> int:
         for b in model.batch_buckets:
             srep.harvest(srep.dispatch([xcov[:b]]))
 
+    device = describe_devices()
+
     def status_payload() -> Dict[str, Any]:
         h = srv.health()
         audit = srv.autoscale_audit()
         return {
             "ready": True,
             "pid": os.getpid(),
+            "device": device,
             "t": time.time(),
             "fingerprint": model.fingerprint(),
             "compile_count": int(model.compile_count),

@@ -20,9 +20,9 @@ never differentiates this, but the parity suites do; the backward is the
 pure-JAX ``dx = g @ dequant(w).T`` (materialising f32 weights is fine off
 the hot path), with ``float0``/zero cotangents for ``q``/``scale``.
 
-Backends without pallas are routed to ``dequant_matmul_reference`` by
-``ops.dispatch.select_path``; off-TPU the kernel runs under
-``interpret=True`` in tests.
+Off-TPU, and for the K the kernel's blocking cannot tile (``_BLOCK_K``),
+``ops.dispatch.select_path`` takes ``dequant_matmul_reference``; off-TPU
+the kernel runs only under the ``interpret=True`` the tests pass.
 """
 
 from __future__ import annotations
@@ -34,17 +34,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific memory spaces; absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from analytics_zoo_tpu.ops import dispatch
 
 BITS = (8, 4)
+# K is blocked in tiles of at most this many rows (halved until they divide
+# K).  Mosaic takes a block whose lane dim is a multiple of 128 or the whole
+# dim, so K must either fit one block or be a multiple of 128: K=1000 picks
+# an 8-wide block and is refused ("last two dimensions of your block shape
+# are divisible by 8 and 128").  Pinned by tests/test_dequant_matmul.py.
+_BLOCK_K = 512
 
 
 def pack_int4(q4):
@@ -151,10 +151,6 @@ def _pad_to(a, dim: int, size: int, value=0):
 
 
 def _dq_forward(x, q, scale, bits, rows, interpret):
-    if pltpu is None:  # pragma: no cover
-        raise ImportError(
-            "pallas TPU support unavailable; dequant_matmul should have "
-            "been routed to dequant_matmul_reference by ops.dispatch")
     m, k = x.shape
     n = q.shape[1]
     k_store = 2 * q.shape[0] if bits == 4 else q.shape[0]
@@ -167,7 +163,7 @@ def _dq_forward(x, q, scale, bits, rows, interpret):
     # block so index maps stay dense
     bm = _pick_block(128, ((m + 7) // 8) * 8)
     bn = _pick_block(128, ((n + 127) // 128) * 128)
-    bk = _pick_block(512, ((k_store + 1) // 2) * 2)
+    bk = _pick_block(_BLOCK_K, ((k_store + 1) // 2) * 2)
     if bk % 2:
         bk *= 2                          # int4 tiles cover whole bytes
     x = _pad_to(_pad_to(x, 0, bm), 1, bk)
@@ -187,7 +183,7 @@ def _dq_forward(x, q, scale, bits, rows, interpret):
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
-        scratch_shapes=[_VMEM((bm, bn), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(x, q, scale)
     return out[:m, :n]
@@ -228,17 +224,20 @@ def dequant_matmul(x, q, scale, bits: int = 8, rows: Optional[int] = None,
     disambiguates odd K); ``scale`` f32 per-output-channel, (N,) or
     (1, N).  Returns (..., N) in ``x.dtype``.
 
-    Dispatch: the Pallas kernel on TPU, the pure-JAX reference elsewhere;
-    ``interpret=True`` forces the kernel in interpreter mode (tests).
-    Differentiable wrt ``x`` on every path.
+    Dispatch: the Pallas kernel on TPU where its K blocking compiles
+    (``_BLOCK_K``), the pure-JAX reference elsewhere; ``interpret=True``
+    forces the kernel in interpreter mode (tests).  Differentiable wrt
+    ``x`` on every path.
     """
     if bits not in BITS:
         raise ValueError(f"bits must be one of {BITS}, got {bits}")
     k = x.shape[-1]
     lead = x.shape[:-1]
+    k_store = (2 if bits == 4 else 1) * q.shape[0]
     path = dispatch.select_path(
         "dequant_matmul",
-        shapes_ok=q.ndim == 2,
+        shapes_ok=q.ndim == 2 and (k_store <= _BLOCK_K
+                                   or k_store % 128 == 0),
         # tiny matmuls: XLA's fused dequant+dot already runs at latency,
         # the kernel pays off once weights are HBM-resident
         min_work_met=q.size >= 256 * 256,

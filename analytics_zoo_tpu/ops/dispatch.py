@@ -3,8 +3,10 @@
 Every hand-written kernel in this package (flash_attention, embedding_bag,
 dequant_matmul) faces the same three-way choice:
 
-- ``"pallas"``     — compiled Mosaic kernel; requires a TPU backend, the
-  TPU pallas extensions importable, and kernel-specific shape limits met.
+- ``"pallas"``     — compiled Mosaic kernel; requires a TPU backend and
+  the kernel's shape limits met.  ``shapes_ok`` must be False for every
+  shape Mosaic refuses: a refused shape is excluded here, at selection
+  time, never caught around ``pallas_call`` at run time.
 - ``"interpret"``  — the same kernel run under ``pallas_call(interpret=
   True)``; bit-faithful to the kernel's math on any backend, used by the
   CPU test tier and debugging (never auto-selected: it is orders of
@@ -26,20 +28,10 @@ from typing import Optional
 
 import jax
 
-try:  # TPU-specific pallas extensions; absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as _pltpu
-except Exception:  # pragma: no cover
-    _pltpu = None
-
 PATH_PALLAS = "pallas"
 PATH_INTERPRET = "interpret"
 PATH_REFERENCE = "reference"
 _PATHS = (PATH_PALLAS, PATH_INTERPRET, PATH_REFERENCE)
-
-
-def pallas_available() -> bool:
-    """True when the TPU pallas extensions imported (compiled or interpret)."""
-    return _pltpu is not None
 
 
 def on_tpu() -> bool:
@@ -75,7 +67,8 @@ def select_path(kernel: str, *, shapes_ok: bool = True,
                 force: Optional[str] = None) -> str:
     """The one backend-routing predicate shared by the ops/ kernels.
 
-    ``shapes_ok``     kernel-specific hard limits (tile divisibility,
+    ``shapes_ok``     kernel-specific hard limits (what Mosaic compiles:
+                      tile divisibility, dtype, scratch budgets; and
                       unsupported features like masks/dropout) — when
                       False the reference path is the only correct one.
     ``min_work_met``  the kernel only *wins* above some problem size;
@@ -95,7 +88,7 @@ def select_path(kernel: str, *, shapes_ok: bool = True,
             raise ValueError(f"unknown kernel path {force!r}; "
                              f"expected one of {_PATHS}")
         path = force
-    elif knob == "off" or not shapes_ok or not pallas_available():
+    elif knob == "off" or not shapes_ok:
         path = PATH_REFERENCE
     elif on_tpu() and (min_work_met or knob == "on"):
         path = PATH_PALLAS

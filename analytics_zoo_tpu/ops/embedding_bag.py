@@ -21,9 +21,13 @@ serialised per element, which keeps duplicate indices exact everywhere
 duplicates to recover DMA overlap.  ``ids`` take the documented
 ``float0`` zero cotangent.
 
-Backends without pallas are routed to ``embedding_bag_reference`` by
-``ops.dispatch.select_path`` (knob: ``ZooConfig.fused_embedding``);
-off-TPU the kernel runs under ``interpret=True`` in tests.
+Dispatch (``ops.dispatch.select_path``, knob ``ZooConfig.fused_embedding``):
+the compiled kernel is selected on TPU only for the shapes Mosaic accepts
+(``_mosaic_accepts``: float32 tables of width exactly 128, bags of at most
+128 ids); everything else — every table the bundled recommenders build
+(NCF's width-20 tables, Wide&Deep's ``class_num``-wide wide bag), every
+bf16-cast table — takes ``embedding_bag_reference``.  Off-TPU the kernel
+runs only under the explicit ``interpret=True`` the tests pass.
 """
 
 from __future__ import annotations
@@ -34,13 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific memory spaces; absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from analytics_zoo_tpu.ops import dispatch
 
@@ -48,6 +46,20 @@ COMBINERS = ("sum", "mean", "sqrtn")
 # out block is (_BAG_BLOCK, D): 8 bags per grid step keeps the f32 sublane
 # tile full while the SMEM ids block stays tiny (8·N int32 scalars)
 _BAG_BLOCK = 8
+# What Mosaic compiles for this kernel on v5e (jax 0.9.0 / libtpu 0.0.34);
+# each bound is the shape on the accepted side of a refusal, pinned by
+# tests/test_embedding_bag.py:
+# - the per-id row DMA slices ONE row out of the HBM table's (8, 128)
+#   tiling.  Width 20/64: "Slice shape along dimension 1 must be aligned
+#   to tiling (128)"; width 256+ and every 16-bit table (two rows per
+#   packed sublane): "Slice shape along dimension 0 must be aligned to
+#   tiling (8), but is 1".  Only float32 rows of exactly one lane tile
+#   pass.
+# - one DMA semaphore per in-flight row, double-buffered: (2, N).  N=256
+#   exhausts the semaphore space ("Ran out of memory in memory space
+#   sflag"); N=128 compiles.
+_ROW_WIDTH = 128
+_MAX_NNZ = 128
 
 
 def _check_args(table, ids, combiner):
@@ -121,11 +133,14 @@ def _fwd_kernel(ids_smem, ids_vmem, table_ref, out_ref, rows, sem, *,
             mask = jnp.ones((1, n), jnp.float32)
         else:
             mask = (ids_vmem[b, :] != pad_id).astype(jnp.float32)[None, :]
-        # masked combine as a (1, N) x (N, D) contraction: one MXU pass,
-        # no per-slot control flow
+        # masked combine as a (1, N) x (N, D) contraction on the MXU, no
+        # per-slot control flow.  HIGHEST: at the default precision the
+        # MXU rounds the f32 rows to bf16 (measured on v5e: 1.9e-3 off the
+        # reference); a lookup must return the table's values
         acc = jax.lax.dot_general(
             mask, rows[b % 2].astype(jnp.float32),
             (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)            # (1, D)
         if combiner != "sum":
             cnt = jnp.maximum(jnp.sum(mask), 1.0)
@@ -143,10 +158,6 @@ def _pad_bags(ids, pad_fill):
 
 
 def _bag_forward(table, ids, combiner, pad_id, interpret):
-    if pltpu is None:  # pragma: no cover
-        raise ImportError(
-            "pallas TPU support unavailable; embedding_bag should have "
-            "been routed to embedding_bag_reference by ops.dispatch")
     vocab, dim = table.shape
     ids = ids.astype(jnp.int32)
     # padded bags gather row 0 and are sliced off; with a pad_id they are
@@ -163,12 +174,12 @@ def _bag_forward(table, ids, combiner, pad_id, interpret):
             pl.BlockSpec((_BAG_BLOCK, n), lambda i: (i, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((_BAG_BLOCK, n), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # table stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),      # table stays in HBM
         ],
         out_specs=pl.BlockSpec((_BAG_BLOCK, dim), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b_pad, dim), table.dtype),
         scratch_shapes=[
-            _VMEM((2, n, dim), table.dtype),        # double-buffered rows
+            pltpu.VMEM((2, n, dim), table.dtype),   # double-buffered rows
             pltpu.SemaphoreType.DMA((2, n)),
         ],
         interpret=interpret,
@@ -220,12 +231,12 @@ def _bag_backward(table_shape, table_dtype, ids, g_scaled, pad_id,
             pl.BlockSpec((_BAG_BLOCK, n), lambda i: (i, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((_BAG_BLOCK, dim), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((vocab, dim), jnp.float32),
         scratch_shapes=[
-            _VMEM((1, dim), jnp.float32),
+            pltpu.VMEM((1, dim), jnp.float32),
             pltpu.SemaphoreType.DMA((1,)),
         ],
         input_output_aliases={2: 0},        # accumulate into the zeros
@@ -370,6 +381,13 @@ def dedup_wanted(*, sharded: bool) -> bool:
 # public entry
 
 
+def _mosaic_accepts(table, max_nnz: int) -> bool:
+    """True for the shapes the compiled kernel accepts (see ``_ROW_WIDTH``
+    / ``_MAX_NNZ``) — auto-dispatch must never select one it refuses."""
+    return (table.dtype == jnp.float32 and table.shape[0] >= 1
+            and table.shape[1] == _ROW_WIDTH and 1 <= max_nnz <= _MAX_NNZ)
+
+
 def embedding_bag(table, ids, combiner: str = "sum", pad_id=0,
                   interpret: bool = False):
     """Fused multi-hot lookup: ``combine_j table[ids[b, j]]`` per bag.
@@ -380,15 +398,16 @@ def embedding_bag(table, ids, combiner: str = "sum", pad_id=0,
     is ``"sum" | "mean" | "sqrtn"`` over each bag's valid slots.
     Out-of-range ids clip, matching ``jnp.take``.
 
-    Dispatch: the Pallas kernel on TPU (``fused_embedding`` knob:
-    auto/on/off), the pure-JAX reference elsewhere; ``interpret=True``
-    forces the kernel in interpreter mode (tests).  Differentiable wrt
-    ``table`` on every path.
+    Dispatch: the Pallas kernel on TPU for the shapes it compiles at
+    (``_mosaic_accepts``; ``fused_embedding`` knob: auto/on/off), the
+    pure-JAX reference elsewhere; ``interpret=True`` forces the kernel
+    in interpreter mode (tests).  Differentiable wrt ``table`` on every
+    path.
     """
     _check_args(table, ids, combiner)
     path = dispatch.select_path(
         "embedding_bag",
-        shapes_ok=table.shape[0] >= 1,
+        shapes_ok=_mosaic_accepts(table, ids.shape[1]),
         # below ~4k rows the whole table sits happily in cache/VMEM and
         # XLA's gather wins; the DMA kernel pays off once the table is
         # HBM-resident
@@ -416,6 +435,7 @@ def embedding_gather(table, ids, interpret: bool = False):
         raise ValueError(f"table must be (vocab, dim), got {table.shape}")
     path = dispatch.select_path(
         "embedding_gather",
+        shapes_ok=_mosaic_accepts(table, 1),
         min_work_met=table.shape[0] >= 4096,
         knob=dispatch.config_knob("fused_embedding", "auto"),
         force=dispatch.PATH_INTERPRET if interpret else None,

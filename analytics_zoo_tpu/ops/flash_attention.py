@@ -13,10 +13,9 @@ HAND-WRITTEN Pallas backward kernels (the FlashAttention-2 recipe): the
 forward additionally emits the per-row logsumexp, the backward recomputes
 the probability tiles from (q, k, lse) in VMEM — no (Lq, Lk) matrix ever
 materialises — and two kernels accumulate dQ (grid over KV blocks) and
-dK/dV (grid over Q blocks) in f32 scratch.  flash_attention requires
-pallas end-to-end (fwd and bwd); backends without it are routed to the
-pure-JAX blockwise path by ``dot_product_attention``'s dispatch.
-Off-TPU the kernels run in interpreter mode under tests.
+dK/dV (grid over Q blocks) in f32 scratch.  Off-TPU
+``dot_product_attention``'s dispatch takes the pure-JAX blockwise path;
+the kernels run there only in interpreter mode under tests.
 """
 
 from __future__ import annotations
@@ -27,13 +26,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific memory spaces; absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -126,11 +119,6 @@ def _blocks(q, k, block_q, block_k):
     bk = _pick_block(block_k, lk)
     assert lq % bq == 0 and lk % bk == 0, (
         f"sequence lengths ({lq},{lk}) must divide blocks ({bq},{bk})")
-    if _VMEM is None:
-        raise ImportError(
-            "jax.experimental.pallas.tpu unavailable — use "
-            "ops.attention.blockwise_attention (dot_product_attention "
-            "dispatches there automatically)")
     return b, h, lq, lk, d, bq, bk
 
 
@@ -151,9 +139,9 @@ def _flash_fwd(q, k, v, sm_scale: float, causal: bool,
         pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
     ]
     scratch = [
-        _VMEM((bq, 128), jnp.float32),
-        _VMEM((bq, 128), jnp.float32),
-        _VMEM((bq, d), jnp.float32),
+        pltpu.VMEM((bq, 128), jnp.float32),
+        pltpu.VMEM((bq, 128), jnp.float32),
+        pltpu.VMEM((bq, d), jnp.float32),
     ]
     o_spec = pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0))
     if with_lse:
@@ -305,7 +293,7 @@ def _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k,
         in_specs=[q_spec3, k_spec3, k_spec3, q_spec3, row_spec3, row_spec3],
         out_specs=q_spec3,
         out_shape=jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
-        scratch_shapes=[_VMEM((bq, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
     )(qf, kf, vf, dof, lse8, delta8)
 
@@ -319,8 +307,8 @@ def _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k,
         out_specs=[k_specK, k_specK],
         out_shape=[jax.ShapeDtypeStruct((b * h, lk, d), k.dtype),
                    jax.ShapeDtypeStruct((b * h, lk, d), v.dtype)],
-        scratch_shapes=[_VMEM((bk, d), jnp.float32),
-                        _VMEM((bk, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
     )(qf, kf, vf, dof, lse8, delta8)
     return (dq.reshape(b, h, lq, d), dk.reshape(b, h, lk, d),
@@ -352,9 +340,6 @@ def _fwd_rule(q, k, v, causal, sm_scale, block_q, block_k, interpret):
 
 
 def _bwd_rule(causal, sm_scale, block_q, block_k, interpret, res, g):
-    # (no blockwise fallback here: if pallas were unavailable the
-    # FORWARD would already have raised — non-pallas backends are routed
-    # to blockwise_attention by dot_product_attention's dispatch)
     q, k, v, out, lse = res
     scale = sm_scale if sm_scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     return _flash_bwd(q, k, v, out, lse, g, scale, causal, block_q,

@@ -63,17 +63,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from analytics_zoo_tpu.ops import dispatch
 from analytics_zoo_tpu.ops.attention import (blockwise_attention,
                                              online_softmax_fold)
-
-try:  # jax >= 0.8
-    from jax import shard_map  # type: ignore
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 NEG_INF = -1e30
 
@@ -95,11 +90,7 @@ def _vary_like(x, axis_name, ref):
     # lazy: parallel.sequence imports ops.attention, so a top-level import
     # here would close a cycle through ops/__init__ during package init
     from analytics_zoo_tpu.parallel.sequence import mark_varying
-    try:
-        axes = tuple(jax.typeof(ref).vma | {axis_name})
-    except (AttributeError, TypeError):
-        axes = axis_name
-    return mark_varying(x, axes)
+    return mark_varying(x, tuple(jax.typeof(ref).vma | {axis_name}))
 
 
 def _hop_masks(i, src, lq, lk, causal, valid_len, total_len):
@@ -360,6 +351,11 @@ def ring_attention(q, k, v, *, mesh: Optional[Mesh] = None,
     ring_ok = ways > 1 and l >= ways
     pad = (-l) % ways if ring_ok else 0
     kernel_ok = ring_ok and (pad == 0 or causal)
+    # Mosaic refuses the hop kernel's (1, 8, block_q) logsumexp block
+    # unless block_q is a lane multiple ("last two dimensions of your
+    # block shape are divisible by 8 and 128"): a 1032-token shard picks
+    # block_q=8.  Auto-dispatch keeps such shards on the pure-JAX hops.
+    compiles = kernel_ok and ((l + pad) // ways) % 128 == 0
 
     if force in (dispatch.PATH_PALLAS, dispatch.PATH_INTERPRET) \
             and not kernel_ok:
@@ -370,7 +366,7 @@ def ring_attention(q, k, v, *, mesh: Optional[Mesh] = None,
     if knob is None:
         knob = dispatch.config_knob("ring_attention", "auto")
 
-    path = dispatch.select_path("ring_attention", shapes_ok=kernel_ok,
+    path = dispatch.select_path("ring_attention", shapes_ok=compiles,
                                 min_work_met=l >= RING_MIN_LEN,
                                 knob=knob, force=force)
 
@@ -390,16 +386,9 @@ def ring_attention(q, k, v, *, mesh: Optional[Mesh] = None,
     spec = P(batch_axis, None, axis, None)
     shard_fn = lambda qs, ks, vs: _ring_shard(
         qs, ks, vs, axis, ways, causal, scale, block_q, block_k, path, l)
-    sm_kw = dict(mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
-    if path in (dispatch.PATH_PALLAS, dispatch.PATH_INTERPRET):
-        # pallas_call has no replication rule; the kernel hops are
-        # verified element-exact against the pure-JAX ring by tests
-        sm_kw["check_rep"] = False
-    try:
-        fn = shard_map(shard_fn, **sm_kw)
-    except TypeError:  # pragma: no cover — newer jax renamed the flag
-        sm_kw.pop("check_rep", None)
-        sm_kw["check_vma"] = False
-        fn = shard_map(shard_fn, **sm_kw)
-    out = fn(q, k, v)
+    # pallas_call has no replication rule; the kernel hops are verified
+    # element-exact against the pure-JAX ring by tests
+    kernel_hops = path in (dispatch.PATH_PALLAS, dispatch.PATH_INTERPRET)
+    out = shard_map(shard_fn, mesh=mesh, in_specs=(spec, spec, spec),
+                    out_specs=spec, check_vma=not kernel_hops)(q, k, v)
     return out[:, :, :l] if pad else out

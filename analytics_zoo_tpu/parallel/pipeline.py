@@ -36,15 +36,10 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from analytics_zoo_tpu.parallel.sequence import mark_varying as _pvary
-
-try:  # jax >= 0.8
-    from jax import shard_map  # type: ignore
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 StageFn = Callable[[Any, jax.Array], jax.Array]
 

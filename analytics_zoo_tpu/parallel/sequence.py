@@ -35,22 +35,13 @@ def mark_varying(x, axis_name):
     types match axis-dependent loop outputs.  Shared by ring attention
     and the pipeline schedule."""
     axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
-    try:
-        # only mark axes the value is not already varying over (pcast
-        # rejects mixed varying/invarying inputs)
-        cur = jax.typeof(x).vma
-        axes = tuple(a for a in axes if a not in cur)
-    except (AttributeError, TypeError):
-        pass
+    # only mark axes the value is not already varying over (pcast rejects
+    # mixed varying/invarying inputs)
+    cur = jax.typeof(x).vma
+    axes = tuple(a for a in axes if a not in cur)
     if not axes:
         return x
-    try:
-        return lax.pcast(x, axes, to="varying")
-    except (AttributeError, TypeError):  # pragma: no cover — older jax
-        try:
-            return lax.pvary(x, axes)
-        except AttributeError:
-            return x
+    return lax.pcast(x, axes, to="varying")
 
 
 def ring_attention(q, k, v, axis_name: str, causal: bool = False,

@@ -232,8 +232,9 @@ def seq_mesh(ways: int, axis: str = "seq"):
     ``ops.ring_attention`` when no explicit sequence-parallel regime is
     active (nn/layers/attention.py).  Cached per (ways, axis): layer
     forwards run at trace time and must not rebuild meshes per call.
-    Returns None when fewer than ``ways`` devices exist (the caller
-    falls back to single-device attention).
+    Asking for more shards than there are devices raises: a model
+    configured for a ``ways``-chip ring must not quietly run its
+    attention on one chip.
     """
     import jax
     from jax.sharding import Mesh
@@ -244,7 +245,10 @@ def seq_mesh(ways: int, axis: str = "seq"):
         return got
     devs = jax.devices()
     if ways < 2 or len(devs) < ways:
-        return None
+        raise ValueError(
+            f"seq_shards={ways} needs a ring of at least 2 and at most "
+            f"{len(devs)} device(s) ({jax.default_backend()}); unset the "
+            "knob to run attention on one device")
     mesh = Mesh(np.asarray(devs[:ways]), (axis,))
     _SEQ_MESH_CACHE[key] = mesh
     return mesh
@@ -377,6 +381,15 @@ def replica_devices(mesh, axis: str = "data") -> list:
                     for a in mesh.axis_names)
         return list(np.atleast_1d(devs[idx]).ravel())
     return list(devs.ravel())
+
+
+def device_span(tree) -> int:
+    """How many devices the widest-placed array leaf of ``tree`` lives
+    on — what a smoke asserts to know a regime really spans the mesh."""
+    import jax
+
+    return max(len(leaf.sharding.device_set)
+               for leaf in jax.tree_util.tree_leaves(tree))
 
 
 def spec_str(arr) -> str:

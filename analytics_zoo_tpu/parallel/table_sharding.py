@@ -50,17 +50,13 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from analytics_zoo_tpu.observe import metrics as obs
 from analytics_zoo_tpu.parallel.sharding import (DataParallel,
                                                  ShardingStrategy,
                                                  path_str)
-
-try:  # jax >= 0.4.35 re-export
-    from jax import shard_map  # type: ignore
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 logger = logging.getLogger("analytics_zoo_tpu.parallel")
 
@@ -162,7 +158,7 @@ def sharded_bag(table, ids, combiner: str = "sum", pad_id=None, *,
         local, mesh=mesh,
         in_specs=(P(axis, None), P(batch_ax, None)),
         out_specs=P(batch_ax, None),
-        check_rep=False,
+        check_vma=False,
     )(table, ids)
 
 

@@ -459,7 +459,7 @@ class Estimator:
         def step(params, state, opt_state, rng, guard, xs, y):
             # rng is carried ON DEVICE and split inside the step — passing
             # a host step counter per step would cost a blocking scalar
-            # transfer (tens of ms over remote-tunnel links) per iteration
+            # transfer per iteration
             rng, sub = jax.random.split(rng)
 
             def lossf(p, rng=sub):

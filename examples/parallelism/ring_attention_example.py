@@ -36,8 +36,6 @@ def main():
         _ensure_devices(args.devices)
 
     import jax
-    if not args.real:
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh

@@ -20,7 +20,10 @@ The warm-start contract (docs/SERVING.md "Warm start & multi-model"):
   same function, so their program-shape sets can never disagree.
 """
 
+import json
 import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -304,3 +307,49 @@ def test_warm_restart_across_real_processes(tmp_path):
     assert warm["cache"]["events"].get("corrupt", 0) == 0
     for b, v in cold["pred_sums"].items():
         assert abs(warm["pred_sums"][b] - v) < 1e-3
+
+
+_REPLICA_RESTART = """
+import json, sys
+import numpy as np
+import jax
+from tests.test_compile_cache import _model, _trained_net
+from analytics_zoo_tpu.deploy import CompileCache
+
+net, x = _trained_net()
+m = _model(net).attach_compile_cache(CompileCache(sys.argv[1]))
+warmed = m.warm()
+sums = [float(np.asarray(rep.harvest(rep.dispatch([x[:b]]))[0]).sum())
+        for rep in m.replica_forwards(n=len(jax.devices()))
+        for b in m.batch_buckets]
+print("RESULT", json.dumps({"devices": len(jax.devices()),
+                            "warmed": int(warmed), "sums": sums,
+                            "compile_count": int(m.compile_count)}))
+"""
+
+
+def test_per_device_replicas_warm_start_on_a_multi_device_host(tmp_path):
+    """A restarted process on a four-device host runs every persisted
+    one-device replica program ON ITS OWN DEVICE with zero live compiles.
+    (jax's deserialize_and_load assumes every backend device unless told
+    otherwise: such a program then demanded four argument shards and the
+    restart died at its first dispatch — only ever visible across a real
+    process boundary, on more than one device.)"""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+
+    def run():
+        proc = subprocess.run(
+            [sys.executable, "-c", _REPLICA_RESTART, str(tmp_path / "xc")],
+            env=env, capture_output=True, text=True, timeout=300)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        line = [ln for ln in proc.stdout.splitlines()
+                if ln.startswith("RESULT ")][-1]
+        return json.loads(line[len("RESULT "):])
+
+    cold, warm = run(), run()
+    n = 4 * len(BUCKETS)
+    assert cold["devices"] == 4 and cold["compile_count"] == n
+    assert warm["warmed"] == n and warm["compile_count"] == 0
+    np.testing.assert_allclose(warm["sums"], cold["sums"], rtol=1e-5)

@@ -33,6 +33,23 @@ def test_context_custom_mesh():
     init_zoo_context()
 
 
+def test_tpu_mesh_that_the_topology_cannot_carry_is_loud(monkeypatch):
+    # make_mesh must not swallow a create_device_mesh error and reshape
+    from jax.experimental import mesh_utils
+
+    from analytics_zoo_tpu.core.context import make_mesh
+
+    class FakeTpu:
+        platform = "tpu"
+
+    def refuse(shape, devices):
+        raise ValueError(f"cannot place mesh {shape} on this topology")
+
+    monkeypatch.setattr(mesh_utils, "create_device_mesh", refuse)
+    with pytest.raises(ValueError, match="cannot place mesh"):
+        make_mesh([FakeTpu(), FakeTpu()], (2,), ("data",))
+
+
 def test_data_sharding(zoo_ctx):
     import jax
     import jax.numpy as jnp

@@ -135,6 +135,63 @@ def _trained_net(in_dim=12, out_dim=6):
     return net, x
 
 
+class TestMosaicShapeRules:
+    """The K-blocking rule: auto-dispatch on TPU selects the kernel only
+    where its K tiles are whole blocks Mosaic accepts (K within one
+    512-row block, or a multiple of 128) — path and counted series with
+    ``on_tpu`` patched to true, and the TPU compiler's verdict on each
+    side (tests/mosaic_aot.py)."""
+
+    @staticmethod
+    def _args(k, bits, m=32, n=1024):
+        rows = (k + 1) // 2 if bits == 4 else k
+        return ((m, k), jnp.float32), ((rows, n), jnp.int8), \
+            ((1, n), jnp.float32)
+
+    @pytest.mark.parametrize("bits", [8, 4])
+    @pytest.mark.parametrize("k,kernel", [
+        (512, True), (511, True), (1024, True), (640, True),
+        (513, False), (1000, False)])
+    def test_path_and_series(self, monkeypatch, bits, k, kernel):
+        from tests.mosaic_aot import selected_series, series
+
+        got = selected_series(
+            monkeypatch,
+            lambda x, q, s: dequant_matmul(x, q, s, bits=bits, rows=k),
+            *(jax.ShapeDtypeStruct(*a) for a in self._args(k, bits)))
+        assert got == series("dequant_matmul",
+                             "pallas" if kernel else "reference")
+
+    @pytest.mark.parametrize("bits", [8, 4])
+    def test_mosaic_compiles_both_sides_of_one_block(self, bits):
+        from analytics_zoo_tpu.ops.dequant_matmul import _dq
+        from tests.mosaic_aot import spec, tpu_compile
+
+        for k in (511, 640):
+            tpu_compile(lambda x, q, s: _dq(x, q, s, bits, k, False),
+                        *(spec(*a) for a in self._args(k, bits)))
+
+    def test_mosaic_refuses_an_untileable_k(self):
+        from analytics_zoo_tpu.ops.dequant_matmul import _dq
+        from tests.mosaic_aot import spec, tpu_compile
+
+        with pytest.raises(ValueError, match="divisible by 8 and 128"):
+            tpu_compile(lambda x, q, s: _dq(x, q, s, 8, 1000, False),
+                        *(spec(*a) for a in self._args(1000, 8)))
+
+    @pytest.mark.parametrize("bits", [8, 4])
+    def test_interpret_parity_past_one_block(self, bits):
+        # the smallest K the rule admits beyond a single block: two
+        # 320-row tiles, the int4 leg unpacking nibbles per tile
+        k = 640
+        w, q, s = _qcase(k, 128, bits, seed=7)
+        x = jnp.asarray(np.random.RandomState(8).randn(8, k)
+                        .astype(np.float32))
+        got = dequant_matmul(x, q, s, bits=bits, rows=k, interpret=True)
+        want = dequant_matmul_reference(x, q, s, bits=bits, rows=k)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+
+
 class TestServingWeightDtype:
     """The replica path: weights stored quantized end-to-end, Dense
     fusing the dequant into its matmul, top-1 stable vs float32."""

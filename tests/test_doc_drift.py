@@ -304,6 +304,34 @@ def _bench():
     return mod
 
 
+class TestBenchNeedsAChip:
+    """A measurement path that finds no TPU fails: no JSON line, no
+    per-chip metric; and a section that raised fails the run."""
+
+    def test_no_tpu_exits_nonzero_and_prints_no_metric(self, capsys):
+        import jax
+
+        b = _bench()
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            with pytest.raises(SystemExit) as exc:
+                b.main()
+        finally:    # main() placed the compile cache; nothing compiled
+            jax.config.update("jax_compilation_cache_dir", before)
+        assert exc.value.code == 1
+        out, err = capsys.readouterr()
+        assert out == "" and "measures a TPU" in err
+
+    def test_error_keys_finds_every_recorded_failure(self):
+        b = _bench()
+        extra = {"int8_error": "ValueError: x", "matmul_4096": {"ms": 1.0},
+                 "dlrm": {"child_error": "child rc=1: boom"},
+                 "restart": {"cold_error": "rc=2", "error": ""},
+                 "parity_max_err": 1e-6}
+        assert sorted(b._error_keys(extra)) == [
+            "dlrm.child_error", "int8_error", "restart.cold_error"]
+
+
 class TestBenchNonFiniteGuards:
     """The helpers that keep Infinity/NaN out of future artifacts."""
 

@@ -285,6 +285,113 @@ class TestDispatch:
         assert dispatch.config_knob("fused_embedding", "auto") == "auto"
 
 
+class TestMosaicShapeRules:
+    """``_mosaic_accepts``: auto-dispatch on TPU selects the compiled
+    kernel only where Mosaic accepts it (float32 rows of exactly 128
+    lanes, bags of at most 128 ids).  Each rule has a case on each side:
+    the path and counted series with ``on_tpu`` patched to true, and the
+    TPU compiler's own verdict on the same shape (tests/mosaic_aot.py)."""
+
+    # (V, D, table dtype, N, kernel path selected?)
+    CASES = [
+        pytest.param(4096, 128, jnp.float32, 4, True, id="f32-d128"),
+        pytest.param(4096, 64, jnp.float32, 4, False, id="narrow-row"),
+        pytest.param(6041, 20, jnp.float32, 4, False, id="ncf-row"),
+        pytest.param(4096, 256, jnp.float32, 4, False, id="wide-row"),
+        pytest.param(4096, 128, jnp.bfloat16, 4, False, id="bf16-row"),
+        pytest.param(4096, 128, jnp.float32, 128, True, id="n128"),
+        pytest.param(4096, 128, jnp.float32, 129, False, id="n129"),
+    ]
+
+    @pytest.mark.parametrize("v,d,dtype,n,kernel", CASES)
+    def test_bag_path_and_series(self, monkeypatch, v, d, dtype, n, kernel):
+        from tests.mosaic_aot import selected_series, series
+
+        got = selected_series(
+            monkeypatch, lambda t, i: embedding_bag(t, i, "sum", 0),
+            jax.ShapeDtypeStruct((v, d), dtype),
+            jax.ShapeDtypeStruct((8, n), jnp.int32))
+        assert got == series("embedding_bag",
+                             "pallas" if kernel else "reference")
+
+    @pytest.mark.parametrize("d,dtype,kernel", [
+        (128, jnp.float32, True), (20, jnp.float32, False),
+        (128, jnp.bfloat16, False)])
+    def test_gather_path_and_series(self, monkeypatch, d, dtype, kernel):
+        from tests.mosaic_aot import selected_series, series
+
+        got = selected_series(
+            monkeypatch, embedding_gather,
+            jax.ShapeDtypeStruct((6041, d), dtype),
+            jax.ShapeDtypeStruct((64, 1), jnp.int32))
+        assert got == series("embedding_gather",
+                             "pallas" if kernel else "reference")
+
+    def test_small_table_stays_on_reference(self, monkeypatch):
+        # min_work_met, not a shape rule: a table XLA keeps in cache
+        from tests.mosaic_aot import selected_series, series
+
+        got = selected_series(
+            monkeypatch, embedding_gather,
+            jax.ShapeDtypeStruct((4095, 128), jnp.float32),
+            jax.ShapeDtypeStruct((64, 1), jnp.int32))
+        assert got == series("embedding_gather", "reference")
+
+    @staticmethod
+    def _kernel_fwd_bwd(n):
+        from analytics_zoo_tpu.ops.embedding_bag import _bag
+
+        def loss(t, i):
+            return _bag(t, i, "mean", 0, False).astype(jnp.float32).sum()
+
+        return jax.value_and_grad(loss)
+
+    def test_mosaic_compiles_the_selected_shape(self):
+        from tests.mosaic_aot import spec, tpu_compile
+
+        tpu_compile(self._kernel_fwd_bwd(4), spec((4096, 128), jnp.float32),
+                    spec((16, 4), jnp.int32))
+
+    @pytest.mark.parametrize("d,dtype,why", [
+        (64, jnp.float32, r"dimension 1 must be aligned to tiling \(128\)"),
+        (256, jnp.float32, r"dimension 0 must be aligned to tiling \(8\)"),
+        (128, jnp.bfloat16, r"dimension 0 must be aligned to tiling \(8\)"),
+    ])
+    def test_mosaic_refuses_the_excluded_rows(self, d, dtype, why):
+        from tests.mosaic_aot import spec, tpu_compile
+
+        with pytest.raises(Exception, match=why):
+            tpu_compile(lambda t, i: self._kernel_fwd_bwd(4)(t, i)[0],
+                        spec((4096, d), dtype), spec((16, 4), jnp.int32))
+
+    @pytest.mark.slow
+    def test_mosaic_bag_length_limit(self):
+        # the unrolled per-row DMAs make these compiles take seconds
+        from analytics_zoo_tpu.ops.embedding_bag import _bag
+        from tests.mosaic_aot import spec, tpu_compile
+
+        fwd = lambda t, i: _bag(t, i, "sum", 0, False)
+        tpu_compile(fwd, spec((4096, 128), jnp.float32),
+                    spec((8, 128), jnp.int32))
+        with pytest.raises(Exception, match="sflag"):
+            tpu_compile(fwd, spec((4096, 128), jnp.float32),
+                        spec((8, 256), jnp.int32))
+
+    def test_interpret_parity_at_smallest_compiled_shape(self):
+        # one 128-lane row per id, one 8-bag block, forward and backward
+        table, ids = _mk(4096, 128, 8, 1, seed=11)
+
+        def loss(f):
+            return lambda t: jnp.sum(f(t) ** 2)
+
+        kern = lambda t: embedding_gather(t, ids, interpret=True)
+        ref = lambda t: jnp.take(t, ids, axis=0)
+        np.testing.assert_allclose(kern(table), ref(table), rtol=RTOL)
+        np.testing.assert_allclose(jax.grad(loss(kern))(table),
+                                   jax.grad(loss(ref))(table), rtol=RTOL,
+                                   atol=1e-6)
+
+
 class TestLayerWiring:
     def test_embedding_layer_output_unchanged(self, rng):
         from analytics_zoo_tpu.nn.layers.embedding import Embedding

@@ -417,6 +417,36 @@ class TestRunProcesses:
         assert env2["JAX_PLATFORMS"] == "cpu"
 
 
+class TestOneProcessPerChip:
+    """The harness's server children: the platform is inherited, never
+    defaulted to the CPU, and no child is started from a process that
+    already holds an accelerator (it could not share the chip)."""
+
+    def test_server_child_inherits_the_parents_platform(self, monkeypatch):
+        from analytics_zoo_tpu.loadgen.harness import _loadgen_env
+        monkeypatch.setenv("XLA_FLAGS", "--xla_whatever")
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        env = _loadgen_env()
+        assert env["JAX_PLATFORMS"] == "tpu" and "XLA_FLAGS" not in env
+        monkeypatch.delenv("JAX_PLATFORMS")
+        assert "JAX_PLATFORMS" not in _loadgen_env()
+
+    def test_no_server_child_once_this_process_holds_a_chip(
+            self, monkeypatch, tmp_path):
+        import jax
+
+        from analytics_zoo_tpu.loadgen import harness
+
+        jax.devices()                   # the backend is initialised
+        harness._require_chip_free()    # ... on the CPU: children allowed
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="already holds the tpu"):
+            harness.start_server_process(
+                str(tmp_path / "spool"), str(tmp_path / "cache"),
+                str(tmp_path / "status.json"), str(tmp_path / "log"))
+        assert not (tmp_path / "log").exists()      # nothing was launched
+
+
 class TestClientRecordMath:
     def test_latency_is_schedule_to_answer(self):
         """Coordinated-omission resistance lives in this definition:

@@ -183,6 +183,44 @@ class TestRingDispatch:
         assert not self._has_ppermute(l=RING_MIN_LEN, knob="off")
         assert not self._has_ppermute(l=RING_MIN_LEN, mesh=None)
 
+    @pytest.mark.parametrize("l,path", [
+        (4096, dispatch.PATH_PALLAS),       # 1024-token shards
+        (4128, dispatch.PATH_REFERENCE),    # 1032-token shards: block_q=8
+    ])
+    def test_tpu_auto_takes_kernel_hops_only_on_lane_multiples(
+            self, monkeypatch, l, path):
+        from tests.mosaic_aot import selected_series, series
+
+        mesh = _mesh(4)
+        shape = jax.ShapeDtypeStruct((1, 2, l, 128), jnp.bfloat16)
+        got = selected_series(
+            monkeypatch, lambda a, b, c: ring_attention(
+                a, b, c, mesh=mesh, causal=True), shape, shape, shape)
+        assert got == series("ring_attention", path)
+
+    def test_mosaic_verdict_on_each_side_of_the_shard_rule(self):
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        from tests.mosaic_aot import spec, tpu_compile, tpu_devices
+
+        mesh = Mesh(np.asarray(tpu_devices()), ("seq",))
+        seq = NamedSharding(mesh, P(None, None, "seq", None))
+        hops = lambda a, b, c: ring_attention(
+            a, b, c, mesh=mesh, causal=True, force=dispatch.PATH_PALLAS)
+        ok = spec((1, 2, 4096, 128), jnp.bfloat16, seq)
+        tpu_compile(hops, ok, ok, ok)
+        bad = spec((1, 2, 4128, 128), jnp.bfloat16, seq)
+        with pytest.raises(ValueError, match="divisible by 8 and 128"):
+            tpu_compile(hops, bad, bad, bad)
+
+    def test_more_seq_shards_than_devices_is_an_error(self):
+        # never a quiet fall back to single-device attention
+        from analytics_zoo_tpu.parallel.sharding import seq_mesh
+
+        assert seq_mesh(4).shape["seq"] == 4
+        with pytest.raises(ValueError, match="seq_shards=16 needs"):
+            seq_mesh(16)
+
     def test_force_kernel_without_mesh_rejected(self):
         q, k, v = _qkv(l=64, d=16)
         with pytest.raises(ValueError, match="needs a mesh"):
