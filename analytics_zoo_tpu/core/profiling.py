@@ -9,8 +9,9 @@ TPU-native design: two complementary mechanisms —
   a process-wide registry (mean/total/count per name), for spotting
   host-bound stages (data prep, device_put, checkpoint writes).
 - ``trace``: a context manager around ``jax.profiler`` that captures an
-  xprof/TensorBoard-viewable device trace; annotations via
-  ``jax.profiler.TraceAnnotation`` inside.
+  xprof/TensorBoard-viewable device trace.  Every stage the program
+  times through ``observe.metrics.time_stage`` shows in it as a
+  ``zoo:<metric>/<labels>`` host event, on the clock of the device's ops.
 """
 
 from __future__ import annotations
@@ -185,7 +186,7 @@ _active_trace_dir: Optional[str] = None
 
 
 @contextlib.contextmanager
-def trace(log_dir: str, annotation: Optional[str] = None) -> Iterator[None]:
+def trace(log_dir: str) -> Iterator[None]:
     """Capture a ``jax.profiler`` device trace into ``log_dir``
     (view with TensorBoard's profile plugin / xprof).
 
@@ -206,23 +207,10 @@ def trace(log_dir: str, annotation: Optional[str] = None) -> Iterator[None]:
     try:
         jax.profiler.start_trace(log_dir)
         started = True
-        if annotation:
-            with jax.profiler.TraceAnnotation(annotation):
-                yield
-        else:
-            yield
+        yield
     finally:
         with _trace_lock:
             _active_trace_dir = None
         if started:
             jax.profiler.stop_trace()
             logger.info("profiler trace written to %s", log_dir)
-
-
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named region that shows up on the device timeline inside a trace."""
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
-        yield

@@ -35,6 +35,8 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from analytics_zoo_tpu.core.profiling import TIMERS
 
 __all__ = ["CATALOG", "MetricsRegistry", "MetricsSnapshot", "METRICS",
@@ -195,9 +197,16 @@ CATALOG: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
     "prefetch_queue_depth": (
         "gauge", "batches queued ahead of the consumer in the prefetch "
         "pipeline", ()),
-    "prefetch_producer_stalls_total": (
-        "counter", "producer put() attempts that found the prefetch "
-        "queue full (consumer is the bottleneck)", ()),
+    "data_stage_seconds": (
+        "histogram", "host data tier, per batch, by stage: gather (the "
+        "source's next()) | upload (the transform: asarray + device_put) "
+        "| queue_full (producer blocked on a full prefetch queue; "
+        "stalled items only) | wait (consumer blocked on the queue)",
+        ("stage",)),
+    "data_upload_bytes_total": (
+        "counter", "host bytes handed to device_put under a batch "
+        "sharding (over data_stage_seconds{stage=upload}: the host "
+        "path's bytes/s)", ()),
     # ops/ kernel dispatch
     "ops_kernel_selected_total": (
         "counter", "kernel backend-routing decisions (trace-time, once "
@@ -439,8 +448,15 @@ def observe(name: str, seconds: float, flat: Optional[str] = None,
 
 @contextmanager
 def time_stage(name: str, flat: Optional[str] = None, **labels: Any):
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        observe(name, time.perf_counter() - t0, flat=flat, **labels)
+    """THE way the program times a stage: the interval is one sample in
+    the ``name{labels}`` histogram and, while a ``jax.profiler`` trace
+    runs, one host event ``zoo:<name>/<label values, sorted by key>`` on
+    the profiler's clock, beside the device's ``XLA Ops``.  With no
+    trace running the annotation is inert."""
+    tag = "/".join(["zoo:" + name, *(str(labels[k]) for k in sorted(labels))])
+    with TraceAnnotation(tag):
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            observe(name, time.perf_counter() - t0, flat=flat, **labels)

@@ -191,8 +191,8 @@ class Estimator:
         self.last_data_path: Optional[str] = None
         self.last_data_path_reason: Optional[str] = None
         # observability: the fit-level root span, the current epoch's
-        # child span (train/step spans parent under it), and the metric
-        # snapshot taken at fit() entry (training_report() deltas it)
+        # child span, and the metric snapshot taken at fit() entry
+        # (training_report() deltas it)
         self._fit_span = None
         self._epoch_span = None
         self._fit_metrics_mark = None
@@ -643,6 +643,7 @@ class Estimator:
         runtime assembles the global array without cross-host copies
         (every process must supply the same row count per step)."""
         TIMERS.incr("estimator/host_device_put", len(arrs))
+        obs.count("data_upload_bytes_total", sum(a.nbytes for a in arrs))
         if self.ctx.process_count > 1:
             return [jax.make_array_from_process_local_data(
                 shard, np.asarray(a)) for a in arrs]
@@ -880,9 +881,9 @@ class Estimator:
             self._resident_epoch = None
             self._stream_shard = None
         restore_sig = self._install_preempt_handler()
-        # fit-level root span + metric mark: every epoch/step span chains
-        # under this trace, and training_report() deltas the registry
-        # against the mark so it covers exactly this run
+        # fit-level root span + metric mark: every epoch and checkpoint
+        # span chains under this trace, and training_report() deltas the
+        # registry against the mark so it covers exactly this run
         self._fit_metrics_mark = obs.METRICS.snapshot()
         self._fit_span = TRACER.start("train/fit", epochs=epochs,
                                       batch_size=batch_size)
@@ -1097,21 +1098,14 @@ class Estimator:
             fn, k = self._multi_step, int(batch_y.shape[0])
         else:
             fn, k = self._train_step, 1
-        parent = self._epoch_span or self._fit_span
-        sp = (TRACER.start("train/step", trace=parent.trace,
-                           parent=parent.sid, kind=kind)
-              if parent is not None else None)
-        t0 = time.perf_counter()
-        (self.params, self.state, self.opt_state, self._rng,
-         self._guard, loss) = fn(self.params, self.state, self.opt_state,
-                                 self._rng, self._guard, batch_x, batch_y)
         # dispatch-side wall time: the carry returns while the device
         # still computes, so this is host dispatch latency, not step math
-        obs.observe("train_step_seconds", time.perf_counter() - t0,
-                    kind=kind)
+        with obs.time_stage("train_step_seconds", kind=kind):
+            (self.params, self.state, self.opt_state, self._rng,
+             self._guard, loss) = fn(self.params, self.state,
+                                     self.opt_state, self._rng,
+                                     self._guard, batch_x, batch_y)
         obs.count("train_steps_total", k, kind=kind)
-        if sp is not None:
-            sp.end(steps=k)
         self.global_step += k
         return k, loss
 

@@ -10,11 +10,25 @@ background thread while the device executes the current step.  JAX
 dispatch is asynchronous, so one batch of lookahead is enough to hide
 host work; the queue depth is the ``data_prefetch`` config knob.
 
-The consumer's blocked-on-queue time aggregates under the
-``prefetch/consumer_wait`` timer (core/profiling.TIMERS): a large total
-relative to step time means the input pipeline — not the device — is the
-bottleneck, which is exactly when the DEVICE cache level
-(data/featureset.CacheLevel) pays off.
+Both threads' time per batch lands in the registry histogram
+``data_stage_seconds{stage}`` (observe/metrics.py):
+
+- producer: ``gather`` (the source's ``next()``: batch assembly),
+  ``upload`` (the transform: ``asarray`` + ``device_put``) and
+  ``queue_full`` (blocked on a full queue; stalled items only) — the
+  three add up to its whole cycle.  ``gather`` and ``upload`` go through
+  ``time_stage``, so a running ``jax.profiler`` trace shows them as
+  ``zoo:data_stage_seconds/<stage>`` host events beside the device's ops;
+- consumer: ``wait`` (blocked on the queue).  A large ``wait`` total
+  relative to step time means the input pipeline — not the device — is
+  the bottleneck, which is exactly when the DEVICE cache level
+  (data/featureset.CacheLevel) pays off; ``queue_full`` is the inverse
+  signal.  The two waits are the absence of work and are kept off the
+  trace: they would overlap every idle gap whole and hide the stage
+  that caused it.
+
+Each end-of-stream probe (the ``next()`` that finds the source
+exhausted, the ``get`` that finds the sentinel) is one more sample.
 """
 
 from __future__ import annotations
@@ -22,9 +36,9 @@ from __future__ import annotations
 import logging
 import queue
 import threading
+import time
 from typing import Any, Callable, Iterable, Iterator, Optional
 
-from analytics_zoo_tpu.core.profiling import timeit
 from analytics_zoo_tpu.observe import metrics as obs
 from analytics_zoo_tpu.robust import faults
 
@@ -56,35 +70,45 @@ class PrefetchIterator:
 
         def put_retry(obj) -> bool:
             """Deliver unless the consumer called close(); never drop."""
-            stalled = False
+            stalled_at = None
             while not self._stop.is_set():
                 try:
-                    self._q.put(obj, timeout=0.1)
-                    # qsize() is advisory under concurrency, which is
-                    # fine for a gauge; the flat mirror keeps legacy
-                    # health() readers working
-                    obs.set_gauge("prefetch_queue_depth", self._q.qsize(),
-                                  flat="prefetch/queue_depth")
-                    return True
+                    # the first try does not block, so that a stall is
+                    # timed from its start
+                    self._q.put(obj, block=stalled_at is not None,
+                                timeout=0.1)
                 except queue.Full:
-                    if not stalled:
-                        # count once per item: the producer outran the
-                        # consumer by a full queue — the inverse signal
-                        # of prefetch/consumer_wait
-                        stalled = True
-                        obs.count("prefetch_producer_stalls_total",
-                                  flat="prefetch/producer_stalls")
+                    if stalled_at is None:
+                        # the producer outran the consumer by a full
+                        # queue: the inverse signal of stage="wait"
+                        stalled_at = time.perf_counter()
                     continue
+                if stalled_at is not None:
+                    obs.observe("data_stage_seconds",
+                                time.perf_counter() - stalled_at,
+                                stage="queue_full")
+                # qsize() is advisory under concurrency, which is fine
+                # for a gauge
+                obs.set_gauge("prefetch_queue_depth", self._q.qsize())
+                return True
             return False
 
         def run():
             try:
-                for item in it:
+                src = iter(it)
+                while True:
+                    with obs.time_stage("data_stage_seconds",
+                                        stage="gather"):
+                        item = next(src, _SENTINEL)
+                    if item is _SENTINEL:
+                        break
                     # chaos hook: a planned producer crash surfaces here
                     # exactly like a real data-pipeline failure would
                     faults.inject("prefetch.producer")
                     if transform is not None:
-                        item = transform(item)
+                        with obs.time_stage("data_stage_seconds",
+                                            stage="upload"):
+                            item = transform(item)
                     if not put_retry(item):
                         return
             except BaseException as e:  # propagate to consumer
@@ -111,10 +135,11 @@ class PrefetchIterator:
         # gone without its sentinel having been consumed (belt to the
         # suspenders above), surface its error / end-of-iteration instead
         # of hanging the training loop
-        with timeit("prefetch/consumer_wait"):
-            item = self._get()
-        obs.set_gauge("prefetch_queue_depth", self._q.qsize(),
-                      flat="prefetch/queue_depth")
+        t0 = time.perf_counter()
+        item = self._get()
+        obs.observe("data_stage_seconds", time.perf_counter() - t0,
+                    stage="wait")
+        obs.set_gauge("prefetch_queue_depth", self._q.qsize())
         if item is _SENTINEL:
             self._thread.join()
             err = self._error()
@@ -168,10 +193,9 @@ class PrefetchIterator:
             self._thread.join(timeout=0.05)
             if not self._thread.is_alive():
                 break
-            import time as _time
             if deadline is None:
-                deadline = _time.monotonic() + timeout
-            elif _time.monotonic() > deadline:
+                deadline = time.monotonic() + timeout
+            elif time.monotonic() > deadline:
                 logger.warning(
                     "prefetch producer did not stop within %.1fs of "
                     "close(); it is wedged in the source iterator or "
