@@ -23,6 +23,8 @@ from typing import Any, Callable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from analytics_zoo_tpu.data.gather import gather_rows
+
 MemoryType = str  # "DRAM" | "DISK_AND_DRAM" | "DIRECT"
 
 
@@ -259,29 +261,12 @@ class FeatureSet:
         steps = n // bs if drop_remainder else int(math.ceil(n / bs))
         for s in range(steps):
             idx = order[s * bs:(s + 1) * bs]
-            batch = tuple(self._gather(a, idx) for a in self.arrays)
+            batch = tuple(gather_rows(a, idx) for a in self.arrays)
             for fn in self.transforms:
                 batch = fn(*batch)
                 if not isinstance(batch, tuple):
                     batch = (batch,)
             yield batch
-
-    @staticmethod
-    def _gather(a: np.ndarray, idx: np.ndarray) -> np.ndarray:
-        """Batch assembly: parallel native row gather for big copies
-        (native/zoo_native.cpp — the MTSampleToMiniBatch role), numpy
-        fancy indexing otherwise."""
-        row_bytes = a.dtype.itemsize * int(np.prod(a.shape[1:], dtype=int))
-        if row_bytes * len(idx) >= (1 << 20) and a.flags["C_CONTIGUOUS"]:
-            try:
-                from analytics_zoo_tpu.native import (available,
-                                                      gather_rows)
-
-                if available():
-                    return gather_rows(a, idx)
-            except Exception:
-                pass
-        return np.asarray(a[idx])
 
     def read_rows(self, start: int, stop: int) -> List[np.ndarray]:
         """Row span [start, stop) of every backing array (views for DRAM

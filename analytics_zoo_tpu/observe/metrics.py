@@ -207,6 +207,13 @@ CATALOG: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
         "counter", "host bytes handed to device_put under a batch "
         "sharding (over data_stage_seconds{stage=upload}: the host "
         "path's bytes/s)", ()),
+    "data_gather_total": (
+        "counter", "arrays gathered into a batch by index "
+        "(data/gather.gather_rows), by the path read off the input: "
+        "inline (under 1 MiB, or under 4 CPUs: the fancy index on the "
+        "calling thread) | native (C-contiguous ndarray: the native "
+        "library's threaded memcpy) | threads (any other array-like: "
+        "pieces of the index on pool threads)", ("path",)),
     # ops/ kernel dispatch
     "ops_kernel_selected_total": (
         "counter", "kernel backend-routing decisions (trace-time, once "

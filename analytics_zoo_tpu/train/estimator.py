@@ -1111,6 +1111,8 @@ class Estimator:
 
     def _fit_arrays(self, x, y, batch_size, epochs, validation_data,
                     end_trigger, shuffle, verbose):
+        from analytics_zoo_tpu.data.gather import gather_rows
+
         xs = _as_list(x)
         assert y is not None, "y required for array training"
         n = xs[0].shape[0]
@@ -1191,6 +1193,13 @@ class Estimator:
                 idx = np.concatenate([idx, [n - 1]])
             return idx
 
+        def rows(a, sl):
+            # a host permutation's rows are copied by the data tier's one
+            # gather; a slice stays a view and a device permutation
+            # indexes on the device
+            return (gather_rows(a, sl) if isinstance(sl, np.ndarray)
+                    else a[sl])
+
         while epoch < epochs:
             batches = None
             try:
@@ -1250,9 +1259,10 @@ class Estimator:
                               if perm is None
                               else perm[ofs:ofs + K * eff_batch])
                         yield ("K",
-                               [a[sl].reshape((K, eff_batch) + a.shape[1:])
+                               [rows(a, sl).reshape(
+                                   (K, eff_batch) + a.shape[1:])
                                 for a in xs],
-                               y_arr[sl].reshape(
+                               rows(y_arr, sl).reshape(
                                    (K, eff_batch) + y_arr.shape[1:]))
                     for ri in range(rem):
                         s0 = n_chunks * K + ri
@@ -1261,7 +1271,8 @@ class Estimator:
                         ofs = s0 * eff_batch
                         sl = (slice(ofs, ofs + eff_batch) if perm is None
                               else perm[ofs:ofs + eff_batch])
-                        yield ("1", [a[sl] for a in xs], y_arr[sl])
+                        yield ("1", [rows(a, sl) for a in xs],
+                               rows(y_arr, sl))
 
                 def prep(item):
                     kind, bx, by = item

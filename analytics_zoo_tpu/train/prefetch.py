@@ -13,7 +13,11 @@ host work; the queue depth is the ``data_prefetch`` config knob.
 Both threads' time per batch lands in the registry histogram
 ``data_stage_seconds{stage}`` (observe/metrics.py):
 
-- producer: ``gather`` (the source's ``next()``: batch assembly),
+- producer: ``gather`` (the source's ``next()``: batch assembly, from
+  the call of ``data/gather.gather_rows`` until every row is in the
+  batch, whether this thread copied them or, for arrays of 1 MiB or
+  more, the native library's or the gather pool's threads did: such an
+  array-like is indexed from several threads at once),
   ``upload`` (the transform: ``asarray`` + ``device_put``) and
   ``queue_full`` (blocked on a full queue; stalled items only) — the
   three add up to its whole cycle.  ``gather`` and ``upload`` go through
