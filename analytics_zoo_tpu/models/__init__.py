@@ -14,6 +14,7 @@ from analytics_zoo_tpu.models.text import (  # noqa: F401
     mean_average_precision,
     ndcg,
 )
+from analytics_zoo_tpu.models.looped_lm import LoopedLM  # noqa: F401
 from analytics_zoo_tpu.models.seq2seq import (  # noqa: F401
     Bridge,
     RNNDecoder,

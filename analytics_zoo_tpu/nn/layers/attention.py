@@ -1,4 +1,6 @@
-"""Attention layers: MultiHeadAttention, TransformerLayer, BERT.
+"""Attention layers: MultiHeadAttention, TransformerLayer, BERT, and the
+pieces of a looped decoder (RotaryEmbedding, GatedFFN, SandwichDecoderBlock,
+LoopedDecoderStack).
 
 Reference capability: api/keras/layers/TransformerLayer.scala:56 (GPT-style
 decoder stack: token+position embedding, n blocks of attention+FFN with
@@ -28,17 +30,22 @@ from analytics_zoo_tpu.parallel.mode import (
     current_seq_parallel as _current_seq_parallel)
 
 
-def _dense_params(rng, d_in, d_out, init, dtype=jnp.float32):
-    return {"kernel": init(rng, (d_in, d_out), dtype),
-            "bias": jnp.zeros((d_out,), dtype)}
+def _dense_params(rng, d_in, d_out, init, dtype=jnp.float32,
+                  use_bias: bool = True):
+    params = {"kernel": init(rng, (d_in, d_out), dtype)}
+    if use_bias:
+        params["bias"] = jnp.zeros((d_out,), dtype)
+    return params
 
 
 def _dense(p, x):
-    return jnp.dot(x, p["kernel"]) + p["bias"]
+    y = jnp.dot(x, p["kernel"])
+    return y + p["bias"] if "bias" in p else y
 
 
 # Single source of LayerNorm math: the canonical layer from normalization.py
-from analytics_zoo_tpu.nn.layers.normalization import LayerNorm as _LayerNorm
+from analytics_zoo_tpu.nn.layers.normalization import (
+    LayerNorm as _LayerNorm, RMSNorm)
 
 _LN = _LayerNorm(name="attention_shared_ln")
 
@@ -59,19 +66,54 @@ def _dropout(rng, x, rate, training):
     return jnp.where(mask, x / keep, 0.0)
 
 
+class RotaryEmbedding(StatelessLayer):
+    """Rotary position embedding (Su et al. 2021, arXiv:2104.09864) in the
+    rotate-half layout: dimension ``i`` of a head is paired with
+    ``i + D/2`` and the pair at position ``m`` is turned by
+    ``m * theta ** (-2i / D)``.  Input (..., L, D), positions ``0..L-1``.
+    No parameters; the turn is computed in float32 and the result has the
+    input's dtype."""
+
+    def __init__(self, theta: float = 10000.0, **kw):
+        super().__init__(**kw)
+        self.theta = float(theta)
+
+    def forward(self, params, x, training=False, rng=None):
+        l, d = x.shape[-2], x.shape[-1]
+        if d % 2:
+            raise ValueError(f"rotary embedding needs an even head size, "
+                             f"got {d}")
+        pos = jnp.arange(l, dtype=jnp.float32)
+        inv_freq = self.theta ** (
+            -jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        angles = pos[:, None] * inv_freq[None, :]           # (L, D/2)
+        cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)
+        sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)
+        x32 = x.astype(jnp.float32)
+        x1, x2 = jnp.split(x32, 2, axis=-1)
+        turned = jnp.concatenate([-x2, x1], axis=-1)
+        return (x32 * cos + turned * sin).astype(x.dtype)
+
+
 class MultiHeadAttention(StatelessLayer):
     """Multi-head (self or cross) attention with fused QKV projection.
 
     Single input → self-attention; two inputs (q, kv) → cross-attention.
     An optional third input is the attention mask (1 = attend),
-    broadcastable to (B, 1, Lq, Lk).
+    broadcastable to (B, 1, Lq, Lk).  ``rotary_theta`` turns q and k by
+    their positions (``RotaryEmbedding``) before the scores are taken;
+    ``use_bias=False`` leaves the four projections without biases.
     """
 
     def __init__(self, nhead: int, hidden_size: Optional[int] = None,
                  attn_drop: float = 0.0, output_drop: float = 0.0,
                  causal: bool = False, init="glorot_uniform",
-                 seq_shards: Optional[int] = None, **kw):
+                 seq_shards: Optional[int] = None, use_bias: bool = True,
+                 rotary_theta: Optional[float] = None, **kw):
         super().__init__(**kw)
+        self.use_bias = use_bias
+        self.rotary = (None if rotary_theta is None else RotaryEmbedding(
+            rotary_theta, name=f"{self.name}_rotary"))
         self.nhead = nhead
         self.hidden_size = hidden_size
         self.attn_drop = attn_drop
@@ -89,12 +131,10 @@ class MultiHeadAttention(StatelessLayer):
             raise ValueError(f"hidden {d} not divisible by nhead {self.nhead}")
         kv_d = rest[0][-1] if rest else q_shape[-1]
         ks = jax.random.split(rng, 4)
-        return {
-            "q": _dense_params(ks[0], q_shape[-1], d, self.initializer),
-            "k": _dense_params(ks[1], kv_d, d, self.initializer),
-            "v": _dense_params(ks[2], kv_d, d, self.initializer),
-            "o": _dense_params(ks[3], d, d, self.initializer),
-        }
+        dims = {"q": q_shape[-1], "k": kv_d, "v": kv_d, "o": d}
+        return {n: _dense_params(k, d_in, d, self.initializer,
+                                 use_bias=self.use_bias)
+                for k, (n, d_in) in zip(ks, dims.items())}
 
     def _split_heads(self, x):
         b, l, d = x.shape
@@ -119,6 +159,9 @@ class MultiHeadAttention(StatelessLayer):
         q = self._split_heads(_dense(params["q"], q_in))
         k = self._split_heads(_dense(params["k"], kv_in))
         v = self._split_heads(_dense(params["v"], kv_in))
+        if self.rotary is not None:
+            q = self.rotary.forward({}, q)
+            k = self.rotary.forward({}, k)
         if mask is not None:
             if mask.ndim == 2:      # (B, Lk) key padding mask
                 mask = mask[:, None, None, :]
@@ -247,16 +290,18 @@ def _stack_block_params(block, keys, hshape):
 
 
 def _run_block_stack(block, n_block, blocks_params, x, training, rng,
-                     mask=None):
+                     mask=None, remat: bool = False):
     """Run a stacked homogeneous block pytree: the GPipe schedule under
     an active pipeline regime, otherwise one `lax.scan` (per-block rng
-    threading for dropout).  Shared by TransformerLayer and BERT so the
-    two stacked paths cannot diverge."""
+    threading for dropout).  Shared by TransformerLayer, BERT and
+    LoopedDecoderStack so the stacked paths cannot diverge.  ``remat``
+    keeps only each block's input for the backward pass and computes the
+    block again there (the pipeline regime has its own ``pipe.remat``)."""
     pipe = _current_pipeline()
     if pipe is not None:
         from analytics_zoo_tpu.parallel.pipeline import pipeline_apply
 
-        if mask is None:
+        if mask is None:  # zoolint: disable=JG-TRACED-BRANCH(None-ness is static pytree structure; the looped stack calls this from inside its scan over passes)
             def stage(p, h):
                 return block.forward(p, h, training=False, rng=None)
 
@@ -279,7 +324,10 @@ def _run_block_stack(block, n_block, blocks_params, x, training, rng,
         args = (h,) if mask is None else (h, mask)
         return block.forward(p, *args, training=training, rng=r)
 
-    if rng is not None:
+    if remat:  # zoolint: disable=JG-TRACED-BRANCH(a python bool decided from static shapes before tracing)
+        apply = jax.checkpoint(apply)
+
+    if rng is not None:  # zoolint: disable=JG-TRACED-BRANCH(None-ness is static pytree structure)
         rngs = jax.random.split(rng, n_block)
 
         def body(h, pr):
@@ -293,6 +341,126 @@ def _run_block_stack(block, n_block, blocks_params, x, training, rng,
 
         x, _ = jax.lax.scan(body, x, blocks_params)
     return x
+
+
+class GatedFFN(StatelessLayer):
+    """Gated feed-forward (Shazeer 2020, arXiv:2002.05202):
+    ``(act(x W_gate) * (x W_up)) W_down``, no biases; ``silu`` makes it
+    SwiGLU."""
+
+    def __init__(self, hidden_size: int, intermediate_size: int,
+                 activation="silu", init="glorot_uniform", **kw):
+        super().__init__(**kw)
+        self.hidden_size = hidden_size
+        self.intermediate = intermediate_size
+        self.act = activations.get(activation)
+        self.initializer = initializers.get(init)
+
+    def build_params(self, rng, x_shape, *rest):
+        d, ff = self.hidden_size, self.intermediate
+        ks = jax.random.split(rng, 3)
+        mk = lambda k, i, o: _dense_params(k, i, o, self.initializer,
+                                           use_bias=False)
+        return {"gate": mk(ks[0], d, ff), "up": mk(ks[1], d, ff),
+                "down": mk(ks[2], ff, d)}
+
+    def forward(self, params, x, training=False, rng=None):
+        return _dense(params["down"], self.act(_dense(params["gate"], x))
+                      * _dense(params["up"], x))
+
+
+class SandwichDecoderBlock(StatelessLayer):
+    """One causal decoder block with a norm before AND after each
+    sub-layer: ``a = x + N2(Attn(N1 x))``, ``y = a + N4(FFN(N3 a))``;
+    RMSNorm, rotary positions on q and k, a gated FFN, no biases."""
+
+    def __init__(self, nhead: int, hidden_size: int, intermediate_size: int,
+                 rotary_theta: float = 10000.0, epsilon: float = 1e-6,
+                 activation="silu", init="glorot_uniform", **kw):
+        super().__init__(**kw)
+        self.attn = MultiHeadAttention(
+            nhead, hidden_size, causal=True, init=init, use_bias=False,
+            rotary_theta=rotary_theta, name=f"{self.name}_attn")
+        self.ffn = GatedFFN(hidden_size, intermediate_size, activation,
+                            init=init, name=f"{self.name}_ffn")
+        self.norm = RMSNorm(epsilon, name=f"{self.name}_norm")
+
+    def build_params(self, rng, x_shape, *rest):
+        ka, kf = jax.random.split(rng)
+        params = {"attn": self.attn.build_params(ka, x_shape),
+                  "ffn": self.ffn.build_params(kf, x_shape)}
+        for i in range(1, 5):
+            params[f"norm{i}"] = self.norm.build_params(None, x_shape)
+        return params
+
+    def forward(self, params, x, training=False, rng=None):
+        n = self.norm.forward
+        a = x + n(params["norm2"], self.attn.forward(
+            params["attn"], n(params["norm1"], x), training=training))
+        return a + n(params["norm4"], self.ffn.forward(
+            params["ffn"], n(params["norm3"], a)))
+
+
+# a looped stack whose blocks would keep more than this for the backward
+# pass computes each block again there instead
+_REMAT_OVER_BYTES = 1 << 30
+
+
+class LoopedDecoderStack(StatelessLayer):
+    """``n_block`` sandwich decoder blocks applied ``passes`` times with
+    ONE set of weights (a looped language model: Ouro, arXiv:2510.25741):
+    ``h_t = N_f(blocks(h_{t-1}))``, the final norm closing every pass and
+    its output feeding the next.
+
+    Input: hidden states (B, L, d).  Output: every pass's ``h_t`` stacked,
+    (passes, B, L, d).  The blocks live as one pytree with a leading
+    ``n_block`` dim (the ``stacked=True`` layout of TransformerLayer and
+    BERT) and run as a ``lax.scan`` over blocks inside a ``lax.scan`` over
+    passes, so one block is traced and compiled, not ``n_block * passes``.
+    Every block's gradient is the sum over the passes.
+
+    Where the ``n_block * passes`` applications would keep more than
+    1 GiB for the backward pass (estimated from the block's widths and
+    the input's shape), each is computed again there and only its input
+    is kept.
+    """
+
+    def __init__(self, n_block: int, nhead: int, hidden_size: int,
+                 intermediate_size: int, passes: int = 4,
+                 rotary_theta: float = 10000.0, epsilon: float = 1e-6,
+                 activation="silu", init="glorot_uniform", **kw):
+        super().__init__(**kw)
+        self.n_block, self.passes = n_block, passes
+        self.hidden_size, self.intermediate = hidden_size, intermediate_size
+        self.block = SandwichDecoderBlock(
+            nhead, hidden_size, intermediate_size, rotary_theta, epsilon,
+            activation, init=init, name=f"{self.name}_block")
+        self.final_norm = RMSNorm(epsilon, name=f"{self.name}_final_norm")
+
+    def build_params(self, rng, x_shape, *rest):
+        return {"blocks": _stack_block_params(
+                    self.block, jax.random.split(rng, self.n_block),
+                    tuple(x_shape)),
+                "final_norm": self.final_norm.build_params(None, x_shape)}
+
+    def _recompute(self, x) -> bool:
+        # a block keeps about ten hidden-wide and three FFN-wide values a
+        # token: the norms' and projections' inputs, the gate's two factors
+        kept = (x.size // x.shape[-1]) * x.dtype.itemsize * (
+            10 * self.hidden_size + 3 * self.intermediate)
+        return kept * self.n_block * self.passes > _REMAT_OVER_BYTES
+
+    def forward(self, params, x, training=False, rng=None):
+        remat = self._recompute(x)
+
+        def one_pass(h, _):
+            h = _run_block_stack(self.block, self.n_block, params["blocks"],
+                                 h, training, None, remat=remat)
+            h = self.final_norm.forward(params["final_norm"], h)
+            return h, h
+
+        _, hs = jax.lax.scan(one_pass, x, None, length=self.passes)
+        return hs
 
 
 class TransformerLayer(StatelessLayer):

@@ -1,4 +1,4 @@
-"""Normalization layers: BatchNormalization, LRN2D, L2 norm.
+"""Normalization layers: BatchNormalization, LayerNorm, RMSNorm, LRN2D.
 
 Reference capability: api/keras/layers/{BatchNormalization,LRN2D,
 WithinChannelLRN2D}.scala.
@@ -116,6 +116,27 @@ class LayerNorm(StatelessLayer):
         var = jnp.var(x, axis=-1, keepdims=True)
         y = (x - mean) * lax.rsqrt(var + self.epsilon)
         return y * params["gamma"] + params["beta"]
+
+
+class RMSNorm(StatelessLayer):
+    """Root-mean-square normalisation over the last axis with a learned
+    scale and no centring (Zhang & Sennrich 2019, arXiv:1910.07467):
+    ``x / sqrt(mean(x**2) + eps) * gamma``.  The statistics are taken in
+    float32 whatever the input's dtype; the output has the input's."""
+
+    def __init__(self, epsilon: float = 1e-6, **kw):
+        super().__init__(**kw)
+        self.epsilon = epsilon
+
+    def build_params(self, rng, input_shape):
+        return {"gamma": jnp.ones((input_shape[-1],), jnp.float32)}
+
+    def forward(self, params, x, training=False, rng=None):
+        x32 = x.astype(jnp.float32)
+        inv = lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+                        + self.epsilon)
+        return (x32 * inv * params["gamma"].astype(jnp.float32)
+                ).astype(x.dtype)
 
 
 class LRN2D(StatelessLayer):

@@ -114,6 +114,109 @@ def sparse_categorical_crossentropy_with_logits(y_true, logits):
     return -jnp.mean(ll)
 
 
+@jax.tree_util.register_pytree_node_class
+class ExitHeads:
+    """What a looped language model hands its loss in place of logits: the
+    hidden states of every pass (T, B, L, d), each pass's exit-gate logit
+    (T, B, L) and the one output kernel (d, V) all passes share.  The
+    logits, T x tokens x V, are left for the loss to form a chunk of
+    tokens at a time.  ``entropy_beta`` is the weight the model's objective
+    gives the exit distribution's entropy.  ``dtype`` is the type the model
+    computed in: the estimator hands every loss float32 values, and the
+    loss multiplies in the model's type again."""
+
+    def __init__(self, hidden, gate_logits, kernel, entropy_beta: float,
+                 dtype=None):
+        self.hidden, self.gate_logits, self.kernel = (hidden, gate_logits,
+                                                      kernel)
+        self.entropy_beta = float(entropy_beta)
+        self.dtype = jnp.dtype(dtype or hidden.dtype).name
+
+    def tree_flatten(self):
+        return ((self.hidden, self.gate_logits, self.kernel),
+                (self.entropy_beta, self.dtype))
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        return cls(*leaves, *aux)
+
+
+def exit_log_probs(gate_logits):
+    """Log of the exit distribution over T passes from the gates' logits
+    (T, ...): ``p_t = lambda_t * prod_{j<t}(1 - lambda_j)`` for t < T and
+    the last pass takes what is left, ``p_T = prod_{j<T}(1 - lambda_j)``
+    (its own gate is not used), ``lambda = sigmoid(gate_logits)``."""
+    s = gate_logits.astype(jnp.float32)
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-s), axis=0)    # log prod (1 - l_j)
+    before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]], axis=0)
+    return jnp.concatenate(
+        [jax.nn.log_sigmoid(s[:-1]) + before[:-1], before[-1:]], axis=0)
+
+
+# the head of a looped model forms at most this many bytes of float32
+# logits at a time (T passes x chunk of tokens x vocabulary)
+_HEAD_CHUNK_BYTES = 512 << 20
+
+
+def _head_chunk(tokens: int, passes: int, vocab: int) -> int:
+    """Largest divisor of ``tokens`` whose logits fit the budget."""
+    c = max(1, min(tokens, _HEAD_CHUNK_BYTES // (4 * passes * vocab)))
+    while tokens % c:
+        c -= 1
+    return c
+
+
+def expected_exit_crossentropy(y_true, heads):
+    """The pre-training loss of a looped language model (Ouro,
+    arXiv:2510.25741): per token, the cross-entropy of every pass's
+    logits weighted by the exit distribution, less ``beta`` times that
+    distribution's entropy; the mean over tokens.
+
+        mean_tokens( sum_t p_t * CE(h_t W, y)  -  beta * H(p) )
+
+    ``heads`` is the model's ``ExitHeads``, which carries ``beta``.  The T
+    heads are formed a chunk of tokens at a time inside a ``lax.scan``
+    whose body is computed again in the backward pass, so at most one
+    chunk of logits (T x chunk x V, 512 MiB of float32 at most: the chunk
+    follows from the shapes) is alive; the kernel's gradient adds up over
+    the chunks in float32.  Plain
+    logits (B, L, V), as the model's ``predict`` path gives them, get the
+    token-level cross-entropy."""
+    if not isinstance(heads, ExitHeads):
+        return sparse_categorical_crossentropy_with_logits(y_true, heads)
+    with jax.named_scope("zoo:lm/head_loss"):
+        passes, d = heads.hidden.shape[0], heads.hidden.shape[-1]
+        labels = _sparse_labels(y_true, heads.hidden[0]).reshape(-1)
+        n = labels.shape[0]
+        c = _head_chunk(n, passes, heads.kernel.shape[-1])
+        beta, dt = heads.entropy_beta, jnp.dtype(heads.dtype)
+
+        def chunks(a):              # (T, n, ...) -> (n / c, T, c, ...)
+            return jnp.moveaxis(
+                a.reshape((passes, n // c, c) + a.shape[2:]), 1, 0)
+
+        @jax.checkpoint
+        def chunk_sum(kernel, h, s, y):
+            logits = jnp.einsum("tcd,dv->tcv", h.astype(dt),
+                                kernel.astype(dt),
+                                preferred_element_type=jnp.float32)
+            ce = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
+                logits, y[None, :, None], axis=-1)[..., 0]
+            logp = exit_log_probs(s)
+            p = jnp.exp(logp)
+            return jnp.sum(p * ce) + beta * jnp.sum(p * logp)
+
+        def body(total, xs):
+            return total + chunk_sum(heads.kernel, *xs), None
+
+        total, _ = jax.lax.scan(
+            body, jnp.zeros((), jnp.float32),
+            (chunks(heads.hidden.reshape(passes, n, d)),
+             chunks(heads.gate_logits.reshape(passes, n)),
+             labels.reshape(n // c, c)))
+        return total / n
+
+
 def class_nll(y_true, log_probs):
     """NLL on log-probabilities (reference ClassNLLCriterion, 197 LoC)."""
     labels = _sparse_labels(y_true, log_probs)
@@ -191,6 +294,7 @@ _REGISTRY = {
     "sparse_categorical_crossentropy": sparse_categorical_crossentropy,
     "sparse_categorical_crossentropy_with_logits":
         sparse_categorical_crossentropy_with_logits,
+    "expected_exit_crossentropy": expected_exit_crossentropy,
     "class_nll": class_nll,
     "kld": kullback_leibler_divergence,
     "kullback_leibler_divergence": kullback_leibler_divergence,

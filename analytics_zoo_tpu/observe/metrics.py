@@ -141,6 +141,9 @@ CATALOG: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
         "(1|K|epoch|shard)", ("kind",)),
     "train_step_seconds": (
         "histogram", "wall time of one step dispatch", ("kind",)),
+    "train_tokens_total": (
+        "counter", "label tokens in the dispatched steps whose labels are "
+        "(B, L) integers (language-model training)", ()),
     "train_epoch_seconds": ("histogram", "wall time of one epoch", ()),
     "train_loss": ("gauge", "last epoch mean loss", ()),
     "train_throughput_rows_per_s": (

@@ -16,6 +16,10 @@ materialises — and two kernels accumulate dQ (grid over KV blocks) and
 dK/dV (grid over Q blocks) in f32 scratch.  Off-TPU
 ``dot_product_attention``'s dispatch takes the pure-JAX blockwise path;
 the kernels run there only in interpreter mode under tests.
+
+The three kernels are named (``pallas_call(name=...)``): on a device trace
+their operations read ``flash_attention_fwd``, ``flash_attention_dq`` and
+``flash_attention_dkv`` (with XLA's ``.N`` suffix).
 """
 
 from __future__ import annotations
@@ -156,6 +160,7 @@ def _flash_fwd(q, k, v, sm_scale: float, causal: bool,
                        jax.ShapeDtypeStruct((b * h, 8, lq), jnp.float32)],
             scratch_shapes=scratch,
             interpret=interpret,
+            name="flash_attention_fwd",
         )(qf, kf, vf)
         return out.reshape(b, h, lq, d), lse[:, 0, :].reshape(b, h, lq)
     out = pl.pallas_call(
@@ -166,6 +171,7 @@ def _flash_fwd(q, k, v, sm_scale: float, causal: bool,
         out_shape=jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qf, kf, vf)
     return out.reshape(b, h, lq, d)
 
@@ -295,6 +301,7 @@ def _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dq",
     )(qf, kf, vf, dof, lse8, delta8)
 
     q_specK = pl.BlockSpec((1, bq, d), lambda bh, ki, qi: (bh, qi, 0))
@@ -310,6 +317,7 @@ def _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(qf, kf, vf, dof, lse8, delta8)
     return (dq.reshape(b, h, lq, d), dk.reshape(b, h, lk, d),
             dv.reshape(b, h, lk, d))

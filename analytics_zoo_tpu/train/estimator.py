@@ -89,6 +89,13 @@ def _cast_like(tree, ref):
         lambda a, r: a.astype(jnp.asarray(r).dtype), tree, ref)
 
 
+def _has_token_axis(y, kind: str) -> bool:
+    """Integer labels (B, L) with L > 1 (a ``K`` chunk: (K, B, L)): one
+    label a token, as a language model's next-token targets are."""
+    return (jnp.issubdtype(y.dtype, jnp.integer)
+            and y.ndim == (3 if kind == "K" else 2) and y.shape[-1] > 1)
+
+
 def resident_epoch_indices(rng, n: int, shuffle: bool = True,
                            pair_structured: bool = False):
     """Gather order for ONE device-resident epoch over ``n`` rows.
@@ -1106,6 +1113,8 @@ class Estimator:
                                      self.opt_state, self._rng,
                                      self._guard, batch_x, batch_y)
         obs.count("train_steps_total", k, kind=kind)
+        if kind in ("1", "K") and _has_token_axis(batch_y, kind):
+            obs.count("train_tokens_total", int(batch_y.size))
         self.global_step += k
         return k, loss
 
@@ -1176,7 +1185,12 @@ class Estimator:
                                else "host_prefetch")
         self.last_data_path_reason = ("jax.Array inputs" if device_resident
                                       else "host array inputs")
-        y_arr = y if device_resident else np.asarray(y)
+        # labels that numpy can only build row by row (lazy rows with a
+        # shape and fancy indexing but no ``__array__``: a data set's
+        # next tokens) stay what they are, as the inputs do, and
+        # gather_rows indexes them a batch at a time; a memmap stays a view
+        lazy = hasattr(y, "shape") and not hasattr(y, "__array__")
+        y_arr = y if (device_resident or lazy) else np.asarray(y)
 
         # Pair-structured losses (rank_hinge: (pos, neg) rows interleaved)
         # must shuffle PAIRS, not rows — a row-level permutation would
