@@ -180,13 +180,15 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
 
     dropping = dropout_rate > 0.0 and dropout_rng is not None
     # the hand-written kernel is sent what it wins from L = 2,048 up.  On a
-    # TPU v5e (chip run, PR 21; bf16, causal, B 4, H 8, L 2,048, D 128):
-    # forward 2.4 ms against 5.6 ms for reference_attention, forward and
-    # backward 5.0 against 10.6 ms.  In the looped decoder's training step
-    # (chip run, PR 29; B 2, H 16, L 4,096, D 128, from the device trace):
-    # forward 6.2 ms a call, dq 3.4 ms, dkv 5.6 ms, 15 % of the kernels'
-    # roofline (PERF.md section 5).  Below 2,048 the kernel's grid overhead
-    # is most of its time and the XLA paths below are taken.
+    # TPU v5e (chip runs, PR 30; bf16, causal, D 128): B 4, H 8, L 2,048:
+    # forward 0.48 ms, dq 0.57, dkv 0.71 in a queue of calls; a call at a
+    # time (chip_smoke.py) forward 1.03 ms against 5.56 for
+    # reference_attention, forward and backward 2.53 against 10.56.  B 2,
+    # H 16, L 4,096, the looped decoder's: 1.51 / 1.78 / 2.16 ms, 48 / 24 /
+    # 24 calls a step (PERF.md section 5).  At B 2, H 16, L 1,024 the three
+    # take 0.41 / 0.24 / 0.41 ms and at L 512 0.39 / 0.20 / 0.37: below
+    # 2,048 a call is mostly its fixed cost; the XLA paths below were not
+    # timed against it there, and the floor stays where it was.
     path = dispatch.select_path(
         "flash_attention",
         shapes_ok=(mask is None and not dropping

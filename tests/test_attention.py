@@ -347,3 +347,225 @@ class TestFlashBackwardKernel:
         ref_lse = jax.nn.logsumexp(s, axis=-1)
         np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
                                    rtol=1e-4, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the flash kernels' tiling (PR 30): operands in the input's dtype, tiles of
+# unequal sides, the mask on crossed tiles only, dead tiles not fetched
+# ---------------------------------------------------------------------------
+
+def _flash_module():
+    # ``analytics_zoo_tpu.ops.flash_attention`` names the function: the
+    # package re-exports it over the module
+    import importlib
+
+    return importlib.import_module("analytics_zoo_tpu.ops.flash_attention")
+
+
+def _flash_case(dtype, lq, lk, seed):
+    rs = np.random.RandomState(seed)
+    q, g = (jnp.asarray(rs.randn(1, 2, lq, 128) * 0.5, dtype)
+            for _ in range(2))
+    k, v = (jnp.asarray(rs.randn(1, 2, lk, 128) * 0.5, dtype)
+            for _ in range(2))
+    return q, k, v, g
+
+
+def _oracle(q, k, v, g, causal, dtype=jnp.float32):
+    """out, lse and the three gradients by ``reference_attention`` with
+    the (already rounded) inputs taken to ``dtype``."""
+    q, k, v = (x.astype(dtype) for x in (q, k, v))
+    out, vjp = jax.vjp(
+        lambda a, b, c: reference_attention(a, b, c, causal=causal), q, k, v)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) \
+        / np.sqrt(q.shape[-1])
+    if causal:
+        lq, lk = q.shape[2], k.shape[2]
+        s = jnp.where(jnp.tril(jnp.ones((lq, lk), bool), k=lk - lq), s,
+                      -jnp.inf)
+    return (out, jax.nn.logsumexp(s, axis=-1)) + tuple(vjp(g.astype(dtype)))
+
+
+def _gap(a, b):
+    a, b = (np.asarray(x, np.float32) for x in (a, b))
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+def _flash_all(q, k, v, g, causal, bq, bk):
+    from analytics_zoo_tpu.ops.flash_attention import _flash_bwd, _flash_fwd
+
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    out, lse = _flash_fwd(q, k, v, scale, causal, bq, bk, True,
+                          with_lse=True)
+    return (out, lse) + _flash_bwd(q, k, v, out, lse, g, scale, causal,
+                                   bq, bk, True)
+
+
+def _tile_kinds_of(lq, lk, bq, bk):
+    """How many score tiles of a causal call are crossed, full, dead."""
+    from analytics_zoo_tpu.ops.flash_attention import _tile_kinds
+
+    n = dict(crossed=0, full=0, dead=0)
+    for qi in range(lq // bq):
+        for ki in range(lk // bk):
+            crossed, full = (bool(x) for x in
+                             _tile_kinds(qi, ki, bq, bk, lk - lq, True))
+            n["crossed" if crossed else "full" if full else "dead"] += 1
+    return n
+
+
+class TestFlashTiling:
+    NAMES = ("out", "lse", "dq", "dk", "dv")
+
+    @pytest.mark.parametrize("lq,lk", [(512, 512), (256, 512)])
+    @pytest.mark.parametrize("bq,bk", [(128, 256), (256, 128)])
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_unequal_tiles_meet_the_reference(self, dtype, causal, bq, bk,
+                                              lq, lk):
+        q, k, v, g = _flash_case(dtype, lq, lk, seed=lq + bq)
+        got = _flash_all(q, k, v, g, causal, bq, bk)
+        want = _oracle(q, k, v, g, causal)
+        if dtype == jnp.float32:
+            for a, b, name in zip(got, want, self.NAMES):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                           rtol=2e-3, atol=2e-4,
+                                           err_msg=name)
+            return
+        # bfloat16: the kernels (float32 scores, softmax and sums) may be
+        # no further from the float32 answer than plain bfloat16 math is
+        plain = _oracle(q, k, v, g, causal, jnp.bfloat16)
+        for a, b, c, name in zip(got, want, plain, self.NAMES):
+            assert a.dtype == (jnp.float32 if name == "lse" else dtype)
+            assert _gap(a, b) <= max(_gap(c, b), 1e-6), name
+
+    def test_dead_full_and_crossed_tiles_in_one_call(self):
+        lq = lk = 512
+        kinds = _tile_kinds_of(lq, lk, 128, 128)
+        assert kinds == dict(crossed=4, full=6, dead=6)
+        q, k, v, g = _flash_case(jnp.float32, lq, lk, seed=5)
+        for a, b, name in zip(_flash_all(q, k, v, g, True, 128, 128),
+                              _oracle(q, k, v, g, True), self.NAMES):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=2e-4, err_msg=name)
+
+    def test_every_live_tile_crossed(self):
+        lq = lk = 256
+        assert _tile_kinds_of(lq, lk, 256, 256) == dict(crossed=1, full=0,
+                                                        dead=0)
+        q, k, v, g = _flash_case(jnp.float32, lq, lk, seed=6)
+        for a, b, name in zip(_flash_all(q, k, v, g, True, 256, 256),
+                              _oracle(q, k, v, g, True), self.NAMES):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=2e-4, err_msg=name)
+
+    def test_rows_that_see_no_key_give_zero(self):
+        # causal with lq > lk: the first lq - lk query rows see nothing
+        from analytics_zoo_tpu.ops.flash_attention import flash_attention
+
+        q, k, v, g = _flash_case(jnp.float32, 384, 128, seed=7)
+
+        def f(q, k, v):
+            return flash_attention(q, k, v, True, None, 128, 128, True)
+
+        out, vjp = jax.vjp(f, q, k, v)
+        want, ref_vjp = jax.vjp(
+            lambda a, b, c: reference_attention(a, b, c, causal=True),
+            q, k, v)
+        assert not np.asarray(out[:, :, :256]).any()
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   rtol=2e-3, atol=2e-4)
+        for a, b, name in zip(vjp(g), ref_vjp(g), "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=2e-4, err_msg=name)
+
+    def test_default_tiles_match_explicit_ones(self):
+        from analytics_zoo_tpu.ops.flash_attention import flash_attention
+
+        q, k, v, _ = _flash_case(jnp.float32, 256, 256, seed=8)
+        a = flash_attention(q, k, v, True, None, None, None, True)
+        b = flash_attention(q, k, v, True, None, 256, 256, True)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
+
+
+class TestFlashTileRule:
+    """``_tiles`` is a function of shapes alone: held here to what the
+    kernels and Mosaic need of it, whatever it prefers."""
+
+    SHAPES = [
+        # lq, lk, d, dtype, causal
+        (4096, 4096, 128, jnp.bfloat16, True),    # ouro-2.6b-fit-packed4k
+        (2048, 2048, 128, jnp.bfloat16, True),    # chip_smoke's
+        (2048, 2048, 64, jnp.float32, False),
+        (512, 4608, 128, jnp.bfloat16, True),     # a hop's rows, longer keys
+        (4608, 4608, 128, jnp.bfloat16, True),
+        (2176, 2176, 128, jnp.bfloat16, True),    # 17 x 128: few divisors
+        (4096, 4096, 512, jnp.float32, True),     # the budget binds
+    ]
+
+    @pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+    @pytest.mark.parametrize("lq,lk,d,dtype,causal", SHAPES)
+    def test_tiles_divide_align_and_fit(self, kernel, lq, lk, d, dtype,
+                                        causal):
+        fa = _flash_module()
+        bq, bk = fa._tiles(kernel, lq, lk, d, dtype, causal)
+        assert lq % bq == 0 and lk % bk == 0
+        assert bq % 128 == 0 and bk % 128 == 0
+        need = fa._tile_bytes(kernel, bq, bk, d, jnp.dtype(dtype).itemsize)
+        assert need <= fa._VMEM_BUDGET
+        params = fa._compiler_params(kernel, bq, bk, d, dtype)
+        limit = (params.vmem_limit_bytes if params is not None
+                 else fa._VMEM_DEFAULT)
+        assert need < limit
+        # a larger tile costs more, so the budget can bind
+        assert fa._tile_bytes(kernel, 2 * bq, bk, d, 2) > \
+            fa._tile_bytes(kernel, bq, bk, d, 2)
+
+    def test_long_sequences_leave_the_old_tile_behind(self):
+        fa = _flash_module()
+        for kernel in ("fwd", "dq", "dkv"):
+            bq, bk = fa._tiles(kernel, 4096, 4096, 128, jnp.bfloat16, True)
+            assert bq >= 512 and bk >= 512, (kernel, bq, bk)
+
+    @pytest.mark.parametrize("blocks", [(128, 128), (256, 256), (128, 256),
+                                        (256, None), (None, 128)])
+    def test_explicit_blocks_are_obeyed(self, blocks):
+        fa = _flash_module()
+        q = jax.ShapeDtypeStruct((2, 16, 4096, 128), jnp.bfloat16)
+        for kernel in ("fwd", "dq", "dkv"):
+            *_, bq, bk = fa._blocks(q, q, *blocks, kernel, True)
+            rule = fa._tiles(kernel, 4096, 4096, 128, jnp.bfloat16, True)
+            assert bq == (blocks[0] or rule[0])
+            assert bk == (blocks[1] or rule[1])
+
+    def test_a_short_or_ragged_length_goes_whole(self):
+        fa = _flash_module()
+        assert fa._tiles("fwd", 64, 200, 128, jnp.float32, False) == (64, 200)
+
+
+class TestFlashCompilesForTheChip:
+    """Mosaic's own answer, with no chip (tests/mosaic_aot.py): the tiles
+    the rule picks, under the VMEM limit it sets, are accepted at the real
+    shapes."""
+
+    @pytest.mark.parametrize("b,h,l,d,dtype,causal", [
+        (2, 16, 4096, 128, jnp.bfloat16, True),
+        (2, 8, 2048, 128, jnp.float32, False),
+        (1, 8, 2176, 128, jnp.bfloat16, True),
+    ])
+    def test_forward_and_backward(self, b, h, l, d, dtype, causal):
+        from analytics_zoo_tpu.ops.flash_attention import (_flash_bwd,
+                                                           _flash_fwd)
+        from tests.mosaic_aot import spec, tpu_compile
+
+        x = spec((b, h, l, d), dtype)
+        rows = spec((b, h, l), jnp.float32)
+        scale = d ** -0.5
+        tpu_compile(lambda q, k, v: _flash_fwd(
+            q, k, v, scale, causal, None, None, False, with_lse=True),
+            x, x, x)
+        text = tpu_compile(lambda q, k, v, o, lse, g: _flash_bwd(
+            q, k, v, o, lse, g, scale, causal, None, None, False),
+            x, x, x, x, rows, x).as_text()
+        assert "flash_attention_dq" in text and "flash_attention_dkv" in text
