@@ -107,7 +107,7 @@ def init_zoo_context(
 
     ``multihost=True`` runs ``jax.distributed.initialize()`` so the same
     program scales to multi-host pods over DCN (replacing the reference's
-    Spark-driver + block-manager transport, docs/wp-bigdl.md:140-160).
+    Spark-driver + block-manager transport, wp-bigdl.md:140-160).
     """
     global _GLOBAL_CONTEXT
     import jax

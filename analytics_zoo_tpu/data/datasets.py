@@ -48,7 +48,8 @@ def generate_movielens_like(n_users: int = 6040, n_items: int = 3706,
                             ratings_per_user: int = 20, latent: int = 8,
                             seed: int = 0):
     """MovieLens-1M-shaped synthetic ratings with a low-rank preference
-    structure (learnable; see bench.py's convergence evidence)."""
+    structure (learnable: the ratings are a noisy function of the
+    latent factors)."""
     rs = np.random.RandomState(seed)
     zu = rs.randn(n_users + 1, latent)
     zi = rs.randn(n_items + 1, latent)

@@ -1,7 +1,7 @@
 """Deterministic lazily-generated giant embedding tables.
 
-The DLRM-scale bench leg (bench.py) and the sharded-table geometry
-tests need 10⁸-row tables that can NEVER be materialized on the host —
+DLRM-scale lookups and the sharded-table geometry tests
+(``tests/test_sharded_embedding.py``) need 10⁸-row tables that can NEVER be materialized on the host —
 a 10⁸×64 f32 table is ~25 GiB.  ``SyntheticGiantTable`` is the
 table-shaped sibling of ``SlicedFeatureSet``: its size accounting
 (``.nbytes``, ``len``) comes from header math alone, and actual values

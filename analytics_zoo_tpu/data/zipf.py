@@ -1,11 +1,11 @@
 """Seeded zipfian id draws — ONE source of truth for skewed traffic.
 
 Recommender lookups are zipfian; every leg of the repo that simulates
-that skew (the ``bench.py`` sharded-table legs, the loadgen
-``ZipfianIdPayload`` class, the hot-cache tests) draws through this
-module so their id streams are **byte-identical** for the same
-``(vocab, n, s, seed)`` — a bench claim about hit rates at skew s=1.0
-is then literally about the distribution the load harness offers.
+that skew (the loadgen ``ZipfianIdPayload`` class, the hot-cache
+tests) draws through this module so their id streams are
+**byte-identical** for the same ``(vocab, n, s, seed)`` — a hit rate
+asserted at skew s=1.0 is then literally about the distribution the
+load harness offers.
 
 The draw is a plain ``Generator.choice`` over the normalized
 ``1/rank**s`` weights (rank 1 = id 0): deterministic from the generator

@@ -48,8 +48,8 @@ ALL_MODELS = "_all"
 
 class AutoscalePolicy:
     """Bounds + watermarks for the control loop.  Defaults are sized
-    for the single-host pipeline; the chaos soak and the bench override
-    them to act fast."""
+    for the single-host pipeline; the chaos soak overrides them to act
+    fast."""
 
     def __init__(self,
                  min_decode_workers: int = 1,

@@ -3,9 +3,7 @@
 The legacy serving wire ships every tensor as
 ``ndarray → tobytes → base64 → JSON string`` and decodes it with the
 mirror-image chain — ~2.7x the bytes on the wire and two full passes
-over the payload in pure Python (the r05 bench measured the full queue
-path at 27 imgs/s while the device side of the same model did
-thousands).  This module replaces it with a length-prefixed binary
+over the payload in pure Python.  This module replaces it with a length-prefixed binary
 frame that moves raw bytes:
 
     AZB1 | u32 meta_len | meta-JSON | u32 n_tensors |
@@ -31,7 +29,7 @@ on-device (``imagenet_preprocess``), never in the codec.
 
 Every pack/unpack reports ``serving_wire_bytes_total{codec=...}`` and
 ``serving_codec_seconds{codec,op}`` into the observe CATALOG so the
-bench breakdown can attribute the wire share per codec.
+wire's share of a request can be attributed per codec.
 """
 
 from __future__ import annotations

@@ -3,8 +3,8 @@
 A serving process pays one XLA compile per (model, bucket shape, dtype,
 device) program signature.  On a restart every one of those compiles is
 paid again before the worker reaches full bucket coverage — the
-dominant term in restart-to-SLO time (docs/PERFORMANCE.md
-``serving_restart_to_slo``).  The reference stack dodged this by
+dominant term in restart-to-SLO time (docs/SERVING.md "Warm start &
+multi-model").  The reference stack dodged this by
 loading pre-built OpenVINO engine blobs (PAPER.md §L0); the TPU-native
 equivalent is ``jax.jit(fwd).lower(...).compile()`` +
 ``jax.experimental.serialize_executable``: the compiled executable

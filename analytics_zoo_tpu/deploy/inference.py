@@ -452,8 +452,8 @@ class InferenceModel:
         """Pre-install every cached executable for this model's
         fingerprint.  A restarted process reaches full bucket coverage
         here, in deserialization time, instead of after N live compiles
-        — and ``compile_count`` stays 0 for every warmed shape (the
-        acceptance proof for the ``serving_restart_to_slo`` bench).
+        — and ``compile_count`` stays 0 for every warmed shape
+        (``tests/test_compile_cache.py`` asserts it across processes).
         Returns the number of programs installed."""
         if self._cache is None:
             return 0

@@ -117,7 +117,7 @@ def encode_tensor(a) -> Dict[str, Any]:
     Binary-wire backends (``queue.wire == "binary"``) skip this entirely
     and ship raw ndarrays through :mod:`deploy.codec`; this stays the
     reference-compatible fallback for Memory/Redis and old producers.
-    Instrumented so the bench can attribute the base64 tax:
+    Instrumented so the base64 tax can be attributed:
     ``serving/codec_b64_encode`` counts calls,
     ``serving_wire_bytes_total{codec="json_b64"}`` the on-wire bytes."""
     t0 = time.perf_counter()
@@ -755,8 +755,7 @@ class ServingConfig:
     the per-device model copies the executor round-robins over, and
     ``max_inflight`` bounds concurrently-dispatched device batches
     (2 = double buffering).  ``pipeline=False`` falls back to the
-    synchronous one-thread worker (the bench's ``serving_sync_baseline``
-    leg measures exactly that).
+    synchronous one-thread worker.
 
     Self-healing knobs (docs/SERVING.md "Failure semantics"):
     ``breaker_threshold`` consecutive failures quarantine a replica,
@@ -3161,7 +3160,7 @@ class ClusterServing:
                 time.sleep(0.05)  # kill the worker (reference keeps its
                 #                   streaming query alive the same way)
 
-    # -- one scheduling quantum (sync mode / tests / bench baseline) ------
+    # -- one scheduling quantum (sync mode / tests) ------------------------
     def serve_once(self) -> int:
         """Serve up to one batch; returns number of records served.
 

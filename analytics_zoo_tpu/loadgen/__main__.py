@@ -1,14 +1,12 @@
-"""Artifact generator: run every load leg and pin ``SLO_r18.json``.
+"""Report generator: run every load leg and write the SLO report.
 
 ::
 
     JAX_PLATFORMS=cpu python -m analytics_zoo_tpu.loadgen \
-        --out SLO_r18.json [--workdir /tmp/loadgen] [--quick]
+        --out <report>.json [--workdir /tmp/loadgen] [--quick]
 
-The artifact's schema and the doc-pinned rows are described in
-docs/LOADGEN.md; ``tests/test_doc_drift.py`` machine-checks the pinned
-``SLO_TABLE`` blocks against the newest ``SLO_*.json`` in the repo
-root.
+The report's schema and the invariants its legs hold (with the test
+that asserts each) are described in docs/LOADGEN.md.
 """
 
 from __future__ import annotations
@@ -22,13 +20,13 @@ import time
 
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__)
-    p.add_argument("--out", default="SLO_r18.json")
+    p.add_argument("--out", required=True,
+                   help="where to write the report")
     p.add_argument("--workdir", default=None,
                    help="scratch dir for the kill leg's spool/cache "
                         "(a fresh tempdir when omitted)")
     p.add_argument("--quick", action="store_true",
-                   help="halved durations for smoke runs (never for "
-                        "the pinned artifact)")
+                   help="halved durations for smoke runs")
     args = p.parse_args(argv)
 
     from analytics_zoo_tpu.loadgen.harness import default_report

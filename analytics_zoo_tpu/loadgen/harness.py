@@ -3,11 +3,10 @@
 
 Each ``run_*_leg`` function is self-contained — it builds its queue,
 server, schedule and client(s), runs to completion, and returns the
-JSON-ready section the artifact writer
-(``python -m analytics_zoo_tpu.loadgen``) assembles into
-``SLO_r18.json``.  The slow soak tests drive the same functions and
-assert over the sections, so the pinned artifact and the CI proof are
-the same code path.
+JSON-ready section the report writer
+(``python -m analytics_zoo_tpu.loadgen``) assembles into one report.
+The slow soak tests drive the same functions and assert over the
+sections, so the report and the CI proof are the same code path.
 
 The kill leg is the only one that crosses a process boundary: the
 server runs as a real OS process (``loadgen/server_main.py``) over a

@@ -7,9 +7,8 @@ per arrival.  Weights may shift over the run (``shift_at_s`` /
 drives is "balanced, then 80/20 onto the laggy model", expressed as
 one mix.
 
-``bench_serving``'s saturated legs draw their request arrays from
-:func:`saturated_images` so the bench and the load harness share one
-source of truth for request shapes (ISSUE 16 satellite).
+Saturated legs draw their request arrays from
+:func:`saturated_images`, the one source of truth for request shapes.
 """
 
 from __future__ import annotations
@@ -57,10 +56,9 @@ class PayloadClass:
 class ZipfianIdPayload(PayloadClass):
     """Skewed recommender id traffic: each request's id block draws
     zipf(s) over ``vocab`` through :func:`analytics_zoo_tpu.data.zipf.
-    zipfian_ids` — the SAME generator the ``bench.py`` sharded-table
-    legs use, so the load harness's skew is byte-identical to the
-    bench's for the same generator state (ISSUE 19 satellite).  The
-    hot-row cache hit rates a bench pins therefore describe exactly the
+    zipfian_ids` — the SAME generator the hot-cache tests use, so the
+    load harness's skew is byte-identical to theirs for the same
+    generator state: a hit rate asserted there describes exactly the
     traffic this class offers."""
 
     def __init__(self, model: str, shape: Tuple[int, ...], vocab: int,
@@ -154,8 +152,8 @@ def saturated_images(n: int, rs=None, seed: int = 0,
                      shape: Tuple[int, ...] = (224, 224, 3)) -> List[np.ndarray]:
     """``n`` distinct uint8 images for a saturated offered-load leg.
 
-    The one source of truth for the request mix ``bench_serving`` and
-    the load harness both saturate with.  Accepts an existing
+    The one source of truth for the request mix a saturated leg
+    offers.  Accepts an existing
     ``np.random.RandomState`` (``rs``) so callers that interleave other
     draws on the same stream keep their historical sequences; without
     one, a fresh ``RandomState(seed)`` makes the leg self-contained.

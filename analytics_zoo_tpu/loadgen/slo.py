@@ -18,9 +18,9 @@ schema"):
   seconds until the first of ``min_consec`` consecutive compliant
   windows.  ``None`` = never recovered inside the run.
 
-Artifacts are plain JSON; ``SLO_r18.json`` at the repo root is the
-doc-of-record copy ``tests/test_doc_drift.py`` machine-checks against
-``docs/LOADGEN.md``'s pinned SLO_TABLE rows.
+Reports are plain strict JSON (:func:`write_artifact`);
+``docs/LOADGEN.md`` names the test that asserts each invariant over
+the sections.
 """
 
 from __future__ import annotations

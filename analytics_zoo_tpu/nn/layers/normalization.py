@@ -35,12 +35,14 @@ class BatchNormalization(Layer):
         """``stats_fraction < 1`` enables ghost-BN: training statistics
         are computed over the leading ``ceil(fraction * B)`` rows of the
         batch (normalization still covers every row).  On TPU the BN
-        stats pass is pure HBM bandwidth (the r4 ResNet-50 roofline:
-        ~9GB of ~20ms/step is BN traffic, docs/PERFORMANCE.md), so
+        stats pass is HBM traffic, and BatchNorm's and the elementwise
+        fusions are what bounds ResNet-50's step (``PERF.md`` §5), so
         reading a quarter of the rows for stats removes most of one of
-        BN's three activation passes.  Estimator numerics: subset stats
-        are the ghost-BN regularizer (Hoffer et al. 2017) — equal or
-        better validation accuracy at batch>=256 in our accuracy leg."""
+        BN's three activation passes; what that is worth on the chip
+        is not measured (the benchmark's cell runs full BN).  Estimator
+        numerics: subset stats are the ghost-BN regularizer (Hoffer et
+        al. 2017); ``tests/test_ghost_bn.py`` holds validation accuracy
+        to full BN's on its texture task."""
         super().__init__(**kw)
         self.epsilon = epsilon
         self.momentum = momentum

@@ -96,7 +96,7 @@ def sparse_categorical_crossentropy(y_true, y_pred, zero_based_label=True):
     mobilenet/vgg16 end in a raw Dense) — with
     ``sparse_categorical_crossentropy_with_logits`` instead: feeding
     logits here clips through the log and the model silently memorizes
-    without generalizing (r5 post-mortem in bench_resnet_accuracy)."""
+    without generalizing."""
     labels = _sparse_labels(y_true, y_pred)
     if not zero_based_label:
         labels = labels - 1

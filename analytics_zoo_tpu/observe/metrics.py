@@ -48,7 +48,7 @@ SeriesKey = Tuple[str, LabelTuple]
 _HIST_RING = 1024
 
 # name -> (type, help, allowed label keys).  The single source of truth
-# for metric names; OBSERVABILITY.md pins this table and test_doc_drift
+# for metric names; docs/OBSERVABILITY.md pins this table and test_doc_drift
 # checks it.
 CATALOG: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
     # serving pipeline (the ``model`` label names the serving model in a

@@ -44,7 +44,7 @@ Dispatch (``ops/dispatch.select_path``, counted in
 - ``ZooConfig.ring_attention`` knob — "auto"/"on"/"off" like
   ``fused_embedding``; "on" rings wherever a mesh allows, "off" pins
   the single-device path;
-- ``force`` — explicit test/bench override; "interpret" runs the flash
+- ``force`` — explicit test override; "interpret" runs the flash
   kernels under ``pallas_call(interpret=True)`` per hop, which is how
   the CPU tier proves kernel-path parity.
 

@@ -194,7 +194,7 @@ class Estimator:
         self._stream_shard_key = None
         self._stream_plan = None        # set by _resolve_data_path
         # which input path the last fit() ran ("device_resident" /
-        # "stream" / "host_prefetch") and why — bench and tests read these
+        # "stream" / "host_prefetch") and why — tests read these
         self.last_data_path: Optional[str] = None
         self.last_data_path_reason: Optional[str] = None
         # observability: the fit-level root span, the current epoch's
@@ -591,8 +591,7 @@ class Estimator:
         arrays are NOT (they feed every epoch) — so an epoch moves zero
         bytes host→device and costs one dispatch (the TPU answer to the
         reference's per-iteration Spark jobs AND to per-batch
-        ``device_put``, which the r05 bench measured as a ~9.4× gap
-        between step compute and end-to-end throughput)."""
+        ``device_put``)."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         key = (n, eff_batch, steps, bool(shuffle))

@@ -6,8 +6,8 @@ reference's DNNL/VNNI int8 claimed ~2x over f32, wp-bigdl.md:192).
 Here quantization is native: per-channel symmetric int8 weights live in
 HBM and the dequant fuses into the consuming matmul on the MXU's int8
 path (`ops.quantization` / `quantize_pytree`).  The script quantizes a
-trained classifier, reports agreement + weight-bytes saved, and on TPU
-the int8 matmul path measures ~2.3x f32 (bench.py `matmul_4096`).
+trained classifier and reports agreement + weight-bytes saved; what the
+int8 path is worth in time on the chip is not measured (`PERF.md` §7).
 """
 
 import argparse
@@ -63,7 +63,6 @@ def main():
           f"(max prob drift {drift:.4f})")
     print(f"weight bytes: f32 {f32_bytes:,} -> int8 {q_bytes:,} "
           f"({f32_bytes / q_bytes:.2f}x smaller)")
-    print("on-TPU int8 matmul path: ~2.3x f32 (bench.py matmul_4096)")
     assert agree > 0.95
 
 

@@ -1,6 +1,6 @@
 # zoolint: hot-path
-"""zoolint fixture: the kernel-bench driver idiom (bench.py kernel
-legs, ops/ dispatch smoke loops).  Draining every tile's result with a
+"""zoolint fixture: the kernel-sweep driver idiom (a loop over tiles,
+as in the ops/ dispatch smoke loops).  Draining every tile's result with a
 per-iteration ``.block_until_ready()`` serializes dispatch against the
 device and fires JG-TRANSFER-HOT; the shipped drivers enqueue the whole
 tile sweep asynchronously and sync ONCE on the last handle, which is

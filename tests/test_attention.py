@@ -118,7 +118,8 @@ class TestBlockwise:
 
 
 class TestFlashKernel:
-    """Pallas kernel in interpreter mode (real-TPU path exercised by bench)."""
+    """Pallas kernel in interpreter mode (the real-TPU path runs in
+    ``chip_smoke.py`` and in the ``ouro-2.6b-fit-packed4k`` cell)."""
 
     def test_forward_matches_reference(self):
         from analytics_zoo_tpu.ops.flash_attention import flash_attention

@@ -1,125 +1,26 @@
-"""docs must not drift from the artifacts/registries they pin.
+"""docs must not drift from the registries and the tree they point at.
 
-r5 shipped a doc quoting flash "8.29x at 1024" while BENCH_r05.json
-said 1.13x — interactive-probe numbers leaked into the doc of record.
-docs/PERFORMANCE.md now pins its numeric claims in a marker-delimited
-table; this test resolves each dotted key into the NEWEST BENCH_*.json
-and fails tier-1 when they disagree, so regenerating the artifact
-without regenerating the doc is a red build, not silent drift.
+docs/OBSERVABILITY.md's pinned metric-names table is machine-checked
+against the live ``observe.metrics.CATALOG`` (names, types, AND label
+keys), so adding or renaming a metric without updating the doc of
+record is red; docs/SERVING.md's error-code table is held to
+``robust.errors.SERVING_ERROR_CODES`` the same way.
 
-The same discipline covers docs/OBSERVABILITY.md: its pinned
-metric-names table is machine-checked against the live
-``observe.metrics.CATALOG`` (names, types, AND label keys), so adding
-or renaming a metric without updating the doc of record is equally
-red.
-
-Also guards the instrument itself: the bench ratio/sanitize helpers
-must never let Infinity/NaN reach an emitted report again.
+And every path a document names exists (``TestDocsPointAtTheTree``): a
+pointer to a script, a test or a record that has been deleted is the
+drift that cost most here.
 """
 
-import importlib.util
-import json
+import ast
+import functools
+import io
 import re
+import tokenize
 from pathlib import Path
 
 import pytest
 
 REPO = Path(__file__).resolve().parent.parent
-DOC = REPO / "docs" / "PERFORMANCE.md"
-
-_TABLE_RE = re.compile(
-    r"<!--\s*BENCH_TABLE:BEGIN([^>]*)-->(.*?)<!--\s*BENCH_TABLE:END\s*-->",
-    re.S)
-
-
-def _newest_artifact():
-    arts = sorted(REPO.glob("BENCH_*.json"))
-    if not arts:
-        pytest.skip("no BENCH_*.json artifact in repo root")
-    return arts[-1]
-
-
-def _pinned_tables():
-    """Every BENCH_TABLE block in the doc, not just the first.  A table
-    may carry ``requires=<dotted key>``: its claims are only checked
-    against artifacts that HAVE that key (so pinning a newly-benched
-    number doesn't fail tier-1 against an older artifact that predates
-    the bench leg — the claim arms itself on the next regeneration)."""
-    tables = []
-    for m in _TABLE_RE.finditer(DOC.read_text()):
-        attrs = dict(re.findall(r"(\w+)=(\S+)", m.group(1)))
-        claims = []
-        for line in m.group(2).splitlines():
-            cells = [c.strip() for c in line.strip().strip("|").split("|")]
-            if (len(cells) != 2 or cells[0] in ("key", "")
-                    or "---" in cells[0]):
-                continue
-            claims.append((cells[0], float(cells[1])))
-        assert claims, "a pinned-claims table is empty"
-        tables.append({"requires": attrs.get("requires"),
-                       "tolerance": float(attrs.get("tolerance", 0.02)),
-                       "claims": claims})
-    assert tables, "PERFORMANCE.md lost its BENCH_TABLE markers"
-    return tables
-
-
-def _pinned_claims():
-    tables = _pinned_tables()
-    return ([c for t in tables for c in t["claims"]],
-            tables[0]["tolerance"])
-
-
-def _resolve(doc, dotted, required=True):
-    cur = {"parsed": doc.get("parsed", doc)}
-    for part in dotted.split("."):
-        if not (isinstance(cur, dict) and part in cur):
-            assert not required, \
-                f"artifact has no key {dotted!r} (stopped at {part!r})"
-            return None
-        cur = cur[part]
-    return cur
-
-
-class TestDocDrift:
-    def test_pinned_claims_match_newest_artifact(self):
-        art = _newest_artifact()
-        doc = json.loads(art.read_text())
-        bad = []
-        for table in _pinned_tables():
-            req = table["requires"]
-            if req and _resolve(doc, req, required=False) is None:
-                continue        # artifact predates this bench leg
-            for key, claimed in table["claims"]:
-                actual = _resolve(doc, key)
-                assert isinstance(actual, (int, float)), \
-                    f"{key} resolves to non-numeric {actual!r}"
-                if actual != pytest.approx(claimed,
-                                           rel=table["tolerance"]):
-                    bad.append(f"{key}: doc={claimed} artifact={actual}")
-        assert not bad, (f"PERFORMANCE.md drifted from {art.name}:\n  "
-                         + "\n  ".join(bad))
-
-    def test_requires_gate_skips_only_missing_keys(self):
-        """The requires= mechanism itself: a table gated on a key the
-        artifact lacks is skipped; one gated on a present key is
-        checked (regression for the multi-table finditer upgrade)."""
-        doc = {"parsed": {"extra": {"new_leg": {"speedup": 12.0}}}}
-        assert _resolve(doc, "parsed.extra.new_leg.speedup") == 12.0
-        assert _resolve(doc, "parsed.extra.absent_leg",
-                        required=False) is None
-        with pytest.raises(AssertionError):
-            _resolve(doc, "parsed.extra.absent_leg")
-        # and the doc of record actually uses multi-table pinning
-        tables = _pinned_tables()
-        assert len(tables) >= 2, \
-            "expected the wire-codec claims in their own BENCH_TABLE"
-        assert any(t["requires"] for t in tables)
-
-    def test_pinned_claims_are_finite(self):
-        import math
-        claims, _ = _pinned_claims()
-        for key, v in claims:
-            assert math.isfinite(v), f"{key} pins a non-finite value"
 
 
 OBS_DOC = REPO / "docs" / "OBSERVABILITY.md"
@@ -222,194 +123,131 @@ class TestServingErrorCodeDocDrift:
                 == {"decode_error", "model_error"})
 
 
-LOADGEN_DOC = REPO / "docs" / "LOADGEN.md"
+PACKAGE = REPO / "analytics_zoo_tpu"
 
-_SLO_TABLE_RE = re.compile(
-    r"<!--\s*SLO_TABLE:BEGIN([^>]*)-->(.*?)<!--\s*SLO_TABLE:END\s*-->",
-    re.S)
+# the documents that describe the tree as it is (PERF.md, ROADMAP.md and
+# CHANGES.md are histories and name what is gone)
+DOCUMENTS = ["README.md",
+             *sorted(f"docs/{p.name}" for p in (REPO / "docs").glob("*.md")),
+             ".claude/skills/verify/SKILL.md"]
+
+_TREE = ("analytics_zoo_tpu/", "benchmark/", "tests/", "docs/", "examples/")
+_PACKAGES = tuple(f"{p.name}/" for p in PACKAGE.iterdir() if p.is_dir())
+_ROOT_SCRIPT = re.compile(r"^\w+\.py$")
+_ROOT_RECORD = re.compile(r"^[A-Z][A-Z0-9]*(_\w+)?\.(json|jsonl|md)$")
+_LINE_OR_TEST = re.compile(r"(::[\w\[\]\-.:]+|:\d[\d,\-–]*)$")
+_PATTERN_CHARS = set("<>*{}[]…$")
+_FILE_SUFFIXES = (".py", ".md", ".json", ".jsonl")
+_WRAPPING = "`()\"'.,;:"
 
 
-def _newest_slo_artifact():
-    arts = sorted(REPO.glob("SLO_*.json"))
-    if not arts:
-        pytest.skip("no SLO_*.json artifact in repo root")
-    return arts[-1]
+@functools.lru_cache(maxsize=None)
+def _package_sources():
+    return "\n".join(p.read_text() for p in sorted(PACKAGE.rglob("*.py")))
 
 
-def _pinned_slo_tables():
-    """SLO_TABLE blocks in docs/LOADGEN.md — same marker/attr grammar
-    as BENCH_TABLE (``requires=`` gates a table on artifacts that have
-    the key; ``tolerance=`` sets the relative tolerance, 0 pins an
-    exact invariant like warm_compile_count)."""
-    tables = []
-    for m in _SLO_TABLE_RE.finditer(LOADGEN_DOC.read_text()):
-        attrs = dict(re.findall(r"(\w+)=(\S+)", m.group(1)))
-        claims = []
-        for line in m.group(2).splitlines():
-            cells = [c.strip() for c in line.strip().strip("|").split("|")]
-            if (len(cells) != 2 or cells[0] in ("key", "")
-                    or "---" in cells[0]):
+def dangling_paths(text, source=False):
+    """The paths into the tree that ``text`` names and the tree lacks.
+
+    The rule.  A word names a path into the tree when it
+
+    - starts with ``analytics_zoo_tpu/``, ``benchmark/``, ``tests/``,
+      ``docs/`` or ``examples/``; or
+    - starts with a package of ``analytics_zoo_tpu/`` and ends in ``.py``
+      or ``/`` (the documents' shorthand, ``ops/flash_attention.py``),
+      and is then looked for under ``analytics_zoo_tpu/``; or
+    - has no directory and is a ``*.py`` (a root-level script); or
+    - has no directory and is an upper-case ``.json``, ``.jsonl`` or
+      ``.md`` (a root-level record such as ``BENCH_r05.json``),
+
+    with a trailing ``:line`` or ``::test`` stripped first.  Outside the
+    rule: a pattern (anything with ``<``, ``>``, ``*``, braces, brackets
+    or ``$``: ``<cell>``, ``tests/test_*.py``), and what a run writes
+    (``flight_NNNN.json``, a model's ``config.json``: lower-case bare
+    names are not records; a checkpoint's ``MANIFEST.json``: a bare name
+    that the package holds as a string literal is a file the program
+    writes, not one the repo keeps).
+
+    In a document only back-ticked words count.  In the comments and
+    docstrings of a source file (``source``) nothing is back-ticked by
+    habit, so every word counts, and prose with a slash in it has to be
+    told from a path: there a word of the first kind counts only when it
+    ends in ``.py``, ``.md``, ``.json`` or ``.jsonl``, and the second and
+    third kinds are left out (a bare ``model.py`` in a docstring is the
+    module beside it or the reference project's).
+    """
+    spans = [text] if source else re.findall(r"`([^`\n]+)`", text)
+    missing = set()
+    for span in spans:
+        for word in span.split():
+            word = _LINE_OR_TEST.sub("", word.strip(_WRAPPING))
+            word = word.rstrip(_WRAPPING)
+            if not word or _PATTERN_CHARS & set(word):
                 continue
-            claims.append((cells[0], float(cells[1])))
-        assert claims, "a pinned SLO table is empty"
-        tables.append({"requires": attrs.get("requires"),
-                       "tolerance": float(attrs.get("tolerance", 0.02)),
-                       "claims": claims})
-    assert tables, "LOADGEN.md lost its SLO_TABLE markers"
-    return tables
+            bare = "/" not in word
+            if word.startswith(_TREE):
+                if source and not word.endswith(_FILE_SUFFIXES):
+                    continue
+                found = (REPO / word).exists()
+            elif bare and _ROOT_RECORD.match(word):
+                found = ((REPO / word).exists()
+                         or f'"{word}"' in _package_sources())
+            elif source:
+                continue
+            elif bare and _ROOT_SCRIPT.match(word):
+                found = (REPO / word).exists()
+            elif word.startswith(_PACKAGES) and word.endswith((".py", "/")):
+                found = (PACKAGE / word).exists()
+            else:
+                continue
+            if not found:
+                missing.add(word)
+    return sorted(missing)
 
 
-class TestLoadgenDocDrift:
-    """docs/LOADGEN.md's pinned SLO rows == the newest SLO_*.json."""
-
-    def test_pinned_slo_claims_match_newest_artifact(self):
-        art = _newest_slo_artifact()
-        doc = json.loads(art.read_text())
-        bad = []
-        for table in _pinned_slo_tables():
-            req = table["requires"]
-            if req and _resolve(doc, req, required=False) is None:
-                continue        # artifact predates this load leg
-            for key, claimed in table["claims"]:
-                actual = _resolve(doc, key)
-                assert isinstance(actual, (int, float)), \
-                    f"{key} resolves to non-numeric {actual!r}"
-                if actual != pytest.approx(claimed,
-                                           rel=table["tolerance"]):
-                    bad.append(f"{key}: doc={claimed} artifact={actual}")
-        assert not bad, (f"LOADGEN.md drifted from {art.name}:\n  "
-                         + "\n  ".join(bad))
-
-    def test_slo_tables_pin_the_hard_invariants(self):
-        """Grammar + coverage, artifact or not: the doc of record must
-        pin the three invariants the chaos soak proves — zero live
-        compiles after a warm restart, shed confined to the over-SLO
-        model, and the open-loop property."""
-        tables = _pinned_slo_tables()
-        keys = {k for t in tables for k, _ in t["claims"]}
-        for must in ("parsed.kill.warm_compile_count",
-                     "parsed.mix_shift.only_over_slo_shed",
-                     "parsed.open_loop.offered_rate_independent"):
-            assert must in keys, f"LOADGEN.md no longer pins {must}"
-        # exact invariants live in a zero-tolerance table
-        strict = [t for t in tables if t["tolerance"] == 0.0]
-        assert strict, "LOADGEN.md lost its zero-tolerance SLO table"
-        assert any(t["requires"] for t in tables)
+def _comments_and_docstrings(source):
+    """What a source file says to its reader: comments and docstrings,
+    no code and no other string."""
+    out = [tok.string for tok in
+           tokenize.generate_tokens(io.StringIO(source).readline)
+           if tok.type == tokenize.COMMENT]
+    for node in ast.walk(ast.parse(source)):
+        if isinstance(node, (ast.Module, ast.ClassDef, ast.FunctionDef,
+                             ast.AsyncFunctionDef)):
+            out.append(ast.get_docstring(node, clean=False) or "")
+    return "\n".join(out)
 
 
-def _bench():
-    spec = importlib.util.spec_from_file_location("bench", REPO / "bench.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+class TestDocsPointAtTheTree:
+    """One instrument (``benchmark/run.py``), one record
+    (``PERF_LEDGER.jsonl``): no document sends a reader to a file the
+    tree no longer has."""
 
+    @pytest.mark.parametrize("document", DOCUMENTS)
+    def test_every_path_a_document_names_exists(self, document):
+        missing = dangling_paths((REPO / document).read_text())
+        assert not missing, f"{document} names what the tree lacks: {missing}"
 
-class TestBenchNeedsAChip:
-    """A measurement path that finds no TPU fails: no JSON line, no
-    per-chip metric; and a section that raised fails the run."""
+    def test_the_rule_catches_a_dangling_pointer(self):
+        text = ("Run `python bench.py` and compare with `BENCH_r05.json`; "
+                "the kernel is `analytics_zoo_tpu/ops/nope.py:12` "
+                "(`ops/nope_too.py`), tested by "
+                "`tests/test_doc_drift.py::TestDocsPointAtTheTree`, and "
+                "writes `flight_0001.json` and `MANIFEST.json`; "
+                "bench.py without back-ticks is prose.")
+        assert dangling_paths(text) == [
+            "BENCH_r05.json", "analytics_zoo_tpu/ops/nope.py", "bench.py",
+            "ops/nope_too.py"]
+        comment = "# pinned in SLO_r18.json (docs/GONE.md); see tests/callers"
+        assert dangling_paths(comment, source=True) == [
+            "SLO_r18.json", "docs/GONE.md"]
 
-    def test_no_tpu_exits_nonzero_and_prints_no_metric(self, capsys):
-        import jax
-
-        b = _bench()
-        before = jax.config.jax_compilation_cache_dir
-        try:
-            with pytest.raises(SystemExit) as exc:
-                b.main()
-        finally:    # main() placed the compile cache; nothing compiled
-            jax.config.update("jax_compilation_cache_dir", before)
-        assert exc.value.code == 1
-        out, err = capsys.readouterr()
-        assert out == "" and "measures a TPU" in err
-
-    def test_error_keys_finds_every_recorded_failure(self):
-        b = _bench()
-        extra = {"int8_error": "ValueError: x", "matmul_4096": {"ms": 1.0},
-                 "dlrm": {"child_error": "child rc=1: boom"},
-                 "restart": {"cold_error": "rc=2", "error": ""},
-                 "parity_max_err": 1e-6}
-        assert sorted(b._error_keys(extra)) == [
-            "dlrm.child_error", "int8_error", "restart.cold_error"]
-
-
-class TestBenchNonFiniteGuards:
-    """The helpers that keep Infinity/NaN out of future artifacts."""
-
-    def test_safe_ratio_refuses_degenerate_operands(self):
-        b = _bench()
-        assert b._safe_ratio(2.0, 1.0) == 2.0
-        assert b._safe_ratio(1.13, 1.0, nd=3) == 1.13
-        for num, den in [(1.0, 0.0), (1.0, -1.0), (0.0, 1.0),
-                         (None, 1.0), (1.0, None),
-                         (float("inf"), 1.0), (1.0, float("nan")),
-                         ("fast", 1.0)]:
-            assert b._safe_ratio(num, den) is None, (num, den)
-
-    def test_sanitize_json_strips_non_finite(self):
-        b = _bench()
-        report = {"a": float("inf"),
-                  "b": {"c": float("nan"), "d": 1.5},
-                  "e": [1.0, float("-inf"), "x"]}
-        clean = b._sanitize_json(report)
-        assert clean == {"a": None, "b": {"c": None, "d": 1.5},
-                         "e": [1.0, None, "x"]}
-        json.dumps(clean, allow_nan=False)   # strict JSON round-trips
-
-    def test_measure_scan_returns_none_below_resolution(self):
-        import numpy as np
-        b = _bench()
-        # an instant program has no measurable slope: the old code
-        # clamped to ~0 and downstream ratios minted Infinity
-        r = b._measure_scan(lambda c, n: c, np.zeros(4), K=16,
-                            rounds=2, probe=False)
-        assert r is None
-
-    def test_roofline_rows_guard_degenerate_inputs(self):
-        b = _bench()
-        row = b._roofline(int(1e8), int(3e8), 1e-3)
-        assert row["bytes_ideal"] == int(1e8)
-        assert row["bytes_moved"] == int(3e8)
-        assert row["traffic_ratio"] == 3.0
-        assert row["gbps_achieved"] == 300.0
-        # no measured time: the GB/s row is ABSENT, not 0/Infinity
-        assert "gbps_achieved" not in b._roofline(100, 300, None)
-        assert b._roofline(100, 0, 1.0)["traffic_ratio"] is None
-
-
-class TestBenchKernelLegProfiler:
-    """The FlightRecorder wired through the kernel bench legs: a
-    speedup-floor breach lands BOTH a flight record and a device
-    profiler trace under BENCH_PROFILE_DIR/<leg>, so the trace that
-    explains a regression ships with the artifact."""
-
-    def test_breach_trace_file_lands(self, tmp_path, monkeypatch):
-        import time
-
-        import jax.numpy as jnp
-
-        b = _bench()
-        monkeypatch.setenv("BENCH_PROFILE_DIR", str(tmp_path))
-        jnp.zeros(1).block_until_ready()    # backend up pre-profiler
-        out = {"fused_vs_unfused_speedup": 0.5}
-        b._breach_check(out, "embedding_bag",
-                        "fused_vs_unfused_speedup", 1.3)
-        assert "breach_recorder_error" not in out, out
-        rec = out.get("breach_flight_record")
-        assert rec and Path(rec).exists()
-        leg_dir = tmp_path / "embedding_bag"
-        deadline = time.time() + 20.0       # trace thread is async
-        trace = []
-        while time.time() < deadline and not trace:
-            trace = list(leg_dir.glob("plugins/profile/*/*.xplane.pb"))
-            time.sleep(0.1)
-        assert trace, "profiler trace never landed under profile_dir"
-
-    def test_no_breach_no_record(self, tmp_path, monkeypatch):
-        b = _bench()
-        monkeypatch.setenv("BENCH_PROFILE_DIR", str(tmp_path))
-        for spd in (2.0, 1.3, None):        # unresolved is NOT a breach
-            out = {"fused_vs_unfused_speedup": spd}
-            b._breach_check(out, "embedding_bag",
-                            "fused_vs_unfused_speedup", 1.3)
-            assert "breach_flight_record" not in out, spd
-        assert not list(tmp_path.iterdir())
+    def test_no_source_file_names_a_missing_record(self):
+        missing = {}
+        for root in (PACKAGE, REPO / "examples"):
+            for path in sorted(root.rglob("*.py")):
+                said = _comments_and_docstrings(path.read_text())
+                gone = dangling_paths(said, source=True)
+                if gone:
+                    missing[str(path.relative_to(REPO))] = gone
+        assert not missing, f"source files name what the tree lacks: {missing}"

@@ -25,12 +25,18 @@ def _mlp(out_dim, in_dim, activation=None):
 class TestGANEstimator:
     def test_learns_a_gaussian(self, zoo_ctx):
         # 2D target distribution N([3, -1], 0.5I): after training the
-        # generator's samples move toward the target mean
+        # generator's samples move toward the target mean.  The estimator
+        # seeds itself from its context, and without one it takes the
+        # GLOBAL context, which an earlier test in the same process may
+        # have left at another seed (tests/test_featureset_streaming.py
+        # does): the mean's error overshoots and swings (0.3 at its least,
+        # 1.6-2.2 at epoch 16 on seeds 1, 2, 3), so the thresholds below
+        # hold for the fixture's seed, not for whatever ran before.
         rs = np.random.RandomState(0)
         real = (rs.randn(2048, 2) * 0.5 + [3.0, -1.0]).astype(np.float32)
         gan = GANEstimator(generator=_mlp(2, 4),
-                           discriminator=_mlp(1, 2), noise_dim=4)
-        before = gan_mean_err = None
+                           discriminator=_mlp(1, 2), noise_dim=4,
+                           ctx=zoo_ctx)
         gan.fit(real, batch_size=128, epochs=1, verbose=False)
         before = np.abs(gan.generate(512).mean(0) - [3.0, -1.0]).sum()
         gan.fit(real, batch_size=128, epochs=15, verbose=False)

@@ -119,9 +119,10 @@ class TestPayloads:
         assert picks1 == picks2
         assert set(picks1) == {"a", "b"}
 
-    def test_saturated_images_matches_bench_stream(self):
-        """bench_serving's historical draw stream must be preserved
-        byte-for-byte when it routes through the shared helper."""
+    def test_saturated_images_is_a_plain_randomstate_stream(self):
+        """The helper's draw stream is a plain ``RandomState`` one,
+        byte for byte, so a caller that interleaves other draws on the
+        same stream keeps its sequence."""
         crs = np.random.RandomState(7)
         a = saturated_images(4, rs=crs)
         crs2 = np.random.RandomState(7)
@@ -132,10 +133,10 @@ class TestPayloads:
         c = saturated_images(2, seed=7)
         assert np.array_equal(c[0], b[0])
 
-    def test_zipfian_payload_matches_bench_generator_bytes(self):
+    def test_zipfian_payload_matches_the_shared_generator_bytes(self):
         """The skew contract (ISSUE 19): the payload class's id blocks
         are BYTE-IDENTICAL to ``data.zipf.zipfian_ids`` for the same
-        generator state — a bench hit-rate claim at s=1.0 is literally
+        generator state — a hit rate asserted at s=1.0 is literally
         about the traffic this class offers."""
         from analytics_zoo_tpu.data.zipf import zipfian_ids
 

@@ -1,4 +1,4 @@
-"""Slow loadgen soaks: the chaos proofs behind SLO_r18.json.
+"""Slow loadgen soaks: the chaos proofs docs/LOADGEN.md names.
 
 Three legs, each a full production-shaped run through the real
 pipeline (CI runs these in the multiprocess job and uploads the

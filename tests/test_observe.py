@@ -466,6 +466,32 @@ class TestFlightRecorder:
         assert st["last_reason"] == "operator_request"
         assert st["last_path"] == out
 
+    @pytest.mark.parametrize("armed", [True, False],
+                             ids=["profile_dir", "no_profile_dir"])
+    def test_trigger_arms_the_profiler_only_with_a_profile_dir(
+            self, tmp_path, armed):
+        """The recorder's profiler arm: with ``profile_dir`` a capture
+        lands its record AND a short device trace there (written by a
+        thread of its own, so the trace is waited for); without one it
+        lands the record alone."""
+        import jax.numpy as jnp
+
+        jnp.zeros(1).block_until_ready()    # backend up pre-profiler
+        profile_dir = tmp_path / "profile"
+        rec, _reg, _tr = _recorder(
+            FakeClock(), tmp_path / "records", profile_ms=50,
+            profile_dir=str(profile_dir) if armed else None)
+        path = rec.trigger("unit_test", detail={"floor": 1.3})
+        assert path and json.load(open(path))["reason"] == "unit_test"
+        trace = []
+        deadline = time.time() + (20.0 if armed else 0.5)
+        while time.time() < deadline and not trace:
+            trace = list(profile_dir.glob("plugins/profile/*/*.xplane.pb"))
+            time.sleep(0.1)
+        assert bool(trace) == armed
+        assert sorted(p.name for p in tmp_path.iterdir()) == (
+            ["profile", "records"] if armed else ["records"])
+
     def test_capture_bumps_flight_counter(self):
         clock = FakeClock()
         rec, _reg, _tr = _recorder(clock)

@@ -10,16 +10,10 @@ stand-in for the Mosaic path).  Routing: the counted dispatch contract
 decision is visible both in ``ops_kernel_selected_total`` and in the
 jaxpr (a ``ppermute`` only appears when the ring is actually taken).
 Memory: inside the shard_map body no array may exceed the per-shard
-logits block — the O(L/ways) per-chip residency the ring exists for.
-Docs: the analytic-r17 rows pinned in docs/PERFORMANCE.md are
-machine-checked against ``bench.ring_attention_geometry`` so the doc of
-record cannot drift from the arithmetic.
+logits block — the O(L/ways) per-chip residency the ring exists for
+(docs/PARALLELISM.md "Sequence parallelism" states it; this is the
+live check).
 """
-
-import importlib.util
-import re
-import time
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -31,8 +25,6 @@ from analytics_zoo_tpu.ops import dispatch
 from analytics_zoo_tpu.ops.attention import blockwise_attention
 from analytics_zoo_tpu.ops.ring_attention import (RING_MIN_LEN,
                                                   ring_attention)
-
-REPO = Path(__file__).resolve().parent.parent
 
 
 def _mesh(ways, axis="seq"):
@@ -296,82 +288,3 @@ class TestRingMemory:
             f"(L/ways)² logits block ({per_shard_logits})")
         # and nothing per-chip ever sees the full sequence axis
         assert all(l not in a.shape for a in inner)
-
-
-class TestRingGeometryDoc:
-    """docs/PERFORMANCE.md analytic-r17 rows == the bench arithmetic."""
-
-    _TABLE_RE = re.compile(
-        r"<!--\s*BENCH_TABLE:BEGIN([^>]*)-->(.*?)<!--\s*BENCH_TABLE:END"
-        r"\s*-->", re.S)
-
-    def test_pinned_rows_match_bench_arithmetic(self):
-        b = _bench()
-        doc = (REPO / "docs" / "PERFORMANCE.md").read_text()
-        table = None
-        for m in self._TABLE_RE.finditer(doc):
-            attrs = dict(re.findall(r"(\w+)=(\S+)", m.group(1)))
-            if attrs.get("source") == "analytic-r17":
-                table = m.group(2)
-        assert table, "PERFORMANCE.md lost its analytic-r17 table"
-        geo = {f"l{L}": b.ring_attention_geometry(L, 4)
-               for L in (8192, 32768, 131072)}
-        geo["ways"] = 4
-        prefix = "parsed.extra.ring_attention.geometry."
-        rows = 0
-        for line in table.splitlines():
-            cells = [c.strip() for c in line.strip().strip("|").split("|")]
-            if len(cells) != 2 or cells[0] in ("key", "") \
-                    or "---" in cells[0]:
-                continue
-            key, want = cells[0], float(cells[1])
-            assert key.startswith(prefix), key
-            node = geo
-            for part in key[len(prefix):].split("."):
-                node = node[part]
-            assert float(node) == want, f"{key}: doc={want} bench={node}"
-            rows += 1
-        assert rows >= 14, f"analytic-r17 table shrank to {rows} rows"
-
-
-def _bench():
-    spec = importlib.util.spec_from_file_location("bench",
-                                                  REPO / "bench.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestRingBenchBreachTrace:
-    """The ring bench leg wires the same FlightRecorder + profiler
-    capture as the embedding-bag leg: a ring_vs_single_speedup floor
-    breach must land a flight record AND a device trace under
-    BENCH_PROFILE_DIR/ring_attention."""
-
-    def test_breach_trace_file_lands(self, tmp_path, monkeypatch):
-        b = _bench()
-        monkeypatch.setenv("BENCH_PROFILE_DIR", str(tmp_path))
-        jnp.zeros(1).block_until_ready()    # backend up pre-profiler
-        out = {"ring_vs_single_speedup": 0.5}
-        b._breach_check(out, "ring_attention",
-                        "ring_vs_single_speedup", 1.0)
-        assert "breach_recorder_error" not in out, out
-        rec = out.get("breach_flight_record")
-        assert rec and Path(rec).exists()
-        leg_dir = tmp_path / "ring_attention"
-        deadline = time.time() + 20.0       # trace thread is async
-        trace = []
-        while time.time() < deadline and not trace:
-            trace = list(leg_dir.glob("plugins/profile/*/*.xplane.pb"))
-            time.sleep(0.1)
-        assert trace, "profiler trace never landed under profile_dir"
-
-    def test_no_breach_no_record(self, tmp_path, monkeypatch):
-        b = _bench()
-        monkeypatch.setenv("BENCH_PROFILE_DIR", str(tmp_path))
-        for spd in (1.6, 1.0, None):        # unresolved is NOT a breach
-            out = {"ring_vs_single_speedup": spd}
-            b._breach_check(out, "ring_attention",
-                            "ring_vs_single_speedup", 1.0)
-            assert "breach_flight_record" not in out, spd
-        assert not list(tmp_path.iterdir())

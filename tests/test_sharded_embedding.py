@@ -1055,7 +1055,7 @@ class TestCachedShardedLookup:
     @pytest.mark.transfer_guard
     def test_pad_slots_skip_route_metrics_and_cold(self, tp_ctx):
         """Pad slots never enter the routing tier: they count in NO
-        lookup metric (the hit-rate gauge the bench pins stays pure
+        lookup metric (the hit-rate gauge stays pure
         traffic) and an all-pad bag triggers NO cold exchange at all —
         it completes under the transfer guard on an EMPTY cache."""
         import jax

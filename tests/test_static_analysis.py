@@ -177,7 +177,7 @@ def test_stream_uploader_fixture():
 
 
 def test_fused_kernel_driver_fixture():
-    """The kernel-bench driver idiom behind bench.py's Pallas legs:
+    """The kernel-sweep driver idiom (a loop over a kernel's tiles):
     draining every tile with a per-iteration block_until_ready fires
     JG-TRANSFER-HOT; the shipped drivers enqueue the sweep and sync
     once on the last handle — quiet by construction."""
