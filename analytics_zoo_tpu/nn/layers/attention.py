@@ -17,10 +17,11 @@ through the same op interface (parallel/sequence.py).
 from __future__ import annotations
 
 import math
-from typing import Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from analytics_zoo_tpu.nn import activations, initializers
 from analytics_zoo_tpu.nn.module import Layer, StatelessLayer, split_rng
@@ -41,6 +42,14 @@ def _dense_params(rng, d_in, d_out, init, dtype=jnp.float32,
 def _dense(p, x):
     y = jnp.dot(x, p["kernel"])
     return y + p["bias"] if "bias" in p else y
+
+
+def _named_dense(params, name, x):
+    """``_dense`` of ``params[name]`` with its result named ``name`` for a
+    ``jax.checkpoint`` policy around the caller (``_kept_for_backward``);
+    under no ``jax.checkpoint`` the name is the identity and lowers to
+    nothing."""
+    return checkpoint_name(_dense(params[name], x), name)
 
 
 # Single source of LayerNorm math: the canonical layer from normalization.py
@@ -156,9 +165,9 @@ class MultiHeadAttention(StatelessLayer):
                 mask = inputs[1]
         else:
             q_in, kv_in, mask = inputs
-        q = self._split_heads(_dense(params["q"], q_in))
-        k = self._split_heads(_dense(params["k"], kv_in))
-        v = self._split_heads(_dense(params["v"], kv_in))
+        q = self._split_heads(_named_dense(params, "q", q_in))
+        k = self._split_heads(_named_dense(params, "k", kv_in))
+        v = self._split_heads(_named_dense(params, "v", kv_in))
         if self.rotary is not None:
             q = self.rotary.forward({}, q)
             k = self.rotary.forward({}, k)
@@ -220,7 +229,7 @@ class MultiHeadAttention(StatelessLayer):
                                             dropout_rng=r1)
         b, h, l, hd = out.shape
         out = out.transpose(0, 2, 1, 3).reshape(b, l, h * hd)
-        out = _dense(params["o"], out)
+        out = _named_dense(params, "o", out)
         return _dropout(r2, out, self.output_drop, training)
 
 
@@ -290,13 +299,15 @@ def _stack_block_params(block, keys, hshape):
 
 
 def _run_block_stack(block, n_block, blocks_params, x, training, rng,
-                     mask=None, remat: bool = False):
+                     mask=None, remat: Optional[Sequence[str]] = None):
     """Run a stacked homogeneous block pytree: the GPipe schedule under
     an active pipeline regime, otherwise one `lax.scan` (per-block rng
     threading for dropout).  Shared by TransformerLayer, BERT and
     LoopedDecoderStack so the stacked paths cannot diverge.  ``remat``
-    keeps only each block's input for the backward pass and computes the
-    block again there (the pipeline regime has its own ``pipe.remat``)."""
+    names what the backward pass keeps of a block besides its input (the
+    ``checkpoint_name``s of the projections' results) and computes the
+    rest of the block again there: none named, the whole block; ``None``,
+    nothing again (the pipeline regime has its own ``pipe.remat``)."""
     pipe = _current_pipeline()
     if pipe is not None:
         from analytics_zoo_tpu.parallel.pipeline import pipeline_apply
@@ -324,8 +335,10 @@ def _run_block_stack(block, n_block, blocks_params, x, training, rng,
         args = (h,) if mask is None else (h, mask)
         return block.forward(p, *args, training=training, rng=r)
 
-    if remat:  # zoolint: disable=JG-TRACED-BRANCH(a python bool decided from static shapes before tracing)
-        apply = jax.checkpoint(apply)
+    if remat is not None:  # zoolint: disable=JG-TRACED-BRANCH(names decided from static shapes before tracing)
+        apply = jax.checkpoint(apply, policy=(
+            jax.checkpoint_policies.save_only_these_names(*remat)
+            if remat else None))
 
     if rng is not None:  # zoolint: disable=JG-TRACED-BRANCH(None-ness is static pytree structure)
         rngs = jax.random.split(rng, n_block)
@@ -365,8 +378,9 @@ class GatedFFN(StatelessLayer):
                 "down": mk(ks[2], ff, d)}
 
     def forward(self, params, x, training=False, rng=None):
-        return _dense(params["down"], self.act(_dense(params["gate"], x))
-                      * _dense(params["up"], x))
+        return _named_dense(
+            params, "down", self.act(_named_dense(params, "gate", x))
+            * _named_dense(params, "up", x))
 
 
 class SandwichDecoderBlock(StatelessLayer):
@@ -401,9 +415,70 @@ class SandwichDecoderBlock(StatelessLayer):
             params["ffn"], n(params["norm3"], a)))
 
 
-# a looped stack whose blocks would keep more than this for the backward
-# pass computes each block again there instead
-_REMAT_OVER_BYTES = 1 << 30
+# What the looped stack may keep for the backward pass beyond a chip that
+# already trains the model with every block computed again there (the layer
+# cannot see the optimizer's state, and a program that read the free memory
+# at trace time could be neither cached nor reasoned about).  Set on a TPU
+# v5e (16 GB) from ``ouro-2.6b-fit-packed4k``, whose named values are
+# 0.75 GiB each over its 24 applications of 8,192 tokens: between the one
+# value whose keeping paid there (``down``: the step 2.5 % shorter, 2.2 GiB
+# of the chip left free at its fullest) and the two that memory would have
+# allowed (1.5 GiB: 1.1 GiB free) but that made the step slower again,
+# because XLA spends more on a second kept value's layout and float32 copy
+# than its product costs (PERF.md section 6, PR 34: the sweep's table).
+_KEEP_BYTES = 1 << 30
+
+# The projections of a sandwich block by the name of their result
+# (``_named_dense``): fan-in, fan-out.  Keeping a result costs fan-out
+# values a token and spares fan-in x fan-out multiply-adds in the backward
+# pass, so a kept byte spares fan-in / itemsize of them.
+_PROJECTIONS = (("down", "ffn", "hidden"), ("o", "hidden", "hidden"),
+                ("q", "hidden", "hidden"), ("k", "hidden", "hidden"),
+                ("v", "hidden", "hidden"), ("gate", "hidden", "ffn"),
+                ("up", "hidden", "ffn"))
+
+
+def _kept_for_backward(tokens: int, itemsize: int, hidden: int,
+                       intermediate: int, applications: int
+                       ) -> Dict[str, int]:
+    """What ``applications`` sandwich blocks over ``tokens`` tokens keep for
+    the backward pass, in bytes by name: ``block_input`` always; each
+    projection's result (0: computed again); ``rest``, what else a block
+    keeps when nothing is computed again (0 otherwise).
+
+    Everything fits ``_KEEP_BYTES`` (a block keeps about ten hidden-wide
+    and three FFN-wide values a token: the norms' and projections' inputs,
+    the gate's two factors): all is kept, ``rest`` among it.  Otherwise the
+    projections are taken by multiply-adds spared a kept byte while their
+    sum stays within ``_KEEP_BYTES``; one that does not fit is passed over
+    and a smaller one after it may still be taken.  None taken: each block
+    is computed again whole."""
+    width = {"hidden": hidden, "ffn": intermediate}
+    a_value = tokens * itemsize * applications
+    size = {name: a_value * width[fan_out]
+            for name, _, fan_out in _PROJECTIONS}
+    kept = {"block_input": a_value * hidden, **dict.fromkeys(size, 0),
+            "rest": 0}
+    everything = a_value * (10 * hidden + 3 * intermediate)
+    if everything <= _KEEP_BYTES:
+        kept.update(size)
+        kept["rest"] = everything - sum(kept.values())
+        return kept
+    room = _KEEP_BYTES
+    # sorted() is stable: equals stay in the table's order
+    for name, _, _ in sorted(_PROJECTIONS, key=lambda p: -width[p[1]]):
+        if size[name] <= room:
+            kept[name] = size[name]
+            room -= size[name]
+    return kept
+
+
+def _kept_names(kept: Dict[str, int]) -> Optional[Sequence[str]]:
+    """Of ``_kept_for_backward``'s answer, what ``_run_block_stack`` is
+    told: the projections kept; ``None`` where nothing is computed again."""
+    if kept["rest"]:
+        return None
+    return [name for name, _, _ in _PROJECTIONS if kept[name]]
 
 
 class LoopedDecoderStack(StatelessLayer):
@@ -419,10 +494,13 @@ class LoopedDecoderStack(StatelessLayer):
     passes, so one block is traced and compiled, not ``n_block * passes``.
     Every block's gradient is the sum over the passes.
 
-    Where the ``n_block * passes`` applications would keep more than
-    1 GiB for the backward pass (estimated from the block's widths and
-    the input's shape), each is computed again there and only its input
-    is kept.
+    What the ``n_block * passes`` applications keep for the backward pass
+    follows from the block's widths and the input's shape
+    (``_kept_for_backward``): everything where that fits a fixed budget of
+    bytes; otherwise each block's input and, of its projections' results,
+    those dearest to compute again a kept byte (the FFN's ``down`` first)
+    that fit, the rest of the block being computed again there.  The
+    registry's ``stack_kept_bytes{name}`` says which at every trace.
     """
 
     def __init__(self, n_block: int, nhead: int, hidden_size: int,
@@ -443,15 +521,20 @@ class LoopedDecoderStack(StatelessLayer):
                     tuple(x_shape)),
                 "final_norm": self.final_norm.build_params(None, x_shape)}
 
-    def _recompute(self, x) -> bool:
-        # a block keeps about ten hidden-wide and three FFN-wide values a
-        # token: the norms' and projections' inputs, the gate's two factors
-        kept = (x.size // x.shape[-1]) * x.dtype.itemsize * (
-            10 * self.hidden_size + 3 * self.intermediate)
-        return kept * self.n_block * self.passes > _REMAT_OVER_BYTES
+    def _kept(self, x) -> Dict[str, int]:
+        """The rule at this stack's widths and ``x``'s shape, told to the
+        registry (at trace time: once a compilation)."""
+        from analytics_zoo_tpu.observe.metrics import set_gauge
+
+        kept = _kept_for_backward(
+            x.size // x.shape[-1], x.dtype.itemsize, self.hidden_size,
+            self.intermediate, self.n_block * self.passes)
+        for name, size in kept.items():
+            set_gauge("stack_kept_bytes", size, name=name)
+        return kept
 
     def forward(self, params, x, training=False, rng=None):
-        remat = self._recompute(x)
+        remat = _kept_names(self._kept(x))
 
         def one_pass(h, _):
             h = _run_block_stack(self.block, self.n_block, params["blocks"],

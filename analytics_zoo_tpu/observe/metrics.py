@@ -144,6 +144,14 @@ CATALOG: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
     "train_tokens_total": (
         "counter", "label tokens in the dispatched steps whose labels are "
         "(B, L) integers (language-model training)", ()),
+    "stack_kept_bytes": (
+        "gauge", "what a looped decoder stack keeps for the backward pass "
+        "over all its layer applications, as reckoned when the step was "
+        "last traced (nn/layers/attention._kept_for_backward): "
+        "block_input, each projection's result by its name (down | o | q "
+        "| k | v | gate | up; 0: computed again there), and rest: what "
+        "else a block keeps where nothing is computed again",
+        ("name",)),
     "train_epoch_seconds": ("histogram", "wall time of one epoch", ()),
     "train_loss": ("gauge", "last epoch mean loss", ()),
     "train_throughput_rows_per_s": (
@@ -299,6 +307,8 @@ class MetricsSnapshot:
 
 
 class MetricsRegistry:
+    # a metric's own name is positional everywhere on the write path, so a
+    # label may be called ``name`` (``stack_kept_bytes{name}``)
     def __init__(self):
         self._lock = threading.Lock()
         self._counters: Dict[SeriesKey, float] = {}
@@ -314,19 +324,19 @@ class MetricsRegistry:
         self._counters[key] = self._counters.get(key, 0) + 1
         return False
 
-    def inc(self, name: str, n: float = 1, **labels: Any) -> None:
+    def inc(self, name: str, /, n: float = 1, **labels: Any) -> None:
         key = (name, _labels_of(labels))
         with self._lock:
             self._declared(name)
             self._counters[key] = self._counters.get(key, 0) + n
 
-    def set(self, name: str, value: float, **labels: Any) -> None:
+    def set(self, name: str, /, value: float, **labels: Any) -> None:
         key = (name, _labels_of(labels))
         with self._lock:
             self._declared(name)
             self._gauges[key] = float(value)
 
-    def observe(self, name: str, value: float, **labels: Any) -> None:
+    def observe(self, name: str, /, value: float, **labels: Any) -> None:
         key = (name, _labels_of(labels))
         with self._lock:
             self._declared(name)
@@ -435,21 +445,21 @@ METRICS = MetricsRegistry()
 # -- module-level helpers with the flat-Timers mirror -----------------------
 
 
-def count(name: str, n: float = 1, flat: Optional[str] = None,
+def count(name: str, /, n: float = 1, flat: Optional[str] = None,
           **labels: Any) -> None:
     METRICS.inc(name, n, **labels)
     if flat:
         TIMERS.incr(flat, int(n))
 
 
-def set_gauge(name: str, value: float, flat: Optional[str] = None,
+def set_gauge(name: str, /, value: float, flat: Optional[str] = None,
               **labels: Any) -> None:
     METRICS.set(name, value, **labels)
     if flat:
         TIMERS.set_gauge(flat, value)
 
 
-def observe(name: str, seconds: float, flat: Optional[str] = None,
+def observe(name: str, /, seconds: float, flat: Optional[str] = None,
             **labels: Any) -> None:
     METRICS.observe(name, seconds, **labels)
     if flat:
@@ -457,7 +467,7 @@ def observe(name: str, seconds: float, flat: Optional[str] = None,
 
 
 @contextmanager
-def time_stage(name: str, flat: Optional[str] = None, **labels: Any):
+def time_stage(name: str, /, flat: Optional[str] = None, **labels: Any):
     """THE way the program times a stage: the interval is one sample in
     the ``name{labels}`` histogram and, while a ``jax.profiler`` trace
     runs, one host event ``zoo:<name>/<label values, sorted by key>`` on

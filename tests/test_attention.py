@@ -251,6 +251,52 @@ class TestTransformerAndBert:
                                    np.asarray(seq2[:, :4]),
                                    rtol=1e-4, atol=1e-4)
 
+    @pytest.mark.parametrize("what", ["forward", "gradient"])
+    def test_projection_names_lower_to_nothing(self, monkeypatch, what):
+        """The ``checkpoint_name``s on the projections' results are read by
+        the looped stack's ``jax.checkpoint`` alone: a block under none
+        lowers to the program it was without them (the lowering numbers
+        its private functions by the equations before them; no more
+        differs)."""
+        import re
+
+        from analytics_zoo_tpu.nn.layers import attention
+
+        blk = TransformerBlock(2, 32, 64, hidden_drop=0.0, attn_drop=0.0,
+                               name="inert")
+        x = jax.random.normal(KEY, (2, 8, 32))
+        params = blk.build_params(KEY, x.shape)
+
+        def traced():
+            """(jaxpr, lowered text) of a function made anew, so that no
+            earlier trace of it is found again."""
+            def fn(p, h):
+                return blk.forward(p, h)
+
+            if what == "gradient":
+                fn = jax.grad(lambda p, h: jnp.sum(jnp.square(
+                    blk.forward(p, h))))
+            return (str(jax.make_jaxpr(fn)(params, x)),
+                    re.sub(r"@(\w+?)_\d+\b", r"@\1",
+                           jax.jit(fn).lower(params, x).as_text()))
+
+        jaxpr, named = traced()
+        assert "name[name=q]" in jaxpr
+        monkeypatch.setattr(attention, "checkpoint_name", lambda v, name: v)
+        jaxpr, plain = traced()
+        assert "name[name=" not in jaxpr
+        assert named == plain
+
+    def test_unrolled_bert_computes_nothing_again(self):
+        layer = BERT(vocab=30, hidden_size=16, n_block=2, nhead=2,
+                     intermediate_size=32, max_position_len=8,
+                     hidden_drop=0.0, attn_drop=0.0)
+        ids = jnp.asarray(np.random.randint(1, 30, (1, 8)), jnp.int32)
+        params, _ = layer.init(KEY, ids.shape, ids.shape)
+        jaxpr = str(jax.make_jaxpr(jax.grad(lambda p: jnp.sum(
+            layer.forward(p, ids, jnp.zeros_like(ids))[0])))(params))
+        assert "checkpoint" not in jaxpr and "remat" not in jaxpr
+
     def test_transformer_trains_in_sequential(self):
         from analytics_zoo_tpu.nn import Sequential
         from analytics_zoo_tpu.nn.layers.core import Dense

@@ -149,30 +149,142 @@ def test_one_pass_is_a_plain_decoder():
     _close(hs[1], stack.forward(params, hs[0])[0])
 
 
-def test_recomputation_on_and_off_give_the_same_gradients(monkeypatch):
+# What the backward pass of the toy stack keeps, by the budget: everything
+# (no jax.checkpoint), ``down`` and ``o`` alone (a value is 2 blocks x 3
+# passes x 32 tokens x 4 bytes x 64 wide), each of them alone, nothing.
+_TOY_VALUE = 2 * 3 * 32 * 4 * 64
+_TOY_BUDGETS = {"none": (1 << 40, None), "down+o": (2 * _TOY_VALUE,
+                                                    ["down", "o"]),
+                "down": (_TOY_VALUE, ["down"]), "full": (0, [])}
+
+
+def _toy_stack_gradient(monkeypatch, budget):
+    """(kept names, gradient function, parameters) of the toy stack under
+    a budget of ``budget`` bytes."""
+    monkeypatch.setattr(attention, "_KEEP_BYTES", budget)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 64))
     stack = LoopedDecoderStack(2, 2, 64, 96, passes=3, name="remat")
     params = stack.build_params(jax.random.PRNGKey(1), x.shape)
-    grads = []
-    for over_bytes, recomputes in ((1 << 40, False), (0, True)):
-        monkeypatch.setattr(attention, "_REMAT_OVER_BYTES", over_bytes)
-        assert stack._recompute(x) is recomputes
-        jaxpr = str(jax.make_jaxpr(jax.grad(lambda p: jnp.sum(jnp.square(
-            stack.forward(p, x)))))(params))
-        assert ("checkpoint" in jaxpr or "remat" in jaxpr) is recomputes
-        grads.append(jax.grad(lambda p: jnp.sum(jnp.square(
-            stack.forward(p, x))))(params))
-    for a, b in zip(*map(jax.tree_util.tree_leaves, grads)):
+    grad = jax.grad(lambda p: jnp.sum(jnp.square(stack.forward(p, x))))
+    return attention._kept_names(stack._kept(x)), grad, params
+
+
+@pytest.mark.parametrize("case", list(_TOY_BUDGETS))
+def test_recomputation_of_any_grade_gives_the_same_gradients(monkeypatch,
+                                                             case):
+    budget, names = _TOY_BUDGETS[case]
+    kept, grad, params = _toy_stack_gradient(monkeypatch, budget)
+    assert kept == names
+    jaxpr = str(jax.make_jaxpr(grad)(params))
+    assert ("checkpoint" in jaxpr or "remat" in jaxpr) is (names is not None)
+    got = grad(params)
+    _, grad, params = _toy_stack_gradient(monkeypatch, 0)
+    for a, b in zip(*map(jax.tree_util.tree_leaves, (got, grad(params)))):
         _close(a, b, tol=1e-5)
 
 
-def test_recomputation_follows_from_the_shapes():
-    """Off where the blocks keep little, on at the benchmark's shapes."""
-    tiny = LoopedDecoderStack(3, 2, 64, 96, passes=4, name="s1")
-    assert not tiny._recompute(jnp.zeros((4, 32, 64)))
-    real = LoopedDecoderStack(6, 16, 2048, 5632, passes=4, name="s2")
-    x = jax.ShapeDtypeStruct((2, 4096, 2048), jnp.bfloat16)
-    assert real._recompute(x)
+def _dots(jaxpr) -> int:
+    """``dot_general`` equations of a jaxpr and of every jaxpr inside it."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "dot_general"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _dots(sub)
+    return n
+
+
+def test_each_kept_value_takes_its_product_out_of_the_backward_pass(
+        monkeypatch):
+    """The gradient's jaxpr holds one ``dot_general`` fewer with each
+    projection kept: 9 of a block's 36 are the forward computed again."""
+    counts = {}
+    for case, (budget, _) in _TOY_BUDGETS.items():
+        _, grad, params = _toy_stack_gradient(monkeypatch, budget)
+        counts[case] = _dots(jax.make_jaxpr(grad)(params).jaxpr)
+    assert counts == {"full": 36, "down": 35, "down+o": 34, "none": 27}
+
+
+# (case, tokens, itemsize, hidden, intermediate, applications, budget, kept)
+_RULE_CASES = [
+    # the toy stacks of this file keep everything: no jax.checkpoint
+    ("tiny", 4 * 32, 4, 64, 96, 3 * 4, None, None),
+    # the benchmark's shapes under the module's own constant
+    ("benchmark", 2 * 4096, 2, 2048, 5632, 6 * 4, None, "table"),
+    # ... which admits the one value that paid on the chip (PERF.md, PR 34)
+    ("benchmark-as-set", 2 * 4096, 2, 2048, 5632, 6 * 4, None, ["down"]),
+    ("benchmark-nothing-fits", 2 * 4096, 2, 2048, 5632, 6 * 4,
+     (3 << 28) - 1, []),
+    ("benchmark-one", 2 * 4096, 2, 2048, 5632, 6 * 4, 3 << 28, ["down"]),
+    ("benchmark-five", 2 * 4096, 2, 2048, 5632, 6 * 4, 15 << 28,
+     ["down", "o", "q", "k", "v"]),
+    # float32, twice the rows: a value is 3 GiB and none fits
+    ("benchmark-float32-x2", 4 * 4096, 4, 2048, 5632, 6 * 4, None, []),
+    # a narrow FFN: ``o`` does not fit, is passed over, ``gate`` is taken
+    ("passed-over", 64, 4, 64, 16, 6, 64 * 4 * 6 * 40, ["gate", "up"]),
+]
+
+
+@pytest.mark.parametrize("case", _RULE_CASES, ids=lambda c: c[0])
+def test_what_is_kept_follows_from_the_shapes(monkeypatch, case):
+    _, tokens, itemsize, hidden, ffn, applications, budget, want = case
+    if budget is not None:
+        monkeypatch.setattr(attention, "_KEEP_BYTES", budget)
+    kept = attention._kept_for_backward(tokens, itemsize, hidden, ffn,
+                                        applications)
+    assert list(kept) == ["block_input"] + [
+        p[0] for p in attention._PROJECTIONS] + ["rest"]
+    assert kept["block_input"] == tokens * itemsize * applications * hidden
+    names = attention._kept_names(kept)
+    if want == "table":
+        # the names in the table's order, dearest first, as far as they
+        # fit; ``gate`` and ``up`` (2.06 GiB each here) never among them
+        order = [p[0] for p in attention._PROJECTIONS]
+        assert names == order[:len(names)] and 1 <= len(names) <= 5
+        assert {kept[n] for n in names} == {3 << 28}
+        took = sum(kept[n] for n in names)
+        assert took <= attention._KEEP_BYTES < took + (3 << 28)
+    else:
+        assert names == want
+    if names is None:
+        # nothing computed again: ten hidden-wide, three FFN-wide values
+        assert sum(kept.values()) == tokens * itemsize * applications * (
+            10 * hidden + 3 * ffn)
+    # the stack asks the same rule with its own widths
+    stack = LoopedDecoderStack(applications, 1, hidden, ffn, passes=1,
+                               name=f"rule_{case[0]}")
+    x = jax.ShapeDtypeStruct((1, tokens, hidden),
+                             {2: jnp.bfloat16, 4: jnp.float32}[itemsize])
+    assert stack._kept(x) == kept
+
+
+def test_a_trace_tells_the_registry_what_it_keeps(monkeypatch):
+    """``stack_kept_bytes{name}``: once a trace, every name, the bytes the
+    rule reckoned; 0 for what is computed again."""
+    sets = []
+    real = METRICS.set
+
+    def noting(name, /, value, **labels):
+        sets.append((name, labels["name"]))
+        real(name, value, **labels)
+
+    monkeypatch.setattr(METRICS, "set", noting)
+    _, grad, params = _toy_stack_gradient(monkeypatch,
+                                          _TOY_BUDGETS["down+o"][0])
+    sets.clear()
+    jax.jit(grad).lower(params)
+    want = {"block_input": _TOY_VALUE, "down": _TOY_VALUE, "o": _TOY_VALUE,
+            "q": 0, "k": 0, "v": 0, "gate": 0, "up": 0, "rest": 0}
+    assert sorted(sets) == sorted(("stack_kept_bytes", n) for n in want)
+    gauges = METRICS.delta(None)["gauges"]
+    assert {n: gauges[f'stack_kept_bytes{{name="{n}"}}']
+            for n in want} == want
+    # everything kept: each projection's result at its size, and the rest
+    _, grad, params = _toy_stack_gradient(monkeypatch, 1 << 40)
+    gauges = METRICS.delta(None)["gauges"]
+    assert gauges['stack_kept_bytes{name="gate"}'] == _TOY_VALUE * 96 // 64
+    assert gauges['stack_kept_bytes{name="q"}'] == _TOY_VALUE
+    assert gauges['stack_kept_bytes{name="rest"}'] == _TOY_VALUE * (
+        4 * 64 + 96) // 64
 
 
 # ------------------------------------------------------ the loss's two parts
