@@ -95,7 +95,7 @@ def main(argv=None) -> int:
         red = out["run"]["trace"]
         extra["trace"] = {k: red.get(k) for k in
                           ("busy_s_each", "span_s", "steps", "step_span_s",
-                           "step_program")}
+                           "step_program", "scope_seconds")}
         extra["trace"]["step_gap_samples"] = len(red.get("step_gaps_ms", []))
     result.print_checks(out["checks"], out["notes"])
     result.print_result(correct=out["correct"], attempted=out["attempted"],

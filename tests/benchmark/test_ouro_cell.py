@@ -88,32 +88,56 @@ def _measure(harness, tree, monkeypatch, seed, trace):
                                    time.perf_counter())
 
 
-def test_benchmark_json_differs_by_appended_entries():
+def _bench():
     with open(os.path.join(tiny.REPO, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    assert [c["name"] for c in bench["configs"]][-1] == "ouro-2.6b"
-    cell = bench["workloads"][-1]
-    assert (cell["name"], cell["chips"], cell["traffic"]) == (
-        "ouro-2.6b-fit-packed4k", 1, "fit-host-packed4k")
-    assert all(w["chips"] == 1 for w in bench["workloads"])
-    # nine per-layer metrics read in every cell; the kernels' own lists
-    # the one cell that reaches them
-    assert len(bench["per_layer"]) == 10
-    assert {m["name"]: m["workloads"] for m in bench["per_layer"]
-            if "workloads" in m} == {
-        "flash_attn_roofline_pct.fit": ["ouro-2.6b-fit-packed4k"]}
+        return json.load(f)
 
 
-@pytest.mark.parametrize("name", ["resnet50-fit-host", "bert-base-fit-host",
-                                  "ouro-2.6b-fit-packed4k"])
+def _entry(entries, name):
+    found = [e for e in entries if e["name"] == name]
+    assert len(found) == 1, (name, [e["name"] for e in entries])
+    return found[0]
+
+
+def test_benchmark_json_differs_by_appended_entries():
+    """What PR 29 wrote is there under its names, wherever later entries
+    put it in the lists: the configuration, the cell and the kernels'
+    metric, which lists that cell alone."""
+    bench = _bench()
+    config = _entry(bench["configs"], "ouro-2.6b")
+    assert config["file"] == "benchmark/configs/ouro-2.6b.json"
+    assert config["reduced"] == ["num_hidden_layers", "layer_types"]
+    assert config["source"] == ("https://huggingface.co/ByteDance/Ouro-2.6B/"
+                                "blob/main/config.json")
+    cell = _entry(bench["workloads"], "ouro-2.6b-fit-packed4k")
+    assert (cell["config"], cell["chips"], cell["traffic"]) == (
+        "ouro-2.6b", 1, "fit-host-packed4k")
+    flash = _entry(bench["per_layer"], "flash_attn_roofline_pct.fit")
+    assert flash == dict(
+        name="flash_attn_roofline_pct.fit", unit="%", better="higher",
+        source="device_trace", layer="kernels", moves="fit_samples_per_s",
+        workloads=["ouro-2.6b-fit-packed4k"])
+
+
+@pytest.mark.parametrize("name", ["stack_ms.fit", "head_loss_ms.fit"])
+def test_scope_metrics_list_the_cell_whose_step_has_the_scopes(name):
+    """The looped decoder's two scopes as milliseconds a step: a share has
+    no better direction."""
+    assert _entry(_bench()["per_layer"], name) == dict(
+        name=name, unit="ms", better="lower", source="device_trace",
+        layer="model step", moves="fit_samples_per_s",
+        workloads=["ouro-2.6b-fit-packed4k"])
+
+
+@pytest.mark.parametrize("name", [w["name"] for w in _bench()["workloads"]])
 def test_each_cell_finds_its_files_by_name(name):
     """What ``test_work_and_peaks``'s test of the same purpose checks, and
-    that the driver's file is there, the metrics are in the file's order,
-    and only the cell that reaches the flash kernels reads their metric."""
+    that the driver's file is there, and that a cell reads exactly the
+    metrics without a list of cells plus those that list it, in the file's
+    order, whatever their number."""
     from harness import spec
 
-    with open(os.path.join(tiny.REPO, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+    bench = _bench()
     c = spec.load_cell(tiny.REPO, name)
     for fn in ("build", "make_data", "work", "to_program", "from_program"):
         assert callable(getattr(c.config_mod, fn))
@@ -125,13 +149,23 @@ def test_each_cell_finds_its_files_by_name(name):
     assert os.path.isfile(os.path.join(
         c.bench_dir, "harness", "drivers", c.traffic["driver"] + ".py"))
     assert callable(c.driver().run)
-    assert {m["name"] for m in c.end_to_end} == {"fit_samples_per_s",
-                                                 "setup_s"}
+    reported = {m["name"] for m in c.end_to_end}
+    assert "setup_s" in reported and len(reported) >= 2
+    everywhere = [m["name"] for m in bench["per_layer"]
+                  if "workloads" not in m and m["moves"] in reported]
+    listed = [m["name"] for m in bench["per_layer"]
+              if name in m.get("workloads", [])]
     mine = [m["name"] for m in bench["per_layer"]
-            if name in m.get("workloads", [name])]
-    assert [m["name"] for m in c.per_layer] == mine
-    assert len(mine) == (10 if name == "ouro-2.6b-fit-packed4k" else 9)
-    assert ("flash_attn_roofline_pct.fit" in mine) == (len(mine) == 10)
+            if m["name"] in everywhere + listed]
+    assert [m["name"] for m in c.per_layer] == mine and everywhere
+    assert ("flash_attn_roofline_pct.fit" in mine) == (
+        name == "ouro-2.6b-fit-packed4k")
+    for m in bench["per_layer"]:
+        # a metric lists cells that are there, each of which reports the
+        # end-to-end metric it moves
+        for w in m.get("workloads", []):
+            other = spec.load_cell(tiny.REPO, w)
+            assert m["moves"] in {e["name"] for e in other.end_to_end}
     for m in c.per_layer:
         assert callable(c.layer_metric_reader(m["name"]))
     for m in c.end_to_end:
@@ -197,7 +231,10 @@ def test_cell_traced_reads_what_a_cpu_trace_holds(ouro_harness, ouro_tree,
     cell, out = _measure(ouro_harness, ouro_tree, monkeypatch, 7, trace=True)
     assert out["correct"], out["checks"]
     names = {m["name"] for m in cell.per_layer}
-    assert len(names) == 9 and set(out["metrics"]) <= names
+    # a cell that no metric lists reads the metrics that list no cell
+    assert names == {m["name"] for m in _bench()["per_layer"]
+                     if "workloads" not in m}
+    assert set(out["metrics"]) <= names
     assert "dispatch_ms.fit" in out["metrics"]
     assert not os.path.exists(os.path.join(ouro_tree, ".bench_trace", CELL))
 

@@ -8,6 +8,10 @@ gradient and the parameters' change after the three."""
 import filecmp
 import json
 import os
+import re
+import shutil
+import subprocess
+import sys
 import time
 import types
 
@@ -49,6 +53,108 @@ def test_adding_cells_edits_no_file_that_is_there(tree):
     for key in ("command", "paths", "run_seconds", "end_to_end",
                 "per_layer"):
         assert after[key] == before[key]
+
+
+SCOPE_READER = '''"""A later configuration's scope metric, whole: the marker's milliseconds."""
+from harness.trace_reduce import scope_ms
+
+
+def read(run):
+    return scope_ms(run, "zoo:tiny/block")
+'''
+
+# the tests that hold BENCHMARK.json and every cell's files to the contract
+CONTRACT_TESTS = [
+    "test_work_and_peaks.py",
+    "test_ouro_cell.py::test_benchmark_json_differs_by_appended_entries",
+    "test_ouro_cell.py::test_scope_metrics_list_the_cell_whose_step_has_the_scopes",
+    "test_ouro_cell.py::test_each_cell_finds_its_files_by_name",
+    "test_ouro_cell.py::test_configuration_keeps_the_published_keys",
+    "test_ouro_cell.py::test_flash_reader_reads_nothing_where_nothing_is_to_be_read",
+]
+
+
+def _files(top):
+    for d, dirs, files in os.walk(top):
+        dirs[:] = [x for x in dirs if x != "__pycache__"]
+        for f in files:
+            yield os.path.relpath(os.path.join(d, f), top)
+
+
+def test_an_addition_whole_leaves_the_contract_tests_green(tmp_path):
+    """What a later PR may bring, all at once: two configurations, two
+    one-chip cells, a four-chip cell and a per-layer metric that lists its
+    cells, appended to a copy of the tree with these tests in it.  No file
+    that was there differs, every accepted entry is still there in its
+    order, and the contract tests of ``test_work_and_peaks`` and
+    ``test_ouro_cell``, run as they are against that tree, all pass."""
+    root = tiny.make_tree(str(tmp_path / "checkout"))
+    with open(os.path.join(root, "benchmark", "layer_metrics",
+                           "tiny_scope_ms.fit.py"), "w") as f:
+        f.write(SCOPE_READER)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["per_layer"].append(dict(
+        name="tiny_scope_ms.fit", unit="ms", better="lower",
+        source="device_trace", layer="model step",
+        moves="fit_samples_per_s",
+        workloads=["bert-tiny-fit", "bert-tiny-dp4"]))
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f, indent=1)
+    shutil.copytree(os.path.join(tiny.REPO, "tests", "benchmark"),
+                    os.path.join(root, "tests", "benchmark"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+
+    for top in ("benchmark", os.path.join("tests", "benchmark")):
+        for rel in _files(os.path.join(tiny.REPO, top)):
+            assert filecmp.cmp(os.path.join(tiny.REPO, top, rel),
+                               os.path.join(root, top, rel),
+                               shallow=False), rel
+    with open(os.path.join(tiny.REPO, "BENCHMARK.json")) as f:
+        before = json.load(f)
+    for key in ("configs", "workloads", "end_to_end", "per_layer"):
+        assert bench[key][:len(before[key])] == before[key]
+    for key in ("command", "paths", "run_seconds"):
+        assert bench[key] == before[key]
+    added = [w for w in bench["workloads"] if w not in before["workloads"]]
+    assert sorted(w["chips"] for w in added) == [1, 1, 4]
+    assert len(bench["configs"]) == len(before["configs"]) + 2
+    assert len(bench["per_layer"]) == len(before["per_layer"]) + 1
+
+    # the listed cells read the new metric, by its file; no other cell does
+    _, spec = tiny.harness_of(root)
+    try:
+        for w in bench["workloads"]:
+            names = {m["name"] for m in
+                     spec.load_cell(root, w["name"]).per_layer}
+            assert ("tiny_scope_ms.fit" in names) == (
+                w["name"] in ("bert-tiny-fit", "bert-tiny-dp4"))
+        read = spec.load_cell(root, "bert-tiny-dp4").layer_metric_reader(
+            "tiny_scope_ms.fit")
+        red = {"steps": 4, "scope_seconds_under": {"zoo:tiny/block": 0.5}}
+        assert read({"trace": red}) == 125.0
+        assert read({"trace": dict(red, scope_seconds_under={})}) is None
+        assert read({"trace": None}) is None
+    finally:
+        tiny.harness_of(tiny.REPO)
+
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("PYTEST_")}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
+        [tiny.REPO] + [p for p in [env.get("PYTHONPATH")] if p]))
+    there = os.path.join(root, "tests", "benchmark")
+    done = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-p", "no:randomly", "--rootdir", root]
+        + [os.path.join(there, t) for t in CONTRACT_TESTS],
+        cwd=root, env=env, capture_output=True, text=True, timeout=600)
+    tail = done.stdout[-3000:] + done.stderr[-1000:]
+    assert done.returncode == 0, tail
+    # the added cells are parameters of the same tests: each cell is a case
+    # of the two that find a cell's files by name
+    passed = re.search(r"(\d+) passed", tail)
+    assert passed and "failed" not in tail and "error" not in tail, tail
+    assert int(passed.group(1)) >= 2 * len(bench["workloads"]) + 10, tail
 
 
 def test_bert_cell_runs_and_meets_its_reference(harness, tree, monkeypatch):

@@ -2,6 +2,8 @@
 through the reader's own JSON form (overlapping operations, an idle gap at
 either end), and a few steps cut from a trace recorded on the chip."""
 
+import hashlib
+import json
 import os
 
 import numpy as np
@@ -16,9 +18,13 @@ FIXTURES = os.path.join(tiny.REPO, "benchmark", "fixtures")
 
 
 def _line(name, events):
+    """Events as (start, duration, name) or (start, duration, name, JAX name
+    stack)."""
+    op_names = [e[3] if len(e) > 3 else "" for e in events]
     return tr.Line(name, np.asarray([e[0] for e in events], np.int64),
                    np.asarray([e[1] for e in events], np.int64),
-                   [e[2] for e in events])
+                   [e[2] for e in events],
+                   op_names if any(op_names) else [])
 
 
 @pytest.fixture()
@@ -188,3 +194,276 @@ def test_recorded_chip_trace(name):
         ops.ends.max() - ops.starts.min())
     assert len(red["device_ops"]) <= 10 and red["device_ops"][0][1] > 0
     assert len(red["step_gaps_ms"]) == red["steps"] - 1
+
+
+# ------------------------------------------------- device time by scope ---
+
+STACK, HEADS, ATTN = "zoo:lm/stack", "zoo:lm/head_loss", "zoo:nn/attn"
+
+
+def _scoped_trace():
+    """One step of 1,600 ns and the start of the next.  A ``while`` of the
+    stack's forward over [0,1000) holds two body events: a product of the
+    stack's backward, [100,300), and a softmax under a scope nested in the
+    stack's, [400,700).  After it a copy the profiler gives no name stack,
+    [1000,1100); idle to 1200; a product of the heads' backward,
+    [1200,1500); an addition outside every scope, [1500,1600)."""
+    return tr.Trace([tr.Plane("/device:TPU:0", [
+        _line("XLA Ops", [
+            (0, 1000, "while.1", f"jit(step)/jvp({STACK})/while"),
+            (100, 200, "fusion.a",
+             f"jit(step)/transpose(jvp({STACK}))/while/body/dot_general:"),
+            (400, 300, "fusion.b",
+             f"jit(step)/jvp({STACK})/while/body/{ATTN}/softmax/exp:"),
+            (1000, 100, "copy.9"),
+            (1200, 300, "fusion.c",
+             f"jit(step)/transpose(jvp({HEADS}))/mul:"),
+            (1500, 100, "fusion.d", "jit(step)/add:"),
+        ]),
+        _line("XLA Modules", [(0, 1600, "jit_step(1)"),
+                              (1600, 1, "jit_step(1)")]),
+    ])])
+
+
+def test_self_time_leaves_a_while_what_its_body_does_not_take():
+    ops = tr.ops_line(_scoped_trace().planes[0])
+    assert tr.self_ns(ops).tolist() == [500, 200, 300, 100, 300, 100]
+    assert int(tr.self_ns(ops).sum()) == tr.busy_ns(ops) == 1500
+
+
+def test_self_time_adds_up_to_busy_time_where_operations_overlap(synthetic):
+    """A [100,200) and B [150,300) overlap without one holding the other:
+    each busy moment goes to the one that started last."""
+    ops = synthetic.device_planes()[0].line("XLA Ops")
+    assert tr.self_ns(ops).tolist() == [50, 150, 50, 50]
+    assert int(tr.self_ns(ops).sum()) == tr.busy_ns(ops)
+
+
+def test_scope_seconds_by_innermost_marker_add_up_to_busy_time():
+    red = tr.reduce(_scoped_trace())
+    own = red["scope_seconds"]
+    # the while's own 500 ns and the backward product's 200 under the
+    # stack's marker, forward and backward one sum; the softmax under the
+    # marker nested in it; the copy without a name stack and the addition
+    # without a marker under ""
+    assert own == {STACK: pytest.approx(700e-9), ATTN: pytest.approx(300e-9),
+                   HEADS: pytest.approx(300e-9), "": pytest.approx(200e-9)}
+    assert sum(own.values()) == pytest.approx(red["busy_s"], rel=1e-12)
+    assert red["busy_s"] == pytest.approx(1500e-9)
+    # under every marker of the name: the stack holds the scope nested in it
+    assert red["scope_seconds_under"] == {
+        STACK: pytest.approx(1000e-9), ATTN: pytest.approx(300e-9),
+        HEADS: pytest.approx(300e-9)}
+
+
+def test_scope_ms_is_a_step_s_milliseconds_or_nothing():
+    red = tr.reduce(_scoped_trace())
+    assert red["steps"] == 2
+    run = {"trace": red}
+    assert tr.scope_ms(run, STACK) == pytest.approx(1000e-6 / 2)
+    assert tr.scope_ms(run, ATTN) == pytest.approx(300e-6 / 2)
+    assert tr.scope_ms(run, HEADS) == pytest.approx(300e-6 / 2)
+    assert tr.scope_ms(run, "zoo:lm/embed") is None
+    assert tr.scope_ms(run, "") is None
+    assert tr.scope_ms({"trace": None}, STACK) is None
+    assert tr.scope_ms({"trace": dict(red, steps=0)}, STACK) is None
+
+
+@pytest.mark.parametrize("op_name,markers", [
+    ("jit(step)/jvp(zoo:lm/stack)/while/body/dot_general:", [STACK]),
+    ("jit(step)/transpose(jvp(zoo:lm/stack))/while", [STACK]),
+    ("jit(step)/zoo:lm/stack/while/body/closed_call", [STACK]),
+    ("jit(step)/zoo:lm/head_loss", [HEADS]),
+    ("jit(step)/jvp(zoo:lm/stack)/zoo:nn/attn/exp:", [STACK, ATTN]),
+    ("jit(step)/zoo:lm2/head_loss_3/x", ["zoo:lm2/head_loss_3"]),
+    ("jit(step)/jit(_where)/select_n:", []),
+    ("jit(step)/zoo:lm", []),
+    ("", []),
+])
+def test_a_marker_is_two_components_and_ends_where_they_end(op_name,
+                                                            markers):
+    assert tr.MARKER.findall(op_name) == markers
+
+
+def test_json_round_trip_and_cut_keep_the_name_stacks(tmp_path):
+    trace = _scoped_trace()
+    path = str(tmp_path / "scoped.json.gz")
+    tr.dump_json(trace, path)
+    back = tr.load_json(path)
+    ops, want = tr.ops_line(back.planes[0]), tr.ops_line(trace.planes[0])
+    assert ops.op_names == want.op_names and ops.names == want.names
+    assert back.planes[0].line("XLA Modules").op_names == []
+    assert tr.reduce(back) == tr.reduce(trace)
+    part = tr.ops_line(tr.cut(back, 400, 1500).planes[0])
+    assert part.names == ["fusion.b", "copy.9", "fusion.c"]
+    assert part.op_names == want.op_names[2:5]
+
+
+def test_a_trace_from_before_the_name_stacks_loads_with_none(synthetic):
+    """The synthetic trace is written without ``op_names``, as every fixture
+    of before was: it loads with the field empty, counts under no scope and
+    reduces to everything it reduced to."""
+    ops = synthetic.device_planes()[0].line("XLA Ops")
+    assert ops.op_names == []
+    red = tr.reduce(synthetic, window_s=600e-9)
+    assert red["scope_seconds"] == {} == red["scope_seconds_under"]
+    assert tr.scope_ms({"trace": red}, STACK) is None
+    assert red["busy_s"] == pytest.approx(300e-9) and red["steps"] == 3
+
+
+# What the two fixtures recorded before the name stacks reduced to, by the
+# reduction as it was then (commit 9aa3987): busy seconds, steps, operations
+# by name, and the SHA-256 of the whole reduction as ``json.dumps(...,
+# sort_keys=True)``.
+REDUCED_BEFORE = {
+    "resnet50-fit-host.4steps.json.gz": (
+        0.419286019, 4, 3766,
+        "2f7d814ef3ed7a45f10d0a23b69b1ed2993e90b97c4b07bde72383c32b2c7a30"),
+    "resnet50-fit-host.spans.json.gz": (
+        0.524279836, 5, 3766,
+        "dfed0faf3f544fdaec7a799b0c2aa0985444805102afe24281675b2d19406298"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(REDUCED_BEFORE))
+def test_fixtures_without_name_stacks_reduce_to_what_they_reduced_to(name):
+    """Every key that the reduction had, equal to the last digit; the two
+    new ones empty."""
+    trace = tr.load_json(os.path.join(FIXTURES, name))
+    assert all(l.op_names == [] for p in trace.planes for l in p.lines)
+    red = tr.reduce(trace)
+    assert red.pop("scope_seconds") == {} == red.pop("scope_seconds_under")
+    busy_s, steps, n_ops, digest = REDUCED_BEFORE[name]
+    assert (red["busy_s"], red["steps"], len(red["op_seconds"])) == (
+        busy_s, steps, n_ops)
+    assert hashlib.sha256(json.dumps(red, sort_keys=True).encode()
+                          ).hexdigest() == digest
+
+
+def test_recorded_chip_trace_of_the_looped_decoder_by_scope():
+    """``ouro-2.6b-fit-packed4k.3steps.json.gz``: three whole steps of PR
+    35's traced run of the cell on a TPU v5e (seed 3500201), with the name
+    stacks.  The two scope metrics read from these three steps what that
+    run printed over its 35: ``stack_ms.fit`` 733.9219898285714 and
+    ``head_loss_ms.fit`` 245.53455899999997; with the embedding's scope and
+    what is under none they add up to the busy time a step."""
+    _, spec = tiny.harness_of(tiny.REPO)
+    trace = tr.load_json(os.path.join(
+        FIXTURES, "ouro-2.6b-fit-packed4k.3steps.json.gz"))
+    ops = tr.ops_line(trace.device_planes()[0])
+    assert len(ops.op_names) == len(ops.starts) > 30_000
+    assert int(tr.self_ns(ops).sum()) == tr.busy_ns(ops)
+    red = tr.reduce(trace)
+    assert red["steps"] == 3
+    cell = spec.load_cell(tiny.REPO, "ouro-2.6b-fit-packed4k")
+    run = {"trace": red}
+    stack = cell.layer_metric_reader("stack_ms.fit")(run)
+    heads = cell.layer_metric_reader("head_loss_ms.fit")(run)
+    assert stack == pytest.approx(733.9219898285714, rel=1e-4)
+    assert heads == pytest.approx(245.53455899999997, rel=1e-4)
+    own = red["scope_seconds"]
+    assert set(own) == {"", "zoo:lm/embed", "zoo:lm/stack",
+                        "zoo:lm/head_loss"}
+    # no marker is nested in another in this program
+    assert red["scope_seconds_under"] == {m: s for m, s in own.items() if m}
+    assert sum(own.values()) == pytest.approx(red["busy_s"], rel=1e-12)
+    rest = 1e3 * (own[""] + own["zoo:lm/embed"]) / 3
+    assert stack + heads + rest == pytest.approx(
+        1e3 * red["busy_s"] / 3, rel=1e-9)
+    assert 25 < rest < 35
+    # the backward pass is in the sums: most of the stack's time is under
+    # ``transpose(jvp(zoo:lm/stack))``
+    back = sum(ns for ns, n in zip(tr.self_ns(ops).tolist(), ops.op_names)
+               if "transpose(jvp(zoo:lm/stack))" in n)
+    assert 0.6 < back / 1e9 / own["zoo:lm/stack"] < 0.9
+    # and the other readers of the cell's line read the same trace
+    assert cell.layer_metric_reader("step_p95_ms.fit")(run) == \
+        pytest.approx(1009.0, rel=1e-3)
+
+
+# ---------------------------------------- the profiler's file, by hand ----
+
+def _varint(n):
+    out = bytearray()
+    while True:
+        out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+        n >>= 7
+        if not n:
+            return bytes(out)
+
+
+def _msg(*fields):
+    """A message's bytes from (field number, value): an int is a varint, a
+    float a fixed 64-bit double, bytes or text a length-delimited field."""
+    out = bytearray()
+    for number, value in fields:
+        if isinstance(value, int):
+            out += _varint(number << 3) + _varint(value)
+        elif isinstance(value, float):
+            out += _varint(number << 3 | 1) + np.float64(value).tobytes()
+        else:
+            value = value.encode() if isinstance(value, str) else value
+            out += _varint(number << 3 | 2) + _varint(len(value)) + value
+    return bytes(out)
+
+
+def _xspace():
+    """``/device:TPU:0`` with three operations in its event-metadata: one
+    whose ``tf_op`` is a string, one whose ``tf_op`` refers to a
+    stat-metadata entry's name, one with other stats only; an ``XLA Ops``
+    line of four events at 5 us, and a host plane."""
+    stats = {1: "tf_op", 2: "flops", 3: f"jit(step)/jvp({HEADS})/mul:",
+             4: "Time Scale Multiplier"}
+    events = {
+        7: ("%while.1 = (s32[]) while(%t), body=%b", "while.1",
+            [_msg((1, 2), (4, 12345)),
+             _msg((1, 1), (5, f"jit(step)/jvp({STACK})/while"))]),
+        8: ("%fusion.c = f32[8] fusion(%p), kind=kLoop", "fusion.c",
+            [_msg((1, 1), (7, 3))]),
+        9: ("%copy.9 = f32[8] copy(%p)", "copy.9",
+            [_msg((1, 2), (3, 64)), _msg((1, 4), (2, 1.0))]),
+    }
+    ops = _msg((1, 1), (2, "XLA Ops"), (3, 5000),
+               (4, _msg((1, 7), (2, 0), (3, 1000_000))),
+               (4, _msg((1, 8), (2, 100_000), (3, 300_000))),
+               (4, _msg((1, 9), (2, 1000_000), (3, 100_000))),
+               (4, _msg((1, 8), (2, 1200_000), (3, 300_000))))
+    device = _msg(
+        (1, 1), (2, "/device:TPU:0"), (3, ops),
+        *[(4, _msg((1, k), (2, _msg((1, k), (2, name), (4, shown),
+                                    *[(5, s) for s in st]))))
+          for k, (name, shown, st) in events.items()],
+        *[(5, _msg((1, k), (2, _msg((1, k), (2, name)))))
+          for k, name in stats.items()])
+    host = _msg((1, 2), (2, "/host:CPU"),
+                (4, _msg((1, 1), (2, _msg((1, 1), (2, "gather"))))))
+    return _msg((1, device), (1, host), (4, "host-0"))
+
+
+def test_name_stacks_are_read_from_the_files_event_metadata():
+    assert tr.op_names_by_event_name(_xspace()) == {"/device:TPU:0": {
+        "%while.1 = (s32[]) while(%t), body=%b":
+            f"jit(step)/jvp({STACK})/while",
+        "%fusion.c = f32[8] fusion(%p), kind=kLoop":
+            f"jit(step)/jvp({HEADS})/mul:"}}
+    assert tr.op_names_by_event_name(b"") == {}
+
+
+def test_load_xplane_gives_each_device_operation_its_name_stack(tmp_path):
+    """The same bytes through ``load_xplane``: JAX's reader gives the
+    events, the file's event-metadata their name stacks."""
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(_xspace())
+    trace = tr.load_xplane(str(path))
+    ops = tr.ops_line(trace.device_planes()[0])
+    assert ops.names == ["while.1", "fusion.c", "copy.9", "fusion.c"]
+    assert ops.starts.tolist() == [5000, 5100, 6000, 6200]
+    assert ops.durs.tolist() == [1000, 300, 100, 300]
+    assert ops.op_names == [f"jit(step)/jvp({STACK})/while",
+                            f"jit(step)/jvp({HEADS})/mul:", "",
+                            f"jit(step)/jvp({HEADS})/mul:"]
+    own, under = tr.scope_seconds(ops)
+    assert own == {STACK: pytest.approx(700e-9), "": pytest.approx(100e-9),
+                   HEADS: pytest.approx(600e-9)}
+    assert under == {STACK: pytest.approx(700e-9),
+                     HEADS: pytest.approx(600e-9)}
