@@ -15,6 +15,7 @@ from analytics_zoo_tpu.models.text import (  # noqa: F401
     ndcg,
 )
 from analytics_zoo_tpu.models.looped_lm import LoopedLM  # noqa: F401
+from analytics_zoo_tpu.models.hybrid_lm import HybridLM  # noqa: F401
 from analytics_zoo_tpu.models.seq2seq import (  # noqa: F401
     Bridge,
     RNNDecoder,

@@ -121,7 +121,8 @@ class LoopedLM(ZooModel):
         heads = config.get("num_attention_heads")
         if config.get("num_key_value_heads", heads) != heads:
             raise ValueError(
-                "grouped-query attention is not supported: "
+                "grouped-query attention is not supported here "
+                "(models.HybridLM takes grouped heads): "
                 f"num_key_value_heads {config['num_key_value_heads']} != "
                 f"num_attention_heads {heads}")
         if config.get("head_dim", config["hidden_size"] // heads) \

@@ -1,6 +1,7 @@
-"""Attention layers: MultiHeadAttention, TransformerLayer, BERT, and the
+"""Attention layers: MultiHeadAttention, TransformerLayer, BERT, the
 pieces of a looped decoder (RotaryEmbedding, GatedFFN, SandwichDecoderBlock,
-LoopedDecoderStack).
+LoopedDecoderStack) and of a decoder of several kinds of layer
+(PreNormDecoderBlock, HybridDecoderStack).
 
 Reference capability: api/keras/layers/TransformerLayer.scala:56 (GPT-style
 decoder stack: token+position embedding, n blocks of attention+FFN with
@@ -16,6 +17,8 @@ through the same op interface (parallel/sequence.py).
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import math
 from typing import Dict, Optional, Sequence, Tuple
 
@@ -110,17 +113,28 @@ class MultiHeadAttention(StatelessLayer):
     Single input → self-attention; two inputs (q, kv) → cross-attention.
     An optional third input is the attention mask (1 = attend),
     broadcastable to (B, 1, Lq, Lk).  ``rotary_theta`` turns q and k by
-    their positions (``RotaryEmbedding``) before the scores are taken;
-    ``use_bias=False`` leaves the four projections without biases.
+    their positions (``RotaryEmbedding``) before the scores are taken, and
+    without it the layer knows no positions; ``use_bias=False`` leaves the
+    four projections without biases.  ``n_kv_head`` gives keys and values
+    fewer heads than the queries (grouped-query attention: each is shared
+    by ``nhead / n_kv_head`` consecutive query heads); ``sm_scale`` takes
+    the place of ``1 / sqrt(head size)`` on the scores.
     """
 
     def __init__(self, nhead: int, hidden_size: Optional[int] = None,
                  attn_drop: float = 0.0, output_drop: float = 0.0,
                  causal: bool = False, init="glorot_uniform",
                  seq_shards: Optional[int] = None, use_bias: bool = True,
-                 rotary_theta: Optional[float] = None, **kw):
+                 rotary_theta: Optional[float] = None,
+                 n_kv_head: Optional[int] = None,
+                 sm_scale: Optional[float] = None, **kw):
         super().__init__(**kw)
         self.use_bias = use_bias
+        self.n_kv_head = nhead if n_kv_head is None else n_kv_head
+        if nhead % self.n_kv_head:
+            raise ValueError(f"{nhead} query heads do not divide over "
+                             f"{self.n_kv_head} key/value heads")
+        self.sm_scale = sm_scale
         self.rotary = (None if rotary_theta is None else RotaryEmbedding(
             rotary_theta, name=f"{self.name}_rotary"))
         self.nhead = nhead
@@ -139,16 +153,30 @@ class MultiHeadAttention(StatelessLayer):
         if d % self.nhead:
             raise ValueError(f"hidden {d} not divisible by nhead {self.nhead}")
         kv_d = rest[0][-1] if rest else q_shape[-1]
+        kv_out = d // self.nhead * self.n_kv_head
         ks = jax.random.split(rng, 4)
-        dims = {"q": q_shape[-1], "k": kv_d, "v": kv_d, "o": d}
-        return {n: _dense_params(k, d_in, d, self.initializer,
+        dims = {"q": (q_shape[-1], d), "k": (kv_d, kv_out),
+                "v": (kv_d, kv_out), "o": (d, d)}
+        return {n: _dense_params(k, d_in, d_out, self.initializer,
                                  use_bias=self.use_bias)
-                for k, (n, d_in) in zip(ks, dims.items())}
+                for k, (n, (d_in, d_out)) in zip(ks, dims.items())}
 
-    def _split_heads(self, x):
+    def projections(self):
+        """(name, fan-in, fan-out) of the results worth keeping for the
+        backward pass (``_keep_within_budget``)."""
+        d = self.hidden_size
+        kv = d // self.nhead * self.n_kv_head
+        return (("o", d, d), ("q", d, d), ("k", d, kv), ("v", d, kv))
+
+    def values_a_token(self) -> int:
+        """About what a token keeps where nothing is computed again: q,
+        the heads' result and the output, k and v."""
+        d = self.hidden_size
+        return 3 * d + 2 * (d // self.nhead * self.n_kv_head)
+
+    def _split_heads(self, x, nhead):
         b, l, d = x.shape
-        return x.reshape(b, l, self.nhead, d // self.nhead).transpose(
-            0, 2, 1, 3)
+        return x.reshape(b, l, nhead, d // nhead).transpose(0, 2, 1, 3)
 
     def forward(self, params, *inputs, training=False, rng=None):
         # Input forms: (x) self-attn; (q, kv) cross-attn with kv 3D;
@@ -165,12 +193,19 @@ class MultiHeadAttention(StatelessLayer):
                 mask = inputs[1]
         else:
             q_in, kv_in, mask = inputs
-        q = self._split_heads(_named_dense(params, "q", q_in))
-        k = self._split_heads(_named_dense(params, "k", kv_in))
-        v = self._split_heads(_named_dense(params, "v", kv_in))
+        q = self._split_heads(_named_dense(params, "q", q_in), self.nhead)
+        k = self._split_heads(_named_dense(params, "k", kv_in),
+                              self.n_kv_head)
+        v = self._split_heads(_named_dense(params, "v", kv_in),
+                              self.n_kv_head)
         if self.rotary is not None:
             q = self.rotary.forward({}, q)
             k = self.rotary.forward({}, k)
+        if self.n_kv_head != self.nhead:
+            # query heads g*j .. g*j + g - 1 read key/value head j; the
+            # repeat's transpose sums their gradients
+            k, v = (jnp.repeat(t, self.nhead // self.n_kv_head, axis=1)
+                    for t in (k, v))
         if mask is not None:
             if mask.ndim == 2:      # (B, Lk) key padding mask
                 mask = mask[:, None, None, :]
@@ -193,6 +228,9 @@ class MultiHeadAttention(StatelessLayer):
                 raise ValueError(
                     "sequence-parallel attention supports self-attention "
                     "only (q and kv shards must rotate together)")
+            if self.sm_scale is not None:
+                raise ValueError("sequence-parallel attention takes no "
+                                 "sm_scale")
             from analytics_zoo_tpu.parallel.sequence import (
                 ring_self_attention)
             out = ring_self_attention(q, k, v, sp.mesh, sp.axis,
@@ -205,7 +243,8 @@ class MultiHeadAttention(StatelessLayer):
             # fused kernels
             drop = self.attn_drop if (training and r1 is not None) else 0.0
             ring_mesh = None
-            if mask is None and kv_in is q_in and drop == 0.0:
+            if (mask is None and kv_in is q_in and drop == 0.0
+                    and self.sm_scale is None):
                 # seq_shards knob: long-context self-attention shards L
                 # over a ring of devices even without an explicit sp
                 # regime (serving's long-document bucket rides this).
@@ -225,6 +264,7 @@ class MultiHeadAttention(StatelessLayer):
             else:
                 out = dot_product_attention(q, k, v, mask=mask,
                                             causal=self.causal,
+                                            sm_scale=self.sm_scale,
                                             dropout_rate=drop,
                                             dropout_rng=r1)
         b, h, l, hd = out.shape
@@ -438,47 +478,69 @@ _PROJECTIONS = (("down", "ffn", "hidden"), ("o", "hidden", "hidden"),
                 ("up", "hidden", "ffn"))
 
 
+def _keep_within_budget(block_input: int, everything: int,
+                        candidates: Sequence[Tuple[str, int, int]]
+                        ) -> Dict[str, int]:
+    """The rule by which a stack keeps values for the backward pass, over
+    ``candidates`` (name, fan-in, bytes over all the stack's applications)
+    in their table's order.  ``everything`` (what the stack keeps where
+    nothing is computed again) fits ``_KEEP_BYTES``: all is kept, ``rest``
+    among it.  Otherwise the candidates are taken by multiply-adds spared a
+    kept byte (a kept byte spares fan-in / itemsize of them) while their sum
+    stays within ``_KEEP_BYTES``; one that does not fit is passed over and a
+    smaller one after it may still be taken.  None taken: each block is
+    computed again whole."""
+    kept = {"block_input": block_input,
+            **{name: 0 for name, _, _ in candidates}, "rest": 0}
+    if everything <= _KEEP_BYTES:
+        kept.update((name, size) for name, _, size in candidates)
+        kept["rest"] = everything - sum(kept.values())
+        return kept
+    room = _KEEP_BYTES
+    # sorted() is stable: equals stay in the table's order
+    for name, _, size in sorted(candidates, key=lambda c: -c[1]):
+        if size <= room:
+            kept[name] = size
+            room -= size
+    return kept
+
+
 def _kept_for_backward(tokens: int, itemsize: int, hidden: int,
                        intermediate: int, applications: int
                        ) -> Dict[str, int]:
     """What ``applications`` sandwich blocks over ``tokens`` tokens keep for
     the backward pass, in bytes by name: ``block_input`` always; each
     projection's result (0: computed again); ``rest``, what else a block
-    keeps when nothing is computed again (0 otherwise).
-
-    Everything fits ``_KEEP_BYTES`` (a block keeps about ten hidden-wide
-    and three FFN-wide values a token: the norms' and projections' inputs,
-    the gate's two factors): all is kept, ``rest`` among it.  Otherwise the
-    projections are taken by multiply-adds spared a kept byte while their
-    sum stays within ``_KEEP_BYTES``; one that does not fit is passed over
-    and a smaller one after it may still be taken.  None taken: each block
-    is computed again whole."""
+    keeps when nothing is computed again (0 otherwise), by
+    ``_keep_within_budget``.  A block keeps about ten hidden-wide and three
+    FFN-wide values a token (the norms' and projections' inputs, the gate's
+    two factors)."""
     width = {"hidden": hidden, "ffn": intermediate}
     a_value = tokens * itemsize * applications
-    size = {name: a_value * width[fan_out]
-            for name, _, fan_out in _PROJECTIONS}
-    kept = {"block_input": a_value * hidden, **dict.fromkeys(size, 0),
-            "rest": 0}
-    everything = a_value * (10 * hidden + 3 * intermediate)
-    if everything <= _KEEP_BYTES:
-        kept.update(size)
-        kept["rest"] = everything - sum(kept.values())
-        return kept
-    room = _KEEP_BYTES
-    # sorted() is stable: equals stay in the table's order
-    for name, _, _ in sorted(_PROJECTIONS, key=lambda p: -width[p[1]]):
-        if size[name] <= room:
-            kept[name] = size[name]
-            room -= size[name]
-    return kept
+    return _keep_within_budget(
+        a_value * hidden, a_value * (10 * hidden + 3 * intermediate),
+        [(name, width[fan_in], a_value * width[fan_out])
+         for name, fan_in, fan_out in _PROJECTIONS])
 
 
 def _kept_names(kept: Dict[str, int]) -> Optional[Sequence[str]]:
-    """Of ``_kept_for_backward``'s answer, what ``_run_block_stack`` is
-    told: the projections kept; ``None`` where nothing is computed again."""
+    """Of the rule's answer, what ``_run_block_stack`` is told: the
+    projections kept, in the table's order; ``None`` where nothing is
+    computed again."""
     if kept["rest"]:
         return None
-    return [name for name, _, _ in _PROJECTIONS if kept[name]]
+    return [name for name, size in kept.items()
+            if size and name not in ("block_input", "rest")]
+
+
+def _tell_kept(kept: Dict[str, int]) -> Dict[str, int]:
+    """``stack_kept_bytes{name}`` of what a stack keeps (at trace time: once
+    a compilation)."""
+    from analytics_zoo_tpu.observe.metrics import set_gauge
+
+    for name, size in kept.items():
+        set_gauge("stack_kept_bytes", size, name=name)
+    return kept
 
 
 class LoopedDecoderStack(StatelessLayer):
@@ -524,14 +586,9 @@ class LoopedDecoderStack(StatelessLayer):
     def _kept(self, x) -> Dict[str, int]:
         """The rule at this stack's widths and ``x``'s shape, told to the
         registry (at trace time: once a compilation)."""
-        from analytics_zoo_tpu.observe.metrics import set_gauge
-
-        kept = _kept_for_backward(
+        return _tell_kept(_kept_for_backward(
             x.size // x.shape[-1], x.dtype.itemsize, self.hidden_size,
-            self.intermediate, self.n_block * self.passes)
-        for name, size in kept.items():
-            set_gauge("stack_kept_bytes", size, name=name)
-        return kept
+            self.intermediate, self.n_block * self.passes))
 
     def forward(self, params, x, training=False, rng=None):
         remat = _kept_names(self._kept(x))
@@ -544,6 +601,118 @@ class LoopedDecoderStack(StatelessLayer):
 
         _, hs = jax.lax.scan(one_pass, x, None, length=self.passes)
         return hs
+
+
+class PreNormDecoderBlock(StatelessLayer):
+    """One decoder block with a norm before each sub-layer and the
+    sub-layers' results scaled into the residual stream:
+    ``a = x + r * Mixer(N1 x)``, ``y = a + r * FFN(N2 a)``; RMSNorm, a gated
+    FFN, no biases.  ``mixer`` is any layer over (B, L, hidden) that has
+    ``projections()`` and ``values_a_token()`` (``MultiHeadAttention``,
+    ``ssm.Mamba2Mixer``); ``scope`` is a ``jax.named_scope`` around it for
+    one that opens none itself."""
+
+    def __init__(self, mixer, hidden_size: int, intermediate_size: int,
+                 residual_multiplier: float = 1.0, epsilon: float = 1e-6,
+                 activation="silu", init="glorot_uniform",
+                 scope: Optional[str] = None, **kw):
+        super().__init__(**kw)
+        self.mixer, self.scope = mixer, scope
+        self.hidden_size, self.intermediate = hidden_size, intermediate_size
+        self.residual_multiplier = residual_multiplier
+        self.ffn = GatedFFN(hidden_size, intermediate_size, activation,
+                            init=init, name=f"{self.name}_ffn")
+        self.norm = RMSNorm(epsilon, name=f"{self.name}_norm")
+
+    def build_params(self, rng, x_shape, *rest):
+        km, kf = jax.random.split(rng)
+        return {"mixer": self.mixer.build_params(km, x_shape),
+                "ffn": self.ffn.build_params(kf, x_shape),
+                "norm1": self.norm.build_params(None, x_shape),
+                "norm2": self.norm.build_params(None, x_shape)}
+
+    def projections(self):
+        """The block's table for ``_keep_within_budget``: (name, fan-in,
+        fan-out) of every named projection, the FFN's ``down`` first."""
+        d, ff = self.hidden_size, self.intermediate
+        return ((("down", ff, d),) + tuple(self.mixer.projections())
+                + (("gate", d, ff), ("up", d, ff)))
+
+    def values_a_token(self) -> int:
+        """About what a token keeps where nothing is computed again: the
+        mixer's, and the norms' inputs and results, the FFN's three."""
+        return (self.mixer.values_a_token() + 5 * self.hidden_size
+                + 3 * self.intermediate)
+
+    def forward(self, params, x, training=False, rng=None):
+        n, r = self.norm.forward, self.residual_multiplier
+        with (jax.named_scope(self.scope) if self.scope
+              else contextlib.nullcontext()):
+            mixed = self.mixer.forward(
+                params["mixer"], n(params["norm1"], x), training=training)
+        a = x + r * mixed
+        return a + r * self.ffn.forward(params["ffn"], n(params["norm2"], a))
+
+
+class HybridDecoderStack(StatelessLayer):
+    """A decoder stack of several kinds of block in a stated order:
+    ``layer_types`` names each layer's kind and ``blocks`` gives one
+    template block a kind (``PreNormDecoderBlock``s over different mixers).
+    Consecutive layers of one kind are a run: a run's parameters are stacked
+    on a leading dim under ``run<i>`` and scanned (``_run_block_stack``), so
+    each kind is traced once a run and not once a layer; the runs follow one
+    another in order, and a final RMSNorm closes the stack.
+
+    What the layers keep for the backward pass is ``LoopedDecoderStack``'s
+    rule under the same budget (``_keep_within_budget``), over the tables
+    the kinds bring: a name stands for the same projection in every kind
+    that has it (the FFN's three) and counts over all of them.
+    ``stack_kept_bytes{name}`` says what was kept at every trace."""
+
+    def __init__(self, layer_types: Sequence[str], blocks: Dict[str, Layer],
+                 hidden_size: int, epsilon: float = 1e-6, **kw):
+        super().__init__(**kw)
+        missing = sorted(set(layer_types) - set(blocks))
+        if missing or not layer_types:
+            raise ValueError(f"layer_types {list(layer_types)} need a block "
+                             f"for each kind; none for {missing}")
+        self.layer_types, self.blocks = tuple(layer_types), dict(blocks)
+        self.hidden_size = hidden_size
+        self.runs = [(kind, len(list(same)))    # (kind, layers), in order
+                     for kind, same in itertools.groupby(self.layer_types)]
+        self.final_norm = RMSNorm(epsilon, name=f"{self.name}_final_norm")
+
+    def build_params(self, rng, x_shape, *rest):
+        params = {"final_norm": self.final_norm.build_params(None, x_shape)}
+        keys = jax.random.split(rng, len(self.runs))
+        for i, ((kind, n), key) in enumerate(zip(self.runs, keys)):
+            params[f"run{i}"] = _stack_block_params(
+                self.blocks[kind], jax.random.split(key, n), tuple(x_shape))
+        return params
+
+    def _kept(self, x) -> Dict[str, int]:
+        """The rule over this stack's kinds and ``x``'s shape, told to the
+        registry."""
+        a_token = x.size // x.shape[-1] * x.dtype.itemsize
+        table: Dict[str, list] = {}         # name -> [fan-in, bytes]
+        everything = 0
+        for kind in dict.fromkeys(self.layer_types):
+            block, n = self.blocks[kind], self.layer_types.count(kind)
+            everything += a_token * n * block.values_a_token()
+            for name, fan_in, fan_out in block.projections():
+                table.setdefault(name, [fan_in, 0])[1] += (a_token * n
+                                                           * fan_out)
+        return _tell_kept(_keep_within_budget(
+            a_token * len(self.layer_types) * self.hidden_size, everything,
+            [(name, fan_in, size)
+             for name, (fan_in, size) in table.items()]))
+
+    def forward(self, params, x, training=False, rng=None):
+        remat = _kept_names(self._kept(x))
+        for i, (kind, n) in enumerate(self.runs):
+            x = _run_block_stack(self.blocks[kind], n, params[f"run{i}"], x,
+                                 training, None, remat=remat)
+        return self.final_norm.forward(params["final_norm"], x)
 
 
 class TransformerLayer(StatelessLayer):

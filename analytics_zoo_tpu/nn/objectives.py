@@ -166,6 +166,40 @@ def _head_chunk(tokens: int, passes: int, vocab: int) -> int:
     return c
 
 
+def _mean_over_token_chunks(chunk_sum, kernel, vocab, per_token, labels):
+    """``sum over chunks of chunk_sum(kernel, *chunk of each per_token
+    array, chunk of labels) / tokens``: the loop both token-level losses
+    below form their logits in.  ``per_token`` arrays are (T, tokens, ...)
+    and ``kernel`` is the head's, over a vocabulary of ``vocab``; the chunk
+    is the largest divisor of the tokens whose T x chunk x V float32 logits
+    fit ``_HEAD_CHUNK_BYTES``.  The chunks run as a ``lax.scan`` whose body is
+    computed again in the backward pass, so at most one chunk of logits is
+    alive, and the kernel's gradient adds up over the chunks in float32."""
+    passes, n = per_token[0].shape[:2]
+    c = _head_chunk(n, passes, vocab)
+
+    def chunks(a):                  # (T, n, ...) -> (n / c, T, c, ...)
+        return jnp.moveaxis(
+            a.reshape((passes, n // c, c) + a.shape[2:]), 1, 0)
+
+    chunk_sum = jax.checkpoint(chunk_sum)
+
+    def body(total, xs):
+        return total + chunk_sum(kernel, *xs), None
+
+    total, _ = jax.lax.scan(
+        body, jnp.zeros((), jnp.float32),
+        tuple(chunks(a) for a in per_token) + (labels.reshape(n // c, c),))
+    return total / n
+
+
+def _chunk_crossentropy(logits, y):
+    """Cross-entropy at every position of a chunk: logits (T, c, V) float32
+    and labels (c,) -> (T, c)."""
+    return jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
+        logits, y[None, :, None], axis=-1)[..., 0]
+
+
 def expected_exit_crossentropy(y_true, heads):
     """The pre-training loss of a looped language model (Ouro,
     arXiv:2510.25741): per token, the cross-entropy of every pass's
@@ -175,11 +209,9 @@ def expected_exit_crossentropy(y_true, heads):
         mean_tokens( sum_t p_t * CE(h_t W, y)  -  beta * H(p) )
 
     ``heads`` is the model's ``ExitHeads``, which carries ``beta``.  The T
-    heads are formed a chunk of tokens at a time inside a ``lax.scan``
-    whose body is computed again in the backward pass, so at most one
-    chunk of logits (T x chunk x V, 512 MiB of float32 at most: the chunk
-    follows from the shapes) is alive; the kernel's gradient adds up over
-    the chunks in float32.  Plain
+    heads are formed a chunk of tokens at a time
+    (``_mean_over_token_chunks``: at most one chunk of logits, T x chunk x
+    V, 512 MiB of float32 at most, is alive).  Plain
     logits (B, L, V), as the model's ``predict`` path gives them, get the
     token-level cross-entropy."""
     if not isinstance(heads, ExitHeads):
@@ -188,33 +220,68 @@ def expected_exit_crossentropy(y_true, heads):
         passes, d = heads.hidden.shape[0], heads.hidden.shape[-1]
         labels = _sparse_labels(y_true, heads.hidden[0]).reshape(-1)
         n = labels.shape[0]
-        c = _head_chunk(n, passes, heads.kernel.shape[-1])
         beta, dt = heads.entropy_beta, jnp.dtype(heads.dtype)
 
-        def chunks(a):              # (T, n, ...) -> (n / c, T, c, ...)
-            return jnp.moveaxis(
-                a.reshape((passes, n // c, c) + a.shape[2:]), 1, 0)
-
-        @jax.checkpoint
         def chunk_sum(kernel, h, s, y):
             logits = jnp.einsum("tcd,dv->tcv", h.astype(dt),
                                 kernel.astype(dt),
                                 preferred_element_type=jnp.float32)
-            ce = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
-                logits, y[None, :, None], axis=-1)[..., 0]
+            ce = _chunk_crossentropy(logits, y)
             logp = exit_log_probs(s)
             p = jnp.exp(logp)
             return jnp.sum(p * ce) + beta * jnp.sum(p * logp)
 
-        def body(total, xs):
-            return total + chunk_sum(heads.kernel, *xs), None
+        return _mean_over_token_chunks(
+            chunk_sum, heads.kernel, heads.kernel.shape[-1],
+            (heads.hidden.reshape(passes, n, d),
+             heads.gate_logits.reshape(passes, n)), labels)
 
-        total, _ = jax.lax.scan(
-            body, jnp.zeros((), jnp.float32),
-            (chunks(heads.hidden.reshape(passes, n, d)),
-             chunks(heads.gate_logits.reshape(passes, n)),
-             labels.reshape(n // c, c)))
-        return total / n
+
+@jax.tree_util.register_pytree_node_class
+class TiedHead:
+    """What a language model whose head is its embedding hands its loss in
+    place of logits: the final hidden states (B, L, d), the embedding (V, d)
+    and the factor on the logits, ``logits = hidden embedding^T * scale``.
+    The logits, tokens x V, are left for the loss to form a chunk of tokens
+    at a time.  ``dtype`` as ``ExitHeads``'s: the type the model computed
+    in."""
+
+    def __init__(self, hidden, embedding, scale: float = 1.0, dtype=None):
+        self.hidden, self.embedding = hidden, embedding
+        self.scale = float(scale)
+        self.dtype = jnp.dtype(dtype or hidden.dtype).name
+
+    def tree_flatten(self):
+        return (self.hidden, self.embedding), (self.scale, self.dtype)
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        return cls(*leaves, *aux)
+
+
+def chunked_token_crossentropy(y_true, head):
+    """The token-level cross-entropy of a language model in one pass over a
+    head tied to the embedding: the mean over tokens of
+    ``CE(h E^T * scale, y)``.  ``head`` is the model's ``TiedHead``; the
+    logits are formed a chunk of tokens at a time
+    (``_mean_over_token_chunks``), and the embedding's gradient from the
+    head adds to the one from the look-up.  Plain logits (B, L, V), as the
+    model's ``predict`` path gives them, get the same loss unchunked."""
+    if not isinstance(head, TiedHead):
+        return sparse_categorical_crossentropy_with_logits(y_true, head)
+    with jax.named_scope("zoo:lm/head_loss"):
+        labels = _sparse_labels(y_true, head.hidden).reshape(-1)
+        scale, dt = head.scale, jnp.dtype(head.dtype)
+
+        def chunk_sum(embedding, h, y):
+            logits = scale * jnp.einsum(
+                "tcd,vd->tcv", h.astype(dt), embedding.astype(dt),
+                preferred_element_type=jnp.float32)
+            return jnp.sum(_chunk_crossentropy(logits, y))
+
+        return _mean_over_token_chunks(
+            chunk_sum, head.embedding, head.embedding.shape[0],
+            (head.hidden.reshape(1, labels.shape[0], -1),), labels)
 
 
 def class_nll(y_true, log_probs):
@@ -295,6 +362,7 @@ _REGISTRY = {
     "sparse_categorical_crossentropy_with_logits":
         sparse_categorical_crossentropy_with_logits,
     "expected_exit_crossentropy": expected_exit_crossentropy,
+    "chunked_token_crossentropy": chunked_token_crossentropy,
     "class_nll": class_nll,
     "kld": kullback_leibler_divergence,
     "kullback_leibler_divergence": kullback_leibler_divergence,

@@ -145,12 +145,13 @@ CATALOG: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
         "counter", "label tokens in the dispatched steps whose labels are "
         "(B, L) integers (language-model training)", ()),
     "stack_kept_bytes": (
-        "gauge", "what a looped decoder stack keeps for the backward pass "
-        "over all its layer applications, as reckoned when the step was "
-        "last traced (nn/layers/attention._kept_for_backward): "
-        "block_input, each projection's result by its name (down | o | q "
-        "| k | v | gate | up; 0: computed again there), and rest: what "
-        "else a block keeps where nothing is computed again",
+        "gauge", "what a looped or a hybrid decoder stack keeps for the "
+        "backward pass over all its layer applications, as reckoned when "
+        "the step was last traced (nn/layers/attention."
+        "_keep_within_budget): block_input, each projection's result by "
+        "its name (down | o | q | k | v | gate | up, and a Mamba-2 "
+        "mixer's out_proj | in_proj; 0: computed again there), and rest: "
+        "what else a block keeps where nothing is computed again",
         ("name",)),
     "train_epoch_seconds": ("histogram", "wall time of one epoch", ()),
     "train_loss": ("gauge", "last epoch mean loss", ()),

@@ -171,7 +171,13 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
     """Entry point used by the attention layers.
 
     Chooses the Pallas flash kernel on TPU when shapes allow, else the
-    blockwise scan.  ``use_flash`` forces the choice (tests).
+    blockwise scan.  ``use_flash`` forces the choice (tests).  The kernel's
+    tiles are 128 lanes wide: a head size that is a multiple of 128 goes to
+    it as it is; one under 128 that is at least 64 is padded with zeros to
+    128 (the scores and the result's first D columns are what they were, at
+    up to twice the kernel's work; ``sm_scale`` stays the unpadded
+    ``1 / sqrt(D)`` unless given); a narrower head, or a wider one that is
+    no multiple of 128, takes the XLA paths below.
     ``dropout_rate`` > 0 with a ``dropout_rng`` applies probability
     dropout (reference attn_drop semantics) via the blockwise path, which
     keeps the O(Lq · block) memory bound during training.
@@ -189,10 +195,12 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
     # take 0.41 / 0.24 / 0.41 ms and at L 512 0.39 / 0.20 / 0.37: below
     # 2,048 a call is mostly its fixed cost; the XLA paths below were not
     # timed against it there, and the floor stays where it was.
+    d = q.shape[-1]
+    lanes = -d % 128 if 64 <= d < 128 else 0    # zero columns to add
     path = dispatch.select_path(
         "flash_attention",
         shapes_ok=(mask is None and not dropping
-                   and q.shape[-1] % 128 == 0 and q.shape[2] % 128 == 0
+                   and (d + lanes) % 128 == 0 and q.shape[2] % 128 == 0
                    and k.shape[2] % 128 == 0),
         min_work_met=max(q.shape[2], k.shape[2]) >= 2048,
         force=(None if use_flash is None else
@@ -207,7 +215,12 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
             raise ValueError("flash kernel does not support attention "
                              "dropout; pass use_flash=False/None")
         from analytics_zoo_tpu.ops.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+        if lanes:
+            q, k, v = (jnp.pad(t, ((0, 0),) * 3 + ((0, lanes),))
+                       for t in (q, k, v))
+            sm_scale = sm_scale if sm_scale is not None else d ** -0.5
+        return flash_attention(q, k, v, causal=causal,
+                               sm_scale=sm_scale)[..., :d]
     if not dropping and q.shape[2] * k.shape[2] <= 256 * 256:
         # tiny sequences: one fused softmax beats the scan
         return reference_attention(q, k, v, mask=mask, causal=causal,
