@@ -20,9 +20,12 @@ chunk, ``sum_s decay_to_end_s (delta x)_s B_s^T``; the states carried from
 chunk to chunk, ``S_in[c+1] = decay_c S_in[c] + state_c`` (L / Q steps); and
 the carried state's term ``decay_from_start_t C_t S_in``.  Decays, cumulative
 sums and states are float32; the products take operands of the compute dtype
-and accumulate in float32.  Plain ``jax.numpy`` under XLA: the backward pass
-is autodiff through this form (under the stack's recomputation one layer's
-(B, H, L / Q, Q, Q) decay tensors are alive at a time).
+and accumulate in float32.  ``ssd_chunked`` is plain ``jax.numpy`` under XLA,
+its backward pass autodiff through this form: one layer's (B, L / Q, H, Q, Q)
+decay tensors cross HBM in every pass.  It is the path off the TPU and the
+oracle; on the TPU the mixer takes the same four products from the Pallas
+kernels of ``ops/ssm_scan.py`` (forward, and a backward written by hand),
+which keep a chunk's decay tile in VMEM.
 """
 
 from __future__ import annotations
@@ -101,8 +104,11 @@ class Mamba2Mixer(StatelessLayer):
     Two ``jax.named_scope``s mark it for a device trace: ``zoo:ssm/mixer``
     the whole mixer and, nested in it, ``zoo:ssm/scan`` from the split of
     the convolved ``xBC`` to ``y`` before the gate.  Every trace counts
-    ``ops_kernel_selected_total{kernel="ssm_scan"}``: the XLA form is the
-    only path there is, ``reference`` in the dispatch's names."""
+    ``ops_kernel_selected_total{kernel="ssm_scan",path=...}``: ``pallas``
+    on a TPU at shapes Mosaic compiles (``ops/ssm_scan.shapes_ok``: the
+    kernels ``ssm_scan_fwd`` and ``ssm_scan_bwd``, inside the scan's
+    scope), ``reference`` (``ssd_chunked``) anywhere else.  The choice is
+    made from the backend and the shapes; there is no switch."""
 
     def __init__(self, hidden_size: int, n_heads: int, head_dim: int,
                  d_state: int, n_groups: int = 1, d_conv: int = 4,
@@ -175,9 +181,8 @@ class Mamba2Mixer(StatelessLayer):
 
     def _scan(self, params, xbc, dt):
         from analytics_zoo_tpu.ops import dispatch
+        from analytics_zoo_tpu.ops.ssm_scan import shapes_ok, ssm_scan
 
-        # no kernel yet; one that comes shows as another path
-        dispatch.select_path("ssm_scan", force=dispatch.PATH_REFERENCE)
         bsz, l, _ = xbc.shape
         h, g, n, f32 = self.n_heads, self.n_groups, self.d_state, jnp.float32
         x, b, c = jnp.split(xbc, [self.d_inner, self.d_inner + g * n],
@@ -196,7 +201,14 @@ class Mamba2Mixer(StatelessLayer):
             # delta = 0: no decay, nothing put in
             x, b, c, dt = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),)
                                    * (t.ndim - 2)) for t in (x, b, c, dt))
-        y = ssd_chunked(x, dt, a, b, c, chunk)
+        # the kernels where Mosaic takes the shapes, on the TPU; a chunk
+        # is all the work a grid step has, so none is too small
+        path = dispatch.select_path("ssm_scan", shapes_ok=shapes_ok(
+            x.shape, g, n, chunk, x.dtype))
+        if path == dispatch.PATH_PALLAS:
+            y = ssm_scan(x, dt, a, b, c, chunk)
+        else:
+            y = ssd_chunked(x, dt, a, b, c, chunk)
         y = y + params["D"].astype(f32)[:, None] * x.astype(f32)
         return y[:, :l].reshape(bsz, l, self.d_inner)
 

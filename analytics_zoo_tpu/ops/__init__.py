@@ -22,6 +22,7 @@ from analytics_zoo_tpu.ops.quantization import (  # noqa: F401
     quantize_program,
     quantize_tensor,
 )
+from analytics_zoo_tpu.ops.ssm_scan import ssm_scan  # noqa: F401
 # last: ring_attention pulls in analytics_zoo_tpu.parallel, whose
 # modules import the ops submodules above — keep them initialized first
 from analytics_zoo_tpu.ops.ring_attention import (  # noqa: F401,E402

@@ -1,7 +1,7 @@
 """Shared backend routing for the Pallas kernels in ``ops/``.
 
 Every hand-written kernel in this package (flash_attention, embedding_bag,
-dequant_matmul) faces the same three-way choice:
+dequant_matmul, ssm_scan) faces the same three-way choice:
 
 - ``"pallas"``     — compiled Mosaic kernel; requires a TPU backend and
   the kernel's shape limits met.  ``shapes_ok`` must be False for every
@@ -14,8 +14,8 @@ dequant_matmul) faces the same three-way choice:
 - ``"reference"``  — the pure-JAX oracle; XLA-compiled, differentiable,
   runs anywhere.
 
-``select_path`` is the single predicate behind all three kernels instead
-of three private copies, and records every decision in the
+``select_path`` is the single predicate behind all of them instead of a
+private copy each, and records every decision in the
 ``ops_kernel_selected_total{kernel,path}`` counter so a serving or
 training job can assert from metrics alone that the hot loop actually hit
 the fused kernel (a silent fall-back to "reference" is a perf bug, not an

@@ -40,6 +40,8 @@ FULL: Dict[str, Any] = {
                   burst=64),
     "kernels": dict(
         flash=dict(B=4, H=8, L=2048, D=128),
+        # granite-4.0-h-micro's mixer on 2 x 4,096 tokens
+        scan=dict(B=2, L=4096, H=64, P=64, G=1, N=128, chunk=256),
         # shape classes the bundled models send (auto-dispatch must keep
         # them on the reference path) and the one the kernel is for
         bag_model=[dict(name="ncf_user", V=6041, D=20, B=8192, N=1),
@@ -365,6 +367,75 @@ def _flash_check(size: Dict[str, int], interpret: bool) -> None:
                f"auto-dispatch did not take the Pallas kernel: {selected}")
 
 
+def _scan_check(size: Dict[str, int], interpret: bool) -> None:
+    """The Mamba-2 scan's kernels against ``ssd_chunked``, output and the
+    five gradients, through the mixer's own dispatch."""
+    import jax
+    import jax.numpy as jnp
+
+    from analytics_zoo_tpu.nn.layers.ssm import Mamba2Mixer, ssd_chunked
+    from analytics_zoo_tpu.observe.metrics import METRICS
+    from analytics_zoo_tpu.ops.ssm_scan import ssm_scan
+
+    B, L, H, P, G, N = (size[k] for k in "BLHPGN")
+    chunk = size["chunk"]
+    ks = jax.random.split(jax.random.PRNGKey(4), 5)
+    x = jax.random.normal(ks[0], (B, L, H, P), jnp.bfloat16)
+    b, c = (jax.random.normal(k, (B, L, G, N), jnp.bfloat16)
+            for k in ks[1:3])
+    # the Mamba-2 defaults: delta log-uniform in 1e-3..1e-1, A in 1..16
+    dt = jnp.exp(jax.random.uniform(ks[3], (B, L, H), jnp.float32,
+                                    jnp.log(1e-3), jnp.log(1e-1)))
+    a = -jax.random.uniform(ks[4], (H,), jnp.float32, 1.0, 16.0)
+    args = (x, dt, a, b, c)
+
+    def kernel(*args):
+        return ssm_scan(*args, chunk, interpret)
+
+    def ref(*args):
+        return ssd_chunked(*args, chunk)
+
+    def grads(f):
+        return jax.jit(jax.grad(lambda *args: f(*args).sum(),
+                                argnums=range(5)))
+
+    out, f_first, f_steady = _first_and_steady(
+        functools.partial(jax.jit(kernel), *args))
+    g, g_first, g_steady = _first_and_steady(
+        functools.partial(grads(kernel), *args))
+    want, _, r_steady = _first_and_steady(
+        functools.partial(jax.jit(ref), *args))
+    gwant, _, rg_steady = _first_and_steady(
+        functools.partial(grads(ref), *args))
+    err = _rel_err(out, want)
+    gerrs = {n: _rel_err(u, v) for n, u, v in zip(
+        ("x", "dt", "a", "b", "c"), g, gwant)}
+    print(f"  ssm_scan B{B} L{L} H{H} P{P} G{G} N{N} chunk {chunk} bf16: "
+          f"fwd {f_steady * 1e3:.2f}ms (reference {r_steady * 1e3:.2f}ms), "
+          f"fwd+bwd {g_steady * 1e3:.2f}ms (reference "
+          f"{rg_steady * 1e3:.2f}ms); first call {f_first:.2f}s / "
+          f"{g_first:.2f}s; rel err fwd {err:.2e} bwd "
+          + " ".join(f"{n} {e:.2e}" for n, e in gerrs.items()))
+    _check(err < 3e-2 and max(gerrs.values()) < 6e-2,
+           f"ssm_scan disagrees with ssd_chunked: fwd {err:.3e}, bwd "
+           f"{gerrs}")
+    if not interpret:
+        # what the mixer's dispatch selects at this shape
+        mixer = Mamba2Mixer(H * P // 2, n_heads=H, head_dim=P, d_state=N,
+                            n_groups=G, chunk_size=chunk,
+                            name="chip_smoke_mixer")
+        mark = METRICS.snapshot()
+        jax.eval_shape(
+            lambda xbc, dt: mixer._scan(
+                {"dt_bias": jnp.zeros((H,)), "A_log": jnp.zeros((H,)),
+                 "D": jnp.ones((H,))}, xbc, dt),
+            jax.ShapeDtypeStruct((B, L, mixer.conv_dim), jnp.bfloat16),
+            jax.ShapeDtypeStruct((B, L, H), jnp.bfloat16))
+        selected = _selected(mark)
+        _check(_series("ssm_scan", "pallas") in selected,
+               f"the mixer did not take the Pallas kernels: {selected}")
+
+
 def _bag_inputs(shape: Dict[str, Any], pad_id):
     import jax
     import jax.numpy as jnp
@@ -488,6 +559,7 @@ def phase_kernels(size: Dict[str, Any]) -> None:
     init_zoo_context()
     interpret = size["interpret"]
     _flash_check(size["flash"], interpret)
+    _scan_check(size["scan"], interpret)
     for shape in size["bag_model"]:
         # Mosaic refuses these rows (narrower than a 128-lane tile):
         # dispatch must keep them on the reference path

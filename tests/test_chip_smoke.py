@@ -35,6 +35,7 @@ TINY = {
                   buckets=(1, 4), burst=8),
     "kernels": dict(
         flash=dict(B=1, H=1, L=256, D=128),
+        scan=dict(B=1, L=32, H=4, P=64, G=2, N=16, chunk=16),
         bag_model=[dict(name="narrow", V=4100, D=20, B=16, N=1)],
         # the smallest shape the compiled kernel accepts: one 128-lane
         # row per id, one 8-bag block
