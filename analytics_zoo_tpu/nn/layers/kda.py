@@ -40,6 +40,11 @@ sums, the solve and the states are float32; the products between sub-chunks
 and the outputs' take operands of the compute dtype and accumulate in
 float32.  ``kda_recurrent`` is the recurrence itself, a position at a time:
 the oracle of the tests and of ``chip_smoke.py``.
+
+``kda_chunked`` is the path off the TPU and the oracle of the kernels; on
+the TPU the mixer takes the same mathematics from the Pallas kernels of
+``ops/kda_scan.py`` (forward, and a backward written by hand), which keep a
+chunk's pairs, its solve and every head's carried state in VMEM.
 """
 
 from __future__ import annotations
@@ -60,7 +65,8 @@ from analytics_zoo_tpu.nn.layers.ssm import causal_conv
 from analytics_zoo_tpu.nn.module import StatelessLayer
 
 _HI = lax.Precision.HIGHEST
-# the chunked scan takes at most this many heads of one sequence at a time
+# the XLA form of the scan takes at most this many heads of one sequence at
+# a time (the kernels take every head at once)
 _SCAN_HEADS = 16
 # the pairs of a chunk are formed this many positions at a time (or the
 # whole chunk where it is shorter): the family's kernels' sub-chunk
@@ -242,10 +248,12 @@ class KDAMixer(StatelessLayer):
     Two ``jax.named_scope``s mark it for a device trace: ``zoo:kda/mixer``
     the whole mixer and, nested in it, ``zoo:kda/scan`` from q, k, v, the
     decays and beta to the outputs before the gated norm.  Every trace
-    counts ``ops_kernel_selected_total{kernel="kda_scan",path=...}``: no
-    kernel takes the scan yet, so the path is ``reference``
-    (``kda_chunked``, plain XLA) everywhere; a kernel shows as another
-    path."""
+    counts ``ops_kernel_selected_total{kernel="kda_scan",path=...}``:
+    ``pallas`` on a TPU at shapes Mosaic compiles (``ops/kda_scan.shapes_ok``:
+    the kernels ``kda_scan_fwd`` and ``kda_scan_bwd``, inside the scan's
+    scope, every head at once), ``reference`` (``kda_chunked``, plain XLA, a
+    sequence's ``_SCAN_HEADS`` heads at a time) anywhere else.  The choice
+    is made from the backend and the shapes; there is no switch."""
 
     def __init__(self, hidden_size: int, n_heads: int, head_dim: int,
                  d_conv: int = 4, chunk_size: int = 64, epsilon: float = 1e-5,
@@ -310,6 +318,7 @@ class KDAMixer(StatelessLayer):
 
     def _scan(self, q, k, v, g, beta):
         from analytics_zoo_tpu.ops import dispatch
+        from analytics_zoo_tpu.ops.kda_scan import kda_scan, shapes_ok
 
         l = q.shape[1]
         sub = self.sub_chunk
@@ -324,10 +333,15 @@ class KDAMixer(StatelessLayer):
             q, k, v, g, beta = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),)
                                         * (t.ndim - 2))
                                 for t in (q, k, v, g, beta))
-        # no kernel takes the scan yet: the XLA form at every shape, a
-        # sequence's _SCAN_HEADS heads at a time and computed again in the
-        # backward pass, so that only their chunk products are alive
-        dispatch.select_path("kda_scan", shapes_ok=False)
+        # the kernels where Mosaic takes the shapes, on the TPU: every head
+        # at once, a chunk's products never in HBM
+        path = dispatch.select_path("kda_scan", shapes_ok=shapes_ok(
+            q.shape, v.shape[-1], chunk, sub, q.dtype))
+        if path == dispatch.PATH_PALLAS:
+            return kda_scan(q, k, v, g, beta, chunk, sub)[:, :l]
+        # the XLA form: a sequence's _SCAN_HEADS heads at a time and
+        # computed again in the backward pass, so that only their chunk
+        # products are alive
         bsz, lp, h = q.shape[:3]
         part = math.gcd(h, _SCAN_HEADS)
 

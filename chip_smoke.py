@@ -3,9 +3,10 @@
 One process, no children, no network.  Drives the main paths once through
 the entry points users call — ``Estimator.fit`` (NeuralCF at MovieLens-1M
 width, ResNet-50 at 224x224), ``ClusterServing`` (MobileNet behind the queue
-path), every ``ops/`` kernel auto-dispatch selects on TPU, and the layers
-of Kimi Linear that have no kernel of their own (the chunked KDA scan, the
-dropless experts' grouped products) — on synthetic
+path), every ``ops/`` kernel auto-dispatch selects on TPU (the KDA scan's
+at one layer of Kimi Linear's cell), and Kimi Linear's own layers (the
+chunked KDA scan against its recurrence, the dropless experts against
+their dense form) — on synthetic
 data made from a seed, checks each result by the repo's own means, and
 prints as the LAST line of stdout::
 
@@ -52,6 +53,9 @@ FULL: Dict[str, Any] = {
         bag_kernel=dict(name="dlrm_like", V=1 << 20, D=128, B=1024, N=32),
         dequant=dict(K=4096, N=4096, Ms=(32, 256)),
         interpret=False),
+    # kimi-linear-48b-a3b-instruct's KDA layer on 2 x 8,192 tokens
+    "kernels/kda_scan": dict(B=2, L=8192, H=32, K=128, chunk=64, sub=16,
+                             interpret=False),
     # kimi-linear-48b-a3b-instruct's KDA heads (the recurrence's backward
     # keeps a state a step, so fewer heads and positions) and its expert
     # layer at published widths with the share a chip holds
@@ -579,6 +583,82 @@ def phase_kernels(size: Dict[str, Any]) -> None:
     _dequant_check(size["dequant"], interpret)
 
 
+def phase_kda_scan(size: Dict[str, Any]) -> Dict[str, float]:
+    """The KDA scan's kernels (``ops/kda_scan.py``) at one layer of the
+    cell against ``kda_chunked``, output and the five gradients, with the
+    milliseconds of each; and what the mixer's dispatch selects there."""
+    import jax
+    import jax.numpy as jnp
+
+    from analytics_zoo_tpu import init_zoo_context
+    from analytics_zoo_tpu.nn.layers.kda import KDAMixer, kda_chunked
+    from analytics_zoo_tpu.observe.metrics import METRICS
+    from analytics_zoo_tpu.ops.kda_scan import kda_scan
+
+    init_zoo_context()
+    B, L, H, K = (size[k] for k in "BLHK")
+    chunk, sub, interpret = size["chunk"], size["sub"], size["interpret"]
+    ks = jax.random.split(jax.random.PRNGKey(6), 7)
+    unit = lambda t: (t / jnp.linalg.norm(t, axis=-1, keepdims=True)
+                      ).astype(jnp.bfloat16)
+    q, k = (unit(jax.random.normal(kk, (B, L, H, K))) for kk in ks[:2])
+    v = jax.random.normal(ks[2], (B, L, H, K), jnp.bfloat16)
+    # the mixer's initialisation: A in 1..16 a head, the time scale
+    # log-uniform in 1e-3..1e-1 a channel (log-decays to -1.6 a step)
+    g = -jax.random.uniform(ks[3], (H, 1), jnp.float32, 1.0, 16.0) * jnp.exp(
+        jax.random.uniform(ks[4], (B, L, H, K), jnp.float32, np.log(1e-3),
+                           np.log(1e-1)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (B, L, H)))
+    weight = jax.random.normal(ks[6], (B, L, H, K))
+    args = (q, k, v, g, beta)
+
+    def kernel(*args):
+        return kda_scan(*args, chunk, sub, interpret)
+
+    def ref(*args):
+        return kda_chunked(*args, chunk, sub)
+
+    def grads(f):
+        return jax.jit(jax.grad(lambda *args: jnp.sum(weight * f(*args)),
+                                argnums=range(5)))
+
+    out, f_first, f_steady = _first_and_steady(
+        functools.partial(jax.jit(kernel), *args))
+    g_out, g_first, g_steady = _first_and_steady(
+        functools.partial(grads(kernel), *args))
+    want, _, r_steady = _first_and_steady(
+        functools.partial(jax.jit(ref), *args))
+    g_want, _, rg_steady = _first_and_steady(
+        functools.partial(grads(ref), *args))
+    err = _rel_err(out, want)
+    gerrs = {n: _rel_err(u, w) for n, u, w in zip(
+        ("q", "k", "v", "g", "beta"), g_out, g_want)}
+    print(f"  kda_scan B{B} L{L} H{H} K{K} chunk {chunk}/{sub} bf16: fwd "
+          f"{f_steady * 1e3:.2f}ms (kda_chunked {r_steady * 1e3:.2f}ms), "
+          f"fwd+bwd {g_steady * 1e3:.2f}ms (kda_chunked "
+          f"{rg_steady * 1e3:.2f}ms); first call {f_first:.2f}s / "
+          f"{g_first:.2f}s; rel err fwd {err:.2e} bwd "
+          + " ".join(f"{n} {e:.2e}" for n, e in gerrs.items()))
+    # the kernels' cotangents stay float32 where autodiff of kda_chunked
+    # rounds them to bfloat16
+    _check(err < 1e-2 and max(gerrs.values()) < 3e-2,
+           f"kda_scan disagrees with kda_chunked: fwd {err:.3e}, bwd "
+           f"{gerrs}")
+    if not interpret:
+        # what the mixer's dispatch selects at this shape
+        mixer = KDAMixer(2304, H, K, chunk_size=chunk,
+                         name="chip_smoke_kda")
+        mark = METRICS.snapshot()
+        jax.eval_shape(mixer._scan, *(
+            jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args))
+        selected = _selected(mark)
+        _check(_series("kda_scan", "pallas") in selected,
+               f"the mixer did not take the Pallas kernels: {selected}")
+    return {"fwd_ms": f_steady * 1e3, "fwd_bwd_ms": g_steady * 1e3,
+            "reference_fwd_ms": r_steady * 1e3,
+            "reference_fwd_bwd_ms": rg_steady * 1e3}
+
+
 def _kda_check(size: Dict[str, int]) -> None:
     """The chunked KDA scan (bfloat16 q, k, v as the mixer gives it)
     against the recurrence a position at a time (float32), output and the
@@ -786,6 +866,7 @@ PHASES = (("train/ncf", phase_train_ncf),
           ("train/resnet50", phase_train_resnet50),
           ("serve", phase_serve),
           ("kernels", phase_kernels),
+          ("kernels/kda_scan", phase_kda_scan),
           ("layers/kimi", phase_kimi_layers),
           ("multichip", phase_multichip))
 

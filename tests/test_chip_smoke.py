@@ -42,6 +42,9 @@ TINY = {
         bag_kernel=dict(name="smallest", V=4096, D=128, B=8, N=2),
         dequant=dict(K=256, N=256, Ms=(8,)),
         interpret=True),
+    # heads of 128 lanes (the kernels' own), two chunks, two head blocks
+    "kernels/kda_scan": dict(B=2, L=128, H=4, K=128, chunk=64, sub=16,
+                             interpret=True),
     "layers/kimi": dict(
         kda=dict(B=1, L=32, H=2, K=16, chunk=16, sub=8),
         moe=dict(T=64, D=32, F=16, E=16, K=4, held=4)),
@@ -96,6 +99,14 @@ def test_phase_serve_tiny():
 
 def test_phase_kernels_interpret_tiny():
     chip_smoke.phase_kernels(TINY["kernels"])
+
+
+def test_phase_kda_scan_interpret_tiny():
+    assert (("kernels/kda_scan", chip_smoke.phase_kda_scan)
+            in chip_smoke.PHASES)
+    times = chip_smoke.phase_kda_scan(TINY["kernels/kda_scan"])
+    assert set(times) == {"fwd_ms", "fwd_bwd_ms", "reference_fwd_ms",
+                          "reference_fwd_bwd_ms"}
 
 
 def test_phase_kimi_layers_tiny():
