@@ -108,10 +108,18 @@ def init_zoo_context(
     ``multihost=True`` runs ``jax.distributed.initialize()`` so the same
     program scales to multi-host pods over DCN (replacing the reference's
     Spark-driver + block-manager transport, wp-bigdl.md:140-160).
+
+    The first call in a process installs the build listener
+    (``observe.install_build_listener``): every program built from here
+    on is booked in ``jit_build_seconds{phase}`` and
+    ``jit_builds_total{phase,program}``.
     """
     global _GLOBAL_CONTEXT
     import jax
 
+    from analytics_zoo_tpu.observe.builds import install_build_listener
+
+    install_build_listener()
     if config is None:
         config = ZooConfig.from_env(**config_overrides)
     elif config_overrides:

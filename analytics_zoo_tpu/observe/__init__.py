@@ -1,6 +1,6 @@
 """analytics_zoo_tpu.observe — the unified observability layer.
 
-Four parts (see docs/OBSERVABILITY.md):
+Five parts (see docs/OBSERVABILITY.md):
 
 - ``trace``    — trace/span ids + the bounded span ring (``TRACER``)
 - ``metrics``  — labeled counters/gauges/histograms with
@@ -9,8 +9,11 @@ Four parts (see docs/OBSERVABILITY.md):
 - ``export``   — Prometheus text dump, JSONL event log, TensorBoard
   bridge
 - ``recorder`` — the SLO-watching flight recorder
+- ``builds``   — JAX's program builds booked in ``METRICS`` by phase
+  (``install_build_listener``, which ``init_zoo_context`` calls)
 """
 
+from analytics_zoo_tpu.observe.builds import install_build_listener
 from analytics_zoo_tpu.observe.export import (JsonlEventLog,
                                               parse_prometheus,
                                               publish_to_summary,
@@ -31,4 +34,5 @@ __all__ = [
     "JsonlEventLog", "to_prometheus", "parse_prometheus",
     "publish_to_summary",
     "SLO", "FlightRecorder",
+    "install_build_listener",
 ]

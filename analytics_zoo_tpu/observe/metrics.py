@@ -262,6 +262,19 @@ CATALOG: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
         "counter", "pod peer losses detected Python-side (barrier "
         "deadlines) and survived — the stock coordination client's "
         "heartbeat detector would have terminated the process", ()),
+    # program builds (observe/builds.py: JAX's own build events)
+    "jit_build_seconds": (
+        "gauge", "seconds this process has spent building programs so far, "
+        "by phase: trace (Python to jaxpr) | lower (jaxpr to StableHLO; "
+        "Pallas to Mosaic) | compile (XLA) | load (the persistent cache's "
+        "executable); self time, a nested step taken out of the one "
+        "around it.  A gauge because a window's delta returns gauges "
+        "whole: a window opened after set-up reads set-up's account",
+        ("phase",)),
+    "jit_builds_total": (
+        "counter", "build steps, by phase and JAX's fun_name of the "
+        "program; in a window of a steady fit any series names a program "
+        "built again (the recompile alarm)", ("phase", "program")),
     # the observability layer itself
     "observe_flight_records_total": (
         "counter", "flight-recorder snapshots captured, by reason",

@@ -73,6 +73,13 @@ def _close(got, want, exact: bool, name=""):
         assert float(np.max(np.abs(got - want))) < 0.05 * scale, name
 
 
+def _counters_but_builds(delta):
+    """A registry window's counters but the programs JAX built in it
+    (``jit_builds_total``)."""
+    return {k: v for k, v in delta["counters"].items()
+            if not k.startswith("jit_builds_total")}
+
+
 CASES = [pytest.param(decay, dtype, id=f"{decay}-{jnp.dtype(dtype).name}")
          for decay in DECAYS for dtype in (jnp.float32, jnp.bfloat16)]
 
@@ -230,7 +237,7 @@ class TestTheMixersDispatch:
         p = m.build_params(jax.random.PRNGKey(1), u.shape)
         mark = METRICS.snapshot()
         jax.eval_shape(m.forward, p, u)
-        assert METRICS.delta(mark)["counters"] == {
+        assert _counters_but_builds(METRICS.delta(mark)) == {
             'ops_kernel_selected_total{kernel="kda_scan",path="reference"}':
                 1}
 
@@ -259,7 +266,7 @@ def test_the_mixers_padded_length_through_the_kernels(monkeypatch):
     mark = METRICS.snapshot()
     with pytest.warns(UserWarning, match="padded by 28"):
         (_, got), dgot = both(m._scan)
-    assert METRICS.delta(mark)["counters"] == {
+    assert _counters_but_builds(METRICS.delta(mark)) == {
         series("kda_scan", "pallas"): 1}
     (_, want), dwant = both(kda_recurrent)
     assert got.shape == want.shape

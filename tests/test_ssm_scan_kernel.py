@@ -253,6 +253,7 @@ class TestTheMixersDispatch:
 
         mark = METRICS.snapshot()
         jax.eval_shape(mixer.forward, p, u)
-        assert METRICS.delta(mark)["counters"] == {
+        assert {k: v for k, v in METRICS.delta(mark)["counters"].items()
+                if not k.startswith("jit_builds_total")} == {
             'ops_kernel_selected_total{kernel="ssm_scan",path="reference"}':
                 1}
