@@ -560,6 +560,15 @@ class SandwichDecoderBlock(StatelessLayer):
 # than its product costs (PERF.md section 6, PR 34: the sweep's table).
 _KEEP_BYTES = 1 << 30
 
+# What a hybrid stack may keep besides, of what a mixer's kernel writes for
+# its own backward pass (``kernel_residuals``): kept, the backward pass runs
+# none of that kernel's forward again.  Set on a TPU v5e (16 GB) from
+# ``kimi-linear-48b-a3b-fit-packed8k``, at 9.81 GB in use at its fullest
+# with every scan computed again (PERF.md section 4): its KDA scans' entry
+# is 2.5 GiB over four layers of 16,384 tokens, so 3 GiB takes it and leaves
+# the chip about 3.5 GB; a step of twice the tokens (5 GiB) keeps none.
+_KERNEL_KEEP_BYTES = 3 << 30
+
 # The projections of a sandwich block by the name of their result
 # (``_named_dense``): fan-in, fan-out.  Keeping a result costs fan-out
 # values a token and spares fan-in x fan-out multiply-adds in the backward
@@ -615,10 +624,22 @@ def _kept_for_backward(tokens: int, itemsize: int, hidden: int,
          for name, fan_in, fan_out in _PROJECTIONS])
 
 
+def _keep_kernel_residuals(entries: Dict[str, int]) -> Dict[str, int]:
+    """Of what kernels write for their own backward pass (name: bytes over
+    all the stack's layers), each entry whole while the sum of those kept
+    stays within ``_KERNEL_KEEP_BYTES``, in order; 0: computed again.  An
+    entry is kept whole or not at all: part of it spares no kernel call."""
+    kept, room = {}, _KERNEL_KEEP_BYTES
+    for name, size in entries.items():
+        kept[name] = size if size <= room else 0
+        room -= kept[name]
+    return kept
+
+
 def _kept_names(kept: Dict[str, int]) -> Optional[Sequence[str]]:
     """Of the rule's answer, what ``_run_block_stack`` is told: the
-    projections kept, in the table's order; ``None`` where nothing is
-    computed again."""
+    projections and kernel residuals kept, in the tables' order; ``None``
+    where nothing is computed again."""
     if kept["rest"]:
         return None
     return [name for name, size in kept.items()
@@ -705,7 +726,8 @@ class PreNormDecoderBlock(StatelessLayer):
     ``kda.KDAMixer``; FFNs: ``GatedFFN``, ``moe.DroplessMoE``); without an
     ``ffn`` the block builds a ``GatedFFN`` of ``intermediate_size``.
     ``scope`` is a ``jax.named_scope`` around the mixer for one that opens
-    none itself."""
+    none itself.  A mixer may also have ``kernel_residuals()``
+    (``kda.KDAMixer``)."""
 
     def __init__(self, mixer, hidden_size: int,
                  intermediate_size: Optional[int] = None,
@@ -741,6 +763,12 @@ class PreNormDecoderBlock(StatelessLayer):
         return (self.mixer.values_a_token() + 5 * self.hidden_size
                 + self.ffn.values_a_token())
 
+    def kernel_residuals(self, x_shape, dtype):
+        """The mixer's (name, bytes) of what its kernel writes for its own
+        backward pass, for the stack's second table; none declared: ()."""
+        declared = getattr(self.mixer, "kernel_residuals", None)
+        return declared(x_shape, dtype) if declared else ()
+
     def forward(self, params, x, training=False, rng=None):
         n, r = self.norm.forward, self.residual_multiplier
         with (jax.named_scope(self.scope) if self.scope
@@ -763,7 +791,10 @@ class HybridDecoderStack(StatelessLayer):
     What the layers keep for the backward pass is ``LoopedDecoderStack``'s
     rule under the same budget (``_keep_within_budget``), over the tables
     the kinds bring: a name stands for the same projection in every kind
-    that has it (the FFN's three) and counts over all of them.
+    that has it (the FFN's three) and counts over all of them.  A second
+    table, under a budget of its own (``_keep_kernel_residuals``), holds
+    what the kinds' kernels write for their own backward pass
+    (``kernel_residuals``), summed over the layers by name.
     ``stack_kept_bytes{name}`` says what was kept at every trace."""
 
     def __init__(self, layer_types: Sequence[str], blocks: Dict[str, Layer],
@@ -792,6 +823,7 @@ class HybridDecoderStack(StatelessLayer):
         registry."""
         a_token = x.size // x.shape[-1] * x.dtype.itemsize
         table: Dict[str, list] = {}         # name -> [fan-in, bytes]
+        residuals: Dict[str, int] = {}      # name -> bytes
         everything = 0
         for kind in dict.fromkeys(self.layer_types):
             block, n = self.blocks[kind], self.layer_types.count(kind)
@@ -799,10 +831,13 @@ class HybridDecoderStack(StatelessLayer):
             for name, fan_in, fan_out in block.projections():
                 table.setdefault(name, [fan_in, 0])[1] += (a_token * n
                                                            * fan_out)
-        return _tell_kept(_keep_within_budget(
+            for name, size in block.kernel_residuals(x.shape, x.dtype):
+                residuals[name] = residuals.get(name, 0) + n * size
+        kept = _keep_within_budget(
             a_token * len(self.layer_types) * self.hidden_size, everything,
             [(name, fan_in, size)
-             for name, (fan_in, size) in table.items()]))
+             for name, (fan_in, size) in table.items()])
+        return _tell_kept({**kept, **_keep_kernel_residuals(residuals)})
 
     def forward(self, params, x, training=False, rng=None):
         remat = _kept_names(self._kept(x))

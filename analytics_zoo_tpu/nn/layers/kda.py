@@ -56,6 +56,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from analytics_zoo_tpu.nn import initializers
 from analytics_zoo_tpu.nn.layers.attention import (
@@ -63,6 +64,7 @@ from analytics_zoo_tpu.nn.layers.attention import (
 from analytics_zoo_tpu.nn.layers.normalization import RMSNorm
 from analytics_zoo_tpu.nn.layers.ssm import causal_conv
 from analytics_zoo_tpu.nn.module import StatelessLayer
+from analytics_zoo_tpu.ops.kda_scan import RESIDUAL
 
 _HI = lax.Precision.HIGHEST
 # the XLA form of the scan takes at most this many heads of one sequence at
@@ -316,14 +318,42 @@ class KDAMixer(StatelessLayer):
             o_proj=dense(inner, d))
         return params
 
+    def _chunking(self, l: int):
+        """The scan's chunk over a sequence of ``l`` and the positions it
+        is padded by."""
+        sub = self.sub_chunk
+        chunk = min(self.chunk_size, -(-l // sub) * sub)
+        return chunk, -l % chunk
+
+    def kernel_residuals(self, x_shape, dtype):
+        """What the scan's kernels write for their own backward pass, as
+        (name, bytes of one application to an input of ``x_shape``), for
+        the stack's recomputation rule (``attention.HybridDecoderStack``):
+        the chunk-start states, float32, and the scaled output the gated
+        norm takes, in ``dtype``, both named ``kda_scan.RESIDUAL``.  Kept
+        together, the backward pass runs no forward kernel again; either
+        alone spares none.  0 bytes where the kernels are not selected
+        (asked as ``_scan`` asks, without counting a selection)."""
+        from analytics_zoo_tpu.ops import dispatch
+        from analytics_zoo_tpu.ops.kda_scan import shapes_ok
+
+        bsz, l, _ = x_shape
+        chunk, pad = self._chunking(l)
+        heads = (bsz, l + pad, self.n_heads, self.head_dim)
+        if not (dispatch.on_tpu() and shapes_ok(heads, self.head_dim, chunk,
+                                                self.sub_chunk, dtype)):
+            return ((RESIDUAL, 0),)
+        states = bsz * (l + pad) // chunk * self.d_inner * self.head_dim * 4
+        return ((RESIDUAL, states + bsz * l * self.d_inner
+                 * jnp.dtype(dtype).itemsize),)
+
     def _scan(self, q, k, v, g, beta):
         from analytics_zoo_tpu.ops import dispatch
         from analytics_zoo_tpu.ops.kda_scan import kda_scan, shapes_ok
 
         l = q.shape[1]
         sub = self.sub_chunk
-        chunk = min(self.chunk_size, -(-l // sub) * sub)
-        pad = -l % chunk
+        chunk, pad = self._chunking(l)
         if pad:
             warnings.warn(
                 f"{self.name}: a sequence of {l} is no multiple of the "
@@ -373,9 +403,10 @@ class KDAMixer(StatelessLayer):
             with jax.named_scope("zoo:kda/scan"):
                 o = self._scan(q, k, v, g, beta)
             # the score scale, on the outputs (o is linear in q); the gated
-            # norm's statistics in float32, its values in the compute dtype
-            o = self.norm.forward(params["o_norm"], (
-                o * self.head_dim ** -0.5).astype(x.dtype))
+            # norm's statistics in float32, its values in the compute dtype.
+            # Named as the kernels' states are (``kernel_residuals``)
+            o = self.norm.forward(params["o_norm"], checkpoint_name(
+                (o * self.head_dim ** -0.5).astype(x.dtype), RESIDUAL))
             gate = jax.nn.sigmoid(_dense(params["g_b"], _dense(
                 params["g_a"], x))).reshape(heads)
             return _named_dense(params, "o_proj",

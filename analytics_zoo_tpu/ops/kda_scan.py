@@ -32,7 +32,9 @@ head of its block:
 
 The forward writes only ``o`` or, for the backward, also the state each
 chunk started from, (B, L / C, heads x V, K) float32: the backward's one
-residual beside the inputs.
+residual beside the inputs, named ``RESIDUAL`` for a ``jax.checkpoint``
+policy around the caller (``KDAMixer`` names the scan's output the same: a
+policy that keeps both runs no forward kernel again in the backward pass).
 
 Autodiff: ``kda_scan`` carries a ``jax.custom_vjp`` with a HAND-WRITTEN
 backward kernel.  The chunks run in reverse with the state's gradient in
@@ -60,6 +62,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -80,6 +83,8 @@ _MAX_HEAD_BLOCK = 2
 # (the whole chunk by substitution); the inverse as ten products of its
 # nilpotent part at full precision took 26.68 / 60.17
 _SOLVE_ROWS = 16
+# the ``checkpoint_name`` of the chunk-start states
+RESIDUAL = "kda_scan"
 
 
 def _dot(a, b, dims):
@@ -473,7 +478,7 @@ def kda_scan(q, k, v, g, beta, chunk: int, sub: int = 16,
 def _fwd_rule(q, k, v, g, beta, chunk, sub, interpret):
     o, s_in = _scan_fwd(q, k, v, g, beta, chunk, sub, interpret,
                         with_states=True)
-    return o, (q, k, v, g, beta, s_in)
+    return o, (q, k, v, g, beta, checkpoint_name(s_in, RESIDUAL))
 
 
 def _bwd_rule(chunk, sub, interpret, res, do):

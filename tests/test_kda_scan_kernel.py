@@ -3,7 +3,8 @@ forward and the five cotangents under the interpreter against
 ``kda_chunked`` and against the recurrence a position at a time, at decays
 strong enough that a chunk's decay overflows float32 when factored;
 ``shapes_ok`` against the TPU compiler's own answer on both sides
-(``tests/mosaic_aot.py``); what the mixer's dispatch selects, and the
+(``tests/mosaic_aot.py``); what a checkpoint that keeps the named residuals
+spares the backward pass; what the mixer's dispatch selects, and the
 mixer's padded length through the kernels.  Parity on the chip is
 ``chip_smoke.py``'s."""
 
@@ -14,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.ad_checkpoint import checkpoint_name
 
 from analytics_zoo_tpu.nn.layers.kda import (KDAMixer, kda_chunked,
                                              kda_recurrent)
@@ -137,6 +139,65 @@ def test_five_cotangents_are_the_chunked_scans_and_the_recurrences(
         assert g.shape == arg.shape and g.dtype == arg.dtype, name
         _close(g, r, exact, name)
         _close(g, s, exact, name)
+
+
+# ------------------------------------------------- under a checkpoint ---
+
+# two chunks of 8, one head: the chunk-start states hold a state
+REMAT_ARGS = _inputs(16, "init", seed=5, bsz=1, h=1)
+REMAT_W = jax.random.normal(jax.random.PRNGKey(9), REMAT_ARGS[2].shape)
+
+
+def _remat_block(*args):
+    """A layer's use of the scan: its output scaled, cast and named as
+    ``KDAMixer.forward`` names it, then a function whose backward pass
+    needs that output."""
+    o = checkpoint_name((scan.kda_scan(*args, 8, 8, True) * 0.5).astype(
+        jnp.bfloat16), scan.RESIDUAL)
+    return jnp.sum(REMAT_W * jnp.tanh(o.astype(jnp.float32)))
+
+
+def _remat_grad(*kept):
+    """The five cotangents of ``_remat_block`` under a checkpoint that
+    keeps the names ``kept``."""
+    return jax.grad(jax.checkpoint(
+        _remat_block,
+        policy=jax.checkpoint_policies.save_only_these_names(*kept)),
+        argnums=range(5))
+
+
+def _kernel_calls(jaxpr):
+    """The Pallas kernels a jaxpr calls, by name, the nested jaxprs'
+    included."""
+    calls = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            calls.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            calls.extend(_kernel_calls(sub))
+    return calls
+
+
+@pytest.mark.parametrize("kept,forwards", [((), 2), ((scan.RESIDUAL,), 1)],
+                         ids=["neither", "both"])
+def test_the_kept_residuals_take_the_forward_kernel_out_of_the_backward(
+        kept, forwards):
+    """Kept, the chunk-start states and the scaled output spare the
+    backward pass its run of ``kda_scan_fwd``."""
+    calls = _kernel_calls(jax.make_jaxpr(_remat_grad(*kept))(
+        *REMAT_ARGS).jaxpr)
+    assert sorted(calls) == ["kda_scan_bwd"] + ["kda_scan_fwd"] * forwards
+
+
+def test_the_kept_residuals_give_the_same_gradients():
+    """The kept values are those the recomputation writes: the cotangents
+    with both names kept are to the bit those with neither (one program
+    for the two, one compile)."""
+    neither, both = jax.jit(lambda *a: (_remat_grad()(*a), _remat_grad(
+        scan.RESIDUAL)(*a)))(*REMAT_ARGS)
+    for name, a, b in zip(NAMES, neither, both):
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_array_equal(a, b, err_msg=name)
 
 
 def _specs(bsz, l, h, dk, dv, chunk, sub, dtype):

@@ -172,9 +172,52 @@ def test_the_stack_keeps_by_the_kinds_tables(monkeypatch):
     net.stack._kept(jnp.zeros((2, 32, 32), jnp.bfloat16))
     assert set(kept) == {"block_input", "down", "o_proj", "q_proj", "k_proj",
                          "v_proj", "o", "q", "kv_a", "kv_b", "gate", "up",
-                         "rest"}
+                         "rest", "kda_scan"}
     # a tiny stack keeps everything: nothing is computed again
     assert kept["rest"] > 0
+
+
+_MIB = 1 << 20
+# what the projections keep at the cell's 2 x 8,192 tokens and at twice
+# that: the kernels' entry has a budget of its own and moves none of them
+_PROJECTIONS_KEPT = {
+    2: {"block_input": 360 * _MIB, "down": 360 * _MIB, "o_proj": 288 * _MIB,
+        "q_proj": 0, "k_proj": 0, "v_proj": 0, "gate": 0, "up": 0,
+        "o": 72 * _MIB, "q": 192 * _MIB, "kv_a": 18 * _MIB, "kv_b": 0,
+        "rest": 0},
+    4: {"block_input": 720 * _MIB, "down": 720 * _MIB, "o_proj": 0,
+        "q_proj": 0, "k_proj": 0, "v_proj": 0, "gate": 0, "up": 0,
+        "o": 144 * _MIB, "q": 0, "kv_a": 36 * _MIB, "kv_b": 0, "rest": 0}}
+
+
+@pytest.mark.parametrize("on_tpu,sequences,scan_kept", [
+    # the cell: four KDA layers' chunk-start states (32 KiB a token) and
+    # scaled outputs (8 KiB a token) over 16,384 tokens, 2.5 GiB
+    (True, 2, 2560 * _MIB),
+    # the reference path writes no states
+    (False, 2, 0),
+    # 5 GiB: over the kernels' budget, so each scan is computed again
+    (True, 4, 0)], ids=["cell", "reference", "over-budget"])
+def test_the_stack_keeps_the_scans_residuals_where_they_fit(
+        monkeypatch, on_tpu, sequences, scan_kept):
+    """The second table: ``kda_scan`` kept whole where the kernels are
+    selected and it fits its budget, 0 elsewhere, told to the registry;
+    the projections keep what they kept without it."""
+    from analytics_zoo_tpu.nn.layers import attention
+    from analytics_zoo_tpu.ops import dispatch
+
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: on_tpu)
+    stack = KimiLinearLM.from_config(_kimi()).model.stack
+    before = METRICS.snapshot()
+    kept = stack._kept(jax.ShapeDtypeStruct((sequences, 8192, 2304),
+                                            jnp.bfloat16))
+    assert kept == {**_PROJECTIONS_KEPT[sequences], "kda_scan": scan_kept}
+    assert ("kda_scan" in attention._kept_names(kept)) is bool(scan_kept)
+    gauges = METRICS.delta(before)["gauges"]
+    assert gauges['stack_kept_bytes{name="kda_scan"}'] == scan_kept
+    # asked without counting a selection
+    assert not any(k.startswith("ops_kernel_selected_total")
+                   for k in METRICS.delta(before)["counters"])
 
 
 # ------------------------------------------------------ latent attention ---
